@@ -111,7 +111,20 @@ class AdmissionMixin:
                             SHED_EXPIRED,
                             0.0,
                         )
-                    self.overload.check_admission(priority, len(self.queue))
+                    # Only what the free slots cannot take at the next
+                    # step waits at all: a burst of eight into eight idle
+                    # slots must not shed because the drain-rate estimate
+                    # was learned from serial traffic (chip_smoke.py's
+                    # warm-ups, then its concurrent burst — PR 21).
+                    active = sum(1 for r in self.slots if r is not None)
+                    room = max(
+                        0,
+                        min(self.max_slots, self.overload.concurrency_limit())
+                        - active,
+                    )
+                    self.overload.check_admission(
+                        priority, max(0, len(self.queue) - room)
+                    )
                 except ShedError as e:
                     self.overload.record_shed(
                         None,
@@ -558,7 +571,7 @@ class AdmissionMixin:
 
         Two phases so an admission BURST costs one prefill dispatch per
         length bucket, not one per request (serial per-request prefill was
-        the churn-throughput hole, VERDICT r2 weak #5): phase 1 assigns
+        the churn-throughput hole): phase 1 assigns
         slots/pages/trie links for everything that fits, phase 2 batches
         the dense prefills by length bucket and grafts each row.
         """

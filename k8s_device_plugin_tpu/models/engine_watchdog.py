@@ -26,7 +26,7 @@ owner thread cannot take the detector down with it:
 - :class:`ChipHealthFeed` — watches the chips the engine is actually
   decoding on: polls the plugin daemon's ``/debug/devices`` surface
   (authoritative — native probes, flap debounce, unplug detection) and
-  falls back to direct ``/dev/accel*`` presence probes when no daemon
+  falls back to direct device-node presence probes when no daemon
   URL is configured or the daemon stops answering.  A chip going
   Unhealthy or vanishing fences the replica instead of letting it serve
   garbage.
@@ -49,20 +49,31 @@ from typing import Callable, Optional
 
 def visible_chip_paths(environ=None, root: str = "/") -> list[str]:
     """Device-node paths of the chips allocated to THIS pod, from the
-    ``TPU_VISIBLE_CHIPS`` env the plugin's Allocate response injects
-    (``"0,1"`` -> ``[/dev/accel0, /dev/accel1]``); empty off-cluster.
-    ``root`` is the injectable host-tree root the rest of the plugin
-    test surface uses."""
+    ``TPU_VISIBLE_CHIPS`` env the plugin's Allocate response injects;
+    empty off-cluster.  The index -> node mapping is discovery's own
+    (plugin/discovery.py), so a VFIO host resolves ``"0"`` to its
+    ``/dev/vfio/<group>`` node and an accel host to ``/dev/accel0``; an
+    index discovery cannot see keeps the accel spelling, which is absent
+    and therefore reads as unplugged.  ``root`` is the injectable
+    host-tree root the rest of the plugin test surface uses."""
     environ = os.environ if environ is None else environ
     text = environ.get("TPU_VISIBLE_CHIPS", "") or ""
-    out: list[str] = []
-    for part in text.replace(",", " ").split():
-        try:
-            idx = int(part)
-        except ValueError:
-            return []
-        out.append(os.path.join(root, f"dev/accel{idx}"))
-    return out
+    try:
+        indices = [int(part) for part in text.replace(",", " ").split()]
+    except ValueError:
+        return []
+    if not indices:
+        return []
+    from ..plugin import discovery
+
+    node_of = {
+        c.index: c.device_path
+        for c in discovery.discover(root=root, environ={}).chips
+    }
+    return [
+        os.path.join(root, node_of.get(i, f"/dev/accel{i}").lstrip("/"))
+        for i in indices
+    ]
 
 
 class StepWatchdog:

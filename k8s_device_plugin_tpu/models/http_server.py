@@ -2095,11 +2095,12 @@ class EngineServer:
 
 
 def _resolve_decode_block(explicit: Optional[int], spec_gamma: int) -> int:
-    """Data-chosen default (round-5 hardware: 52/425/826 tokens/sec at
-    block 1/8/16, b8): 16 — unless speculation is on, which steps
-    per-token (the engine rejects the combination).  An explicit
-    --decode-block always wins (and the engine will reject an explicit
-    block > 1 combined with --spec-gamma)."""
+    """Default 16 (chosen from 52/425/826 tokens/sec at block 1/8/16, b8:
+    builder session 2026-08-01, before PR 1, not re-measured) — unless
+    speculation is on, which steps per-token (the engine rejects the
+    combination).  An explicit --decode-block always wins (and the
+    engine will reject an explicit block > 1 combined with
+    --spec-gamma)."""
     if explicit is not None:
         return explicit
     return 1 if spec_gamma else 16
@@ -2115,14 +2116,9 @@ def main(argv: Optional[list[str]] = None) -> None:
     import jax
     import jax.numpy as jnp
 
-    from ..utils.platform import honor_jax_platforms_env
     from .benchmark import _positive_int
     from .engine import EngineMetrics, _pow2_int
     from .transformer import GPTConfig, PagedConfig, TransformerLM
-
-    honor_jax_platforms_env(
-        empty_is_auto=False, log=lambda m: print(m, file=sys.stderr)
-    )
 
     p = argparse.ArgumentParser(prog="tpu-serving-http")
     p.add_argument("--hidden", type=_positive_int, default=512)
@@ -2145,10 +2141,11 @@ def main(argv: Optional[list[str]] = None) -> None:
         action=argparse.BooleanOptionalAction,
         default=None,
         help="force the split-K flash-decode paged-attention kernel "
-        "on/off (default: gather everywhere until a hardware round "
-        "proves the split-K Mosaic lowering — docs/kernels.md; force on "
-        "for long-context pools where max-pages-per-seq far exceeds "
-        "typical lengths)",
+        "on/off (default: gather everywhere — the kernel lowers and "
+        "agrees with gather on the v5e, its speed is not measured, "
+        "docs/kernels.md; on a TPU on means the compiled Mosaic kernel; "
+        "force on for long-context pools where max-pages-per-seq far "
+        "exceeds typical lengths)",
     )
     p.add_argument(
         "--kernel-splits",
@@ -2170,8 +2167,9 @@ def main(argv: Optional[list[str]] = None) -> None:
         default=None,
         help="tokens per dispatch in pure decode (power of two; one "
         "scanned program amortizes the per-step host round-trip — "
-        "round-5 hardware measured 52/425/826 tokens/sec at block "
-        "1/8/16, b8, on a dispatch-bound link; under saturation a "
+        "52/425/826 tokens/sec at block 1/8/16, b8, in the builder "
+        "session of 2026-08-01, before PR 1, not re-measured; under "
+        "saturation a "
         "finishing request's slot is refilled at the next step "
         "boundary, adding up to block-size steps of first-token wait — "
         "set 1 for lowest time-to-first-token; default: 16, or 1 when "
@@ -2311,14 +2309,6 @@ def main(argv: Optional[list[str]] = None) -> None:
         "tpu_engine_tp_size gauge; 1 = single-chip (default)",
     )
     p.add_argument("--http-port", type=int, default=8000)
-    p.add_argument(
-        "--compilation-cache-dir",
-        default=os.environ.get("TPU_COMPILATION_CACHE_DIR", ""),
-        help="persist XLA compilations here so a restarted pod skips its "
-        "20-40s-per-program recompiles (deploy/k8s-deploy-serve-http.yaml "
-        "mounts an emptyDir, which survives liveness-probe container "
-        "restarts); empty = no persistent cache",
-    )
     p.add_argument(
         "--span-ring",
         type=_positive_int,
@@ -2529,10 +2519,19 @@ def main(argv: Optional[list[str]] = None) -> None:
             "target; an already-quantized target (--quant) leaves nothing "
             "to verify against — drop one of the flags"
         )
-    from ..utils.platform import enable_compilation_cache
+    from ..utils.platform import device_facts, enable_compilation_cache
 
-    enable_compilation_cache(
-        args.compilation_cache_dir, log=lambda m: print(m, file=sys.stderr)
+    # A restarted pod reuses its compilations: the manifests point
+    # JAX_COMPILATION_CACHE_DIR at an emptyDir, which survives
+    # liveness-probe container restarts.
+    enable_compilation_cache(log=lambda m: print(m, file=sys.stderr))
+    facts = device_facts()
+    print(
+        f"backend: platform={facts['platform']} "
+        f"device_kind={facts['device_kind']!r} "
+        f"device_count={facts['device_count']}",
+        file=sys.stderr,
+        flush=True,
     )
 
     cfg = GPTConfig(
@@ -2668,6 +2667,12 @@ def main(argv: Optional[list[str]] = None) -> None:
         mesh=mesh,
         **spec_kw,
     )
+    # Under --tp the engine holds its own sharded copy: drop this
+    # function's references, or the unsharded init tree stays resident on
+    # the first chip for the life of the server (0.87 GB in use there
+    # against 0.21 GB on each other chip of the --tp 4 replica on the
+    # four-chip v5e host, PR 21).
+    del params, spec_kw
     watchdog = None
     if args.watchdog:
         watchdog = StepWatchdog(

@@ -57,9 +57,10 @@ class ResNet(nn.Module):
     dtype: Any = jnp.bfloat16
     # BatchNorm OUTPUT dtype (batch statistics are float32 either way —
     # flax computes them upcast).  bf16 halves the conv->BN->conv
-    # activation traffic; the round-3 session-2 hardware A/B measured
-    # 2630 vs 2071 images/sec at b128 (+27%, BASELINE.md), so bf16 is
-    # the default.  Set float32 to reproduce the old headline config.
+    # activation traffic; an A/B measured 2630 vs 2071 images/sec at b128
+    # (+27%; builder session 2026-08-01, record deleted in PR 21, not
+    # re-measured), so bf16 is the default.  Set float32 to reproduce
+    # the old headline config.
     norm_dtype: Any = jnp.bfloat16
     # "conv7" (the standard 7x7/s2 stem) or "space_to_depth": pack 2x2
     # pixel blocks into channels ([H,W,3] -> [H/2,W/2,12]) and run a

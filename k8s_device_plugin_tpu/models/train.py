@@ -217,9 +217,9 @@ def make_fused_lm_train_step(
     step.  ``chunk`` needs no relation to the vocab size (the op pads and
     masks the ragged tail).
 
-    This is a MEMORY lever, not a speed lever: the round-5 hardware chunk
-    sweep (b8 s1024 vocab 32k, BASELINE.md) measured 0.95x/0.98x/0.99x
-    naive throughput at chunk = vocab/8, vocab/2, vocab — the scan tail
+    This is a MEMORY lever, not a speed lever: a chunk sweep (b8 s1024
+    vocab 32k; builder session 2026-08-01, record deleted in PR 21, not
+    re-measured) saw 0.95x/0.98x/0.99x naive throughput at chunk = vocab/8, vocab/2, vocab — the scan tail
     never beats the one-shot matmul it replaces.  The default
     ``chunk=None`` resolves to vocab//2, the measured sweet spot: 2x
     logits-memory cut for ~2% throughput; pass a small explicit chunk

@@ -65,14 +65,17 @@ class PagedConfig:
     # (the interpreter is a parity lane, not a serving path), which is
     # what moved the KERNELS smoke ledger from 0.06-0.12x of the gather
     # path to >=1x (benchmark.py --kernel).
-    # None = auto: the GATHER path everywhere, still.  Round-5 hardware
-    # measured the OLD single-pass kernel losing to XLA's gather+einsum
-    # at moderate contexts (0.82-0.91x standalone, -56 ms/step at b8,
-    # BASELINE.md); the split-K rewrite changes that math's schedule but
-    # has not yet had a Mosaic hardware round, so auto stays gather
-    # until one records tuning rows (ops/tuning.py, docs/kernels.md
-    # "Fallback & parity contract").  Explicit True forces the kernel
-    # (all pool formats); explicit False forces gather.
+    # None = auto: the GATHER path everywhere, still.  The builder
+    # session of 2026-08-01 (before PR 1, record deleted in PR 21, not
+    # re-measured) saw the OLD single-pass kernel lose to XLA's
+    # gather+einsum at moderate contexts (0.82-0.91x standalone).  The
+    # split-K rewrite lowers under Mosaic on the v5e and agrees with the
+    # gather path for all three pool formats (chip run, PR 21), but its
+    # speed is not measured, so auto stays gather until a cell compares
+    # the two (ops/tuning.py, docs/kernels.md "Fallback & parity
+    # contract", ROADMAP Speed 5).  Explicit True forces the kernel — on
+    # a TPU that is the compiled Mosaic kernel, never the interpreter or
+    # the XLA lane (all pool formats); explicit False forces gather.
     use_kernel: bool | None = None
     # Split-K degree override: None = the per-generation tuning table
     # (ops/tuning.py — degenerate 1-split on CPU and short contexts,
@@ -81,7 +84,7 @@ class PagedConfig:
 
     def kernel_enabled(self, quant_kv: bool = False) -> bool:
         """Resolve the tri-state ``use_kernel`` at trace time (auto =
-        gather until a hardware round proves the split-K Mosaic lowering
+        gather until a chip cell measures the split-K kernel against it
         — the engine meters the resolution via tpu_engine_kernel_enabled
         and `kernel.fallback` flight events, models/engine.py)."""
         if self.use_kernel is None:

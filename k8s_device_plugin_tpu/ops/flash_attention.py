@@ -331,7 +331,7 @@ def _flash_impl(
     kv tile is *shared* across the head group in VMEM — no repeated K/V is
     ever materialized in HBM and the kernel does kv_heads' worth of kv
     traffic, not heads' (the GQA bandwidth win the round-1 `jnp.repeat`
-    path gave away, VERDICT r1 weak #4).
+    path gave away).
     """
     batch, heads, seq_q, head_dim = q.shape
     kv_heads, seq_kv = k.shape[1], k.shape[2]

@@ -46,13 +46,13 @@ is what took the CPU smoke rows from the old Pallas-interpreter's
 real kernel through the Pallas interpreter — that is the parity lane for
 the kernel itself (tests/test_paged_attention.py), not a serving path.
 
-Status: the PREVIOUS single-pass kernel was Mosaic-compiled and
-parity-checked on real v5e (rounds 3/5, BASELINE.md).  The split-K
-rewrite keeps its page/block geometry (full-page blocks, scalar-prefetch
-table, lane-replicated f32 state) but adds the split grid axis and
-partial outputs — interpreter parity is pinned; a hardware round must
-re-prove Mosaic and fill the tuning rows before `use_kernel` defaults on
-(docs/kernels.md "Fallback & parity contract").
+Status: lowered by Mosaic and run on a v5e under jax 0.9.0 (chip run,
+PR 21) at the shipped pool geometry — page 16, 4 kv heads, group 4, head
+size 64, 32 pages a row, batch 8 — for float, int8 and int4 pools at 1
+and 8 splits, agreeing with the gather path to bf16 rounding
+(chip_smoke.py's kernel leg repeats the check).  Its speed against the
+gather path is not measured, so `use_kernel` stays opt-in
+(docs/kernels.md "Fallback & parity contract", ROADMAP Speed 5).
 """
 
 from __future__ import annotations
@@ -71,14 +71,6 @@ NEG_INF = float("-inf")
 # TPU vector registers are 8 sublanes x 128 lanes; a q tile shorter than 8
 # rows would be sub-sublane, so the head group is padded up to this.
 _MIN_GROUP_TILE = 8
-
-# jax renamed TPUCompilerParams -> CompilerParams across the versions this
-# repo meets (the hardware image vs the CPU driver image); resolve once so
-# the kernel builds on both.
-_COMPILER_PARAMS = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
 
 def _unpack_int4(packed: jax.Array, dtype) -> jax.Array:
     """Sign-extend an int4-packed array (two nibbles per int8 byte along
@@ -376,7 +368,7 @@ def _paged_pallas(
         out_shape=out_shape,
         # batch and split axes are independent; the page axis carries the
         # online-softmax scratch between iterations (sequential).
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -48,18 +48,19 @@ class DecodeRow:
     source: str
 
 
-# Keyed by device_kind prefix (the flash-attention table's convention).
-# The TPU rows are PROVISIONAL: they inherit the grid-overhead shape of
-# the round-2/3 flash block sweeps (v5e amortizes setup over large
-# sequential spans; v4 prefers smaller working sets) and exist so a
-# hardware round has a schema to fill in — `use_kernel` stays opt-in
-# until one does (models/transformer.py PagedConfig).
+# Keyed by device_kind prefix (the flash-attention table's convention;
+# the v5e reports "TPU v5 lite").  The split counts are still unswept:
+# PR 21's chip run proved that the v5 lite row's splits (8 at the shipped
+# 32-page rows) lower under Mosaic and agree with the gather path for
+# float, int8 and int4 pools, and timed nothing — `use_kernel` stays
+# opt-in until a cell measures kernel against gather
+# (models/transformer.py PagedConfig, ROADMAP Speed 5).
 DECODE_ROWS: tuple[DecodeRow, ...] = (
-    DecodeRow("TPU v5 lite", 4, 8, "provisional: awaiting hw round"),
-    DecodeRow("TPU v5e", 4, 8, "provisional: awaiting hw round"),
-    DecodeRow("TPU v5p", 4, 8, "provisional: awaiting hw round"),
-    DecodeRow("TPU v4", 4, 4, "provisional: smaller VMEM, fewer splits"),
-    DecodeRow("TPU v6", 4, 8, "provisional: inherits v5e until swept"),
+    DecodeRow("TPU v5 lite", 4, 8, "lowered and parity-checked on v5e (PR 21); speed not measured"),
+    DecodeRow("TPU v5e", 4, 8, "alias of the v5 lite row"),
+    DecodeRow("TPU v5p", 4, 8, "provisional: inherits v5e, never run"),
+    DecodeRow("TPU v4", 4, 4, "provisional: smaller VMEM, fewer splits, never run"),
+    DecodeRow("TPU v6", 4, 8, "provisional: inherits v5e, never run"),
 )
 
 # CPU smoke / Pallas interpreter: splitting buys nothing (no DMA
@@ -88,20 +89,16 @@ _ACCEL_TYPE_PREFIXES: tuple[tuple[str, str], ...] = (
 def device_generation(environ: Optional[Mapping[str, str]] = None) -> str:
     """The generation key tuning rows match against.
 
-    Preference order: the live backend's device_kind (authoritative when
-    jax actually sits on a TPU), then the plugin-injected
-    ``TPU_ACCELERATOR_TYPE`` (the discovered-topology source — present
-    in every Allocate-launched serving container even before jax
-    initializes the chip), else "cpu".
+    The live backend's device_kind when jax sits on a TPU (a backend
+    that fails to initialise raises here — it is never read as "cpu");
+    on any other backend the plugin-injected ``TPU_ACCELERATOR_TYPE``
+    (present in every Allocate-launched serving container), else "cpu".
     """
-    env = os.environ if environ is None else environ
-    try:
-        import jax
+    import jax
 
-        if jax.default_backend() == "tpu":
-            return jax.devices()[0].device_kind
-    except Exception:  # codelint: ignore[naked-except] best-effort probe: jax may be absent (plugin-only install) or refuse to initialize a backend here; the env/cpu fallback below is the answer either way
-        pass
+    if jax.default_backend() == "tpu":
+        return jax.devices()[0].device_kind
+    env = os.environ if environ is None else environ
     accel = env.get("TPU_ACCELERATOR_TYPE", "")
     for prefix, kind in _ACCEL_TYPE_PREFIXES:
         if accel.startswith(prefix):

@@ -30,25 +30,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 NEG_INF = float("-inf")
 
-try:  # jax >= 0.8 spelling
-    from jax import shard_map as _shard_map
-except ImportError:  # older: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
 def shard_map_unchecked(body, *, mesh, in_specs, out_specs):
-    """`shard_map` with varying-manual-axes checking off, across the JAX
-    kwarg rename (`check_vma` >= 0.8, `check_rep` before).  The single home
-    for this version shim — ulysses/pipeline/1F1B bodies all mix replicated
-    inputs with per-device collectives, which the checker rejects."""
-    try:
-        return _shard_map(
-            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    except TypeError:
-        return _shard_map(
-            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
+    """`shard_map` with varying-manual-axes checking off — ulysses/pipeline/
+    1F1B bodies all mix replicated inputs with per-device collectives,
+    which the checker rejects."""
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def expand_gqa_kv(q, k, v):
@@ -60,13 +48,8 @@ def expand_gqa_kv(q, k, v):
 
 def _mark_varying(tree, axis_name):
     """Tag device-invariant values as varying over ``axis_name`` (shard_map
-    tracks varying manual axes; scan carries must agree).  API drifted:
-    pcast(to="varying") is current, pvary the older spelling."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(tree, axis_name, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(tree, axis_name)
-    return tree  # pre-varying-types jax: no tagging needed
+    tracks varying manual axes; scan carries must agree)."""
+    return jax.lax.pcast(tree, axis_name, to="varying")
 
 
 def ring_attention(
@@ -204,7 +187,7 @@ def ring_self_attention(
         sm_scale=sm_scale,
         extra_varying=tuple(a for a in (batch_axis, head_axis) if a),
     )
-    shard_mapped = _shard_map(
+    shard_mapped = jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
     )
     sharding = NamedSharding(mesh, spec)

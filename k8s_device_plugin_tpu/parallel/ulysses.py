@@ -142,8 +142,7 @@ def ulysses_self_attention(
         ulysses_attention, axis_name=axis, causal=causal, sm_scale=sm_scale
     )
     # The Pallas call inside the body reports no varying-manual-axes info on
-    # its outputs, so shard_map's vma checking must be off (check_rep on
-    # pre-0.8 jax spellings).
+    # its outputs, so shard_map's vma checking must be off.
     shard_mapped = shard_map_unchecked(
         body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
     )

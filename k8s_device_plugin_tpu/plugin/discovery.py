@@ -2,11 +2,17 @@
 
 Replaces the reference's sysfs scanner (`countGPUDev`, reference main.go:50-81,
 which globs /sys/class/kfd/kfd/topology/nodes/*/properties and counts
-`simd_count > 0`) with a TPU-native inventory:
+`simd_count > 0`) with a TPU-native inventory.  A host shows its chips in
+one of two forms:
 
-- chips are enumerated from ``/dev/accel*`` (the TPU VM chardev nodes, the
-  analogue of the reference's /dev/kfd at main.go:84,144) cross-checked against
-  ``/sys/class/accel/accel*``,
+- accel driver: ``/dev/accel*`` chardev nodes (the analogue of the
+  reference's /dev/kfd at main.go:84,144) cross-checked against
+  ``/sys/class/accel/accel*``;
+- VFIO (the v5e hosts of PR 21's chip runs, tests/testdata/tpu-host-v5e-vfio):
+  no accel node at all — each chip is a Google PCI function bound to
+  vfio-pci, reached through ``/dev/vfio/<iommu group>`` plus the shared
+  ``/dev/vfio/vfio`` container node.  Chip index = the group node's rank
+  in numeric order, as libtpu counts them;
 - per-chip PCI identity (vendor/device/numa/PCI address) is read from sysfs,
 - host mesh bounds / accelerator type / multi-host worker metadata come from
   the environment or ``/run/tpu`` drop-in files written by node bootstrap.
@@ -52,12 +58,16 @@ TPU_METADATA_DIR = "run/tpu"
 _ACCEL_DEV_RE = re.compile(r"accel(\d+)$")
 
 
+# The VFIO container node every VFIO user opens beside its group node.
+VFIO_CONTAINER_PATH = "/dev/vfio/vfio"
+
+
 @dataclass(frozen=True)
 class TpuChip:
-    """One discovered TPU chip (one /dev/accel* node)."""
+    """One discovered TPU chip (one /dev/accelN or /dev/vfio/<group> node)."""
 
-    index: int  # host-local chip index (the N in /dev/accelN)
-    device_path: str  # host devfs path, e.g. "/dev/accel0"
+    index: int  # host-local chip index (accelN's N; group-node rank under VFIO)
+    device_path: str  # host devfs path: "/dev/accel0" or "/dev/vfio/2"
     vendor_id: str | None = None
     device_id: str | None = None
     pci_address: str | None = None
@@ -79,6 +89,9 @@ class TpuHostInventory:
     accelerator_type: str | None  # e.g. "v5litepod-16"
     worker_id: int  # index of this host within its slice
     worker_hostnames: tuple[str, ...]  # all hosts in the slice, worker order
+    # Device nodes every container needs beside its own chips' nodes
+    # (VFIO hosts: the container node; accel hosts: none).
+    shared_device_paths: tuple[str, ...] = ()
 
     @property
     def chip_count(self) -> int:
@@ -138,11 +151,62 @@ def _sysfs_chip_info(root: str, index: int) -> dict:
     }
 
 
+def _vfio_chips(
+    root: str, generations: Mapping[str, str]
+) -> tuple[list[TpuChip], int]:
+    """Chips of a VFIO host and the count of Google functions sysfs shows.
+
+    Every Google-vendor PCI function with an IOMMU group is one chip,
+    reached through ``/dev/vfio/<group>``.  Its index is the rank of that
+    node among the host's TPU group nodes in NUMERIC order — which is how
+    libtpu 0.0.34 counts ``TPU_VISIBLE_CHIPS`` (four-chip v5e host, PR 21:
+    a child granted index i held ``/dev/vfio/i`` there, while PCI order
+    was groups 2, 1, 3, 0; on the one-chip machine the single node, named
+    1 or 2, is index 0).  A function whose group node is absent is not
+    advertised.
+    """
+    by_group: dict[int, str] = {}
+    for dev_dir in sorted(glob.glob(os.path.join(root, "sys/bus/pci/devices/*"))):
+        vendor = _read_text(os.path.join(dev_dir, "vendor"))
+        if vendor is None or vendor.lower() != GOOGLE_VENDOR_ID:
+            continue
+        try:
+            group = os.path.basename(os.readlink(os.path.join(dev_dir, "iommu_group")))
+        except OSError:
+            continue  # not behind an IOMMU: not a VFIO-served chip
+        if group.isdigit():
+            by_group[int(group)] = dev_dir
+    chips: list[TpuChip] = []
+    for group in sorted(by_group):
+        dev_dir = by_group[group]
+        if not os.path.exists(os.path.join(root, "dev/vfio", str(group))):
+            log.warning(
+                "sysfs shows TPU function %s (iommu group %d) but "
+                "/dev/vfio/%d is absent; not advertising it",
+                os.path.basename(dev_dir), group, group,
+            )
+            continue
+        device_id = _read_text(os.path.join(dev_dir, "device"))
+        chips.append(
+            TpuChip(
+                index=len(chips),
+                device_path=f"/dev/vfio/{group}",
+                vendor_id=GOOGLE_VENDOR_ID,
+                device_id=device_id,
+                pci_address=os.path.basename(dev_dir),
+                numa_node=_read_int(os.path.join(dev_dir, "numa_node")),
+                generation=generations.get((device_id or "").lower()),
+            )
+        )
+    return chips, len(by_group)
+
+
 def _metadata(root: str, name: str, environ: Mapping[str, str], env_key: str) -> str | None:
     """Node metadata: the /run/tpu drop-in file is authoritative; the env var
-    is the fallback.  (A daemon inherits ambient env — e.g. a TPU-VM image's
-    sitecustomize exporting TPU_* for every python process — so node-level
-    files must win over whatever leaked into the pod environment.)"""
+    is the fallback.  (A daemon inherits ambient env — the v5e chip machine
+    exports TPU_ACCELERATOR_TYPE, TPU_CHIPS_PER_HOST_BOUNDS and more to every
+    process — so node-level files must win over whatever the environment
+    carries.)"""
     value = _read_text(os.path.join(root, TPU_METADATA_DIR, name))
     if value:
         return value
@@ -218,6 +282,21 @@ def discover(
             )
         )
 
+    shared_device_paths: tuple[str, ...] = ()
+    if not indices and not sysfs_indices:
+        # No accel driver on this host: the chips are VFIO functions.
+        chips, n_functions = _vfio_chips(root, generations)
+        physical_span = n_functions
+        if chips:
+            shared_device_paths = (VFIO_CONTAINER_PATH,)
+    else:
+        # Bounds describe the PHYSICAL mesh, so infer them from the full
+        # index span the driver exposed (sysfs ∪ devfs), not from how many
+        # chips survived filtering: on a 2x2 host with accel2's dev node
+        # missing the remaining chips {0,1,3} still sit at their 2x2
+        # coordinates.
+        physical_span = max(indices | sysfs_indices, default=-1) + 1
+
     # --- host/slice metadata ------------------------------------------------
     accelerator_type = _metadata(
         root, "accelerator-type", environ, "TPU_ACCELERATOR_TYPE"
@@ -226,11 +305,6 @@ def discover(
     bounds_text = _metadata(
         root, "chips-per-host-bounds", environ, "TPU_CHIPS_PER_HOST_BOUNDS"
     )
-    # Bounds describe the PHYSICAL mesh, so infer them from the full index
-    # span the driver exposed (sysfs ∪ devfs), not from how many chips
-    # survived filtering: on a 2x2 host with accel2's dev node missing the
-    # remaining chips {0,1,3} still sit at their 2x2 coordinates.
-    physical_span = max(indices | sysfs_indices, default=-1) + 1
     if bounds_text:
         try:
             bx, by, bz = (int(v) for v in bounds_text.split(","))
@@ -262,6 +336,7 @@ def discover(
         accelerator_type=accelerator_type,
         worker_id=worker_id,
         worker_hostnames=worker_hostnames,
+        shared_device_paths=shared_device_paths,
     )
     log.info(
         "discovered %d TPU chip(s), bounds=%s, accelerator_type=%s, worker %d/%d",

@@ -7,9 +7,16 @@ multi-chip story (SURVEY.md §2.4/§5.8): libtpu forms the host-local ICI mesh
 and jax.distributed coordinates across hosts purely from variables like these.
 The plugin never moves tensor bytes — it tells the workload where its chips
 sit so the workload's collectives ride ICI.
+
+Checked against libtpu 0.0.34 on v5e hosts (chip runs, PR 21): with no
+other TPU_* variable in the environment, the set below brings the runtime
+up on exactly the granted chips, renumbered from device 0 with coordinates
+from (0,0,0).
 """
 
 from __future__ import annotations
+
+import math
 
 from .discovery import TpuChip, TpuHostInventory
 from .topology import SubMesh, bounds_str
@@ -40,10 +47,13 @@ def allocation_envs(
         "TPU_SKIP_MDS_QUERY": "true",
     }
 
-    if len(chips) == inventory.chip_count and inventory.chip_count > 0:
+    if len(chips) == inventory.chip_count == math.prod(inventory.host_bounds):
         # Whole host: advertise the true host mesh bounds, and (if this host
         # is part of a multi-host slice) the worker coordinates jax.distributed
-        # needs to stitch hosts together over DCN.
+        # needs to stitch hosts together over DCN.  A host that shows fewer
+        # chips than its bounds hold (the one-chip v5e machine of PR 21's
+        # chip runs: one VFIO group of a 2x2 board, node metadata still
+        # saying 2,2,1) is a sub-block like any other.
         envs["TPU_CHIPS_PER_HOST_BOUNDS"] = inventory.chips_per_host_bounds_str
         envs["TPU_WORKER_ID"] = str(inventory.worker_id)
         if inventory.worker_hostnames:

@@ -31,6 +31,23 @@ HEALTH_OVERRIDE_DIR = "run/tpu/health"
 _BUSY_ERRNOS = {errno.EBUSY, errno.EACCES, errno.EPERM}
 
 
+def _presence_only(chip: TpuChip) -> bool:
+    """A VFIO group node admits ONE opener at a time (a second open()
+    gets EBUSY), so a probe that opened it could make a workload's libtpu
+    lose the race for its own chip.  For those nodes presence is the
+    whole probe."""
+    return chip.device_path.startswith("/dev/vfio/")
+
+
+def _node_present(dev_path: str) -> bool:
+    try:
+        st = os.stat(dev_path)
+    except OSError:
+        return False  # device node vanished
+    # On a real node this is a chardev; fixture trees use regular files.
+    return stat.S_ISCHR(st.st_mode) or stat.S_ISREG(st.st_mode)
+
+
 class ChipHealthChecker:
     """Probes one chip at a time; the single-probe path is stateless.
 
@@ -119,15 +136,12 @@ class ChipHealthChecker:
             return injected
 
         dev_path = os.path.join(self._root, chip.device_path.lstrip("/"))
+        if _presence_only(chip):
+            return _node_present(dev_path)
         if self._prober is not None:
             code, err = self._prober.probe(dev_path)
             return self._classify(dev_path, code, err)
-        try:
-            st = os.stat(dev_path)
-        except OSError:
-            return False  # device node vanished
-        # On a real node this is a chardev; fixture trees use regular files.
-        if not (stat.S_ISCHR(st.st_mode) or stat.S_ISREG(st.st_mode)):
+        if not _node_present(dev_path):
             return False
         try:
             fd = os.open(dev_path, os.O_RDONLY | os.O_NONBLOCK)
@@ -182,9 +196,11 @@ class ChipHealthChecker:
             if injected is not None:
                 result[chip.k8s_id] = injected
                 continue
-            batched.append(
-                (chip, os.path.join(self._root, chip.device_path.lstrip("/")))
-            )
+            path = os.path.join(self._root, chip.device_path.lstrip("/"))
+            if _presence_only(chip):
+                result[chip.k8s_id] = _node_present(path)
+                continue
+            batched.append((chip, path))
         codes = self._prober.probe_many([path for _, path in batched])
         for (chip, path), (code, err) in zip(batched, codes):
             result[chip.k8s_id] = self._classify(path, code, err)

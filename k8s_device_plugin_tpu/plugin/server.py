@@ -653,11 +653,10 @@ class TpuDevicePlugin:
     ) -> pb.ContainerAllocateResponse:
         car = pb.ContainerAllocateResponse()
         # Exactly the requested chips' device nodes — never the whole devfs.
-        for chip in sorted(chips, key=lambda c: c.index):
+        paths = [c.device_path for c in sorted(chips, key=lambda c: c.index)]
+        for path in paths + list(inventory.shared_device_paths):
             car.devices.add(
-                container_path=chip.device_path,
-                host_path=chip.device_path,
-                permissions="rw",
+                container_path=path, host_path=path, permissions="rw"
             )
         sub = self._sub_mesh_of(inventory, chips)
         if sub is None and 1 < len(chips) < inventory.chip_count:

@@ -3,9 +3,9 @@
 PR 1 gave the daemon and the serving engine scrapeable gauges and a
 request-span ring — good for "how is it doing NOW".  What they could not
 answer is the post-mortem question arXiv:2510.16946 frames as the
-host-side diagnosis gap (and that BENCH_r05 actually hit: "accelerator
-backend dead or hung" with nothing to dump): *what happened in the last
-60 seconds before it went wrong*.  This module is the black box:
+host-side diagnosis gap (and that a driver capture actually hit:
+"accelerator backend dead or hung" with nothing to dump): *what happened
+in the last 60 seconds before it went wrong*.  This module is the black box:
 
 - **Typed events**: ``record(kind, **fields)`` appends one timestamped
   dict (registration, ListAndWatch updates, Allocate, health

@@ -180,8 +180,8 @@ class Histogram:
     per-priority-class split, never a per-request/tenant one."""
 
     TYPE = "histogram"
-    # Log-spaced seconds, 1ms..10s: covers local-chip decode steps
-    # (~ms), relay-RTT steps (~100ms), and compile stalls (~s).
+    # Log-spaced seconds, 1ms..10s: covers decode steps (~ms), prefill
+    # chunks (~100ms), and compile stalls (~s).
     DEFAULT_BUCKETS = (
         0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
         1.0, 2.5, 5.0, 10.0,

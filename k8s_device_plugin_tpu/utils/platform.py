@@ -1,11 +1,10 @@
-"""One shared JAX platform-selection override.
+"""What every JAX entry point shares about the device it runs on: the
+facts it prints (platform, device_kind, count), the per-chip peak MFU is
+taken against, and the one persistent compile-cache rule.
 
-A TPU-VM image's site hooks may pin the hardware platform
-programmatically BEFORE user code runs; the ``JAX_PLATFORMS`` env var
-alone does not undo a programmatic pin — ``jax.config.update`` does.
-Every entry point that must honor the pod-spec env (repo-root
-``bench.py``'s measurement subprocess, the in-pod benchmark runner, the
-serving-engine CLI) routes through here so the semantics can't drift.
+Platform selection itself is JAX's own: ``JAX_PLATFORMS`` in the pod
+spec (or the smoke's child environment) decides, and a listed platform
+that cannot initialise is an error, never a quiet CPU run.
 """
 
 from __future__ import annotations
@@ -17,8 +16,7 @@ from typing import Callable, Optional
 # Peak dense bf16 matmul throughput per chip, by device_kind substring
 # (first match wins; more specific substrings first).  Public figures:
 # v4 275, v5e 197, v5p 459, v6e/Trillium 918, v3 123, v2 45 TFLOP/s.
-# Used for MFU reporting (bench.py) — an unknown generation yields None
-# and MFU is simply omitted, never guessed.
+# The v5e reports itself as "TPU v5 lite" (chip run, PR 21).
 PEAK_BF16_FLOPS_BY_KIND: tuple[tuple[str, float], ...] = (
     ("v5p", 459e12),
     ("v5 lite", 197e12),
@@ -29,78 +27,72 @@ PEAK_BF16_FLOPS_BY_KIND: tuple[tuple[str, float], ...] = (
     ("v2", 45e12),
 )
 
+# <checkout>/.jax_cache, from this file's own location
+# (k8s_device_plugin_tpu/utils/platform.py): the path is part of what a
+# warm start depends on, so it never comes from /tmp, a pid or the clock.
+DEFAULT_COMPILATION_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
 
-def peak_bf16_flops(device) -> Optional[float]:
-    """Per-chip peak bf16 FLOP/s for a jax device, or None if unknown."""
-    kind = (getattr(device, "device_kind", "") or "").lower()
+
+def peak_bf16_flops(device) -> float:
+    """Per-chip peak bf16 FLOP/s for a jax device.  A device_kind the
+    table does not know raises: an MFU against a guessed peak is worse
+    than no MFU, and a silently missing one hides that the table is
+    stale."""
+    kind = getattr(device, "device_kind", "") or ""
+    lowered = kind.lower()
     for sub, peak in PEAK_BF16_FLOPS_BY_KIND:
-        if sub in kind:
+        if sub in lowered:
             return peak
-    return None
+    raise ValueError(
+        f"no bf16 peak for device_kind {kind!r}: add a row to "
+        "PEAK_BF16_FLOPS_BY_KIND (utils/platform.py) before reporting MFU"
+    )
 
 
-def honor_jax_platforms_env(
-    *,
-    empty_is_auto: bool,
-    log: Optional[Callable[[str], None]] = None,
-) -> None:
-    """Apply ``JAX_PLATFORMS`` from the environment over any programmatic pin.
-
-    ``empty_is_auto``: what ``JAX_PLATFORMS=""`` means.  True — reset to
-    automatic backend selection (bench.py's fallback ladder needs this to
-    un-pin a wedged accelerator); False — treat empty as unset and leave
-    any existing pin alone (the benchmark/serving CLIs: an empty var in a
-    pod spec should be a no-op, not a reset).
-
-    Best-effort by contract: a failed update is reported through ``log``
-    (when given) and never raises — no entry point should die over
-    platform plumbing.
-    """
+def device_facts() -> dict:
+    """The three facts every record and debug surface names, as JAX
+    reports them: ``jax.devices()[0].platform``, ``.device_kind`` and
+    ``len(jax.devices())``."""
     import jax
 
-    if "JAX_PLATFORMS" not in os.environ:
-        return
-    value = os.environ["JAX_PLATFORMS"]
-    if not value and not empty_is_auto:
-        return
-    try:
-        jax.config.update("jax_platforms", value or None)
-    except Exception as e:
-        if log is not None:
-            log(f"could not apply JAX_PLATFORMS={value!r}: {e}")
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+    }
 
 
 def enable_compilation_cache(
-    cache_dir: str,
     *,
     min_compile_seconds: float = 1.0,
     log: Optional[Callable[[str], None]] = None,
-) -> None:
-    """Persist XLA compilations under ``cache_dir`` so a restarted pod
-    reuses them instead of recompiling (TPU compiles run 20-40s per
-    program; a liveness-probe restart of the serving pod would otherwise
-    pay them all again — the manifests mount an emptyDir here, which
-    survives container restarts within the pod).
+) -> str:
+    """The one persistent compile-cache rule (serving server, engine CLI,
+    benchmark runner, bench.py).  Where ``JAX_COMPILATION_CACHE_DIR`` is
+    set — the deploy manifests point it at their mounted emptyDir — JAX
+    reads it itself and this sets no directory in code; otherwise the
+    cache lives at ``<checkout>/.jax_cache``.  Returns the directory in
+    use.
 
     ``min_compile_seconds`` filters entries: only compilations at least
     this slow are written (sub-second CPU test compiles would churn the
-    dir).  An empty ``cache_dir`` is a no-op, so every entry point can
-    pass its flag/env value straight through (same self-contained
-    semantics as honor_jax_platforms_env).  Best-effort: serving must
-    come up cacheless rather than die over cache plumbing.
+    directory).
     """
-    if not cache_dir:
-        return
     import jax
 
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = DEFAULT_COMPILATION_CACHE_DIR
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", min_compile_seconds
-        )
-        if log is not None:
-            log(f"persistent compilation cache at {cache_dir}")
-    except Exception as e:
-        if log is not None:
-            log(f"compilation cache unavailable ({cache_dir}): {e}")
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", min_compile_seconds
+    )
+    if log is not None:
+        log(f"persistent compilation cache at {cache_dir}")
+    return cache_dir

@@ -1,6 +1,6 @@
 """End-to-end preemption/resume through the real benchmark runner.
 
-VERDICT r1 weak #5: checkpoint machinery existed but no workload entry point
+Checkpoint machinery existed but no workload entry point
 took a checkpoint dir, so the preemption-resume flow (BASELINE config 5's
 health-check-preemption Job) was never exercised end to end.  These tests run
 `models/benchmark.py` as a subprocess — the same command the benchmark pods

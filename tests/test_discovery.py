@@ -46,7 +46,8 @@ def test_discover_dev_node_missing(tmp_path):
 
 def test_metadata_files_win_over_env(tmp_path):
     # Drop-in files are authoritative: a daemon can inherit ambient TPU_* env
-    # (TPU-VM sitecustomize), which must not shadow node-level truth.
+    # (the v5e chip machine exports them to every process), which must not
+    # shadow node-level truth.
     root = make_fake_tpu_host(tmp_path, n_chips=4, accelerator_type="v5litepod-4")
     inv = discovery.discover(
         root=root, environ={"TPU_ACCELERATOR_TYPE": "v5litepod-16"}
@@ -167,3 +168,64 @@ def test_discovery_against_committed_v5e_tree():
     assert all(c.generation == "v5e" for c in inv.chips)
     # Device nodes resolve under the tree's /dev.
     assert inv.chips[7].device_path.endswith("dev/accel7")
+
+
+# ------------------------------------------- captured v5e VFIO host (PR 21)
+
+VFIO_TREE = "tpu-host-v5e-vfio"
+
+
+def _vfio_root():
+    import os
+
+    return os.path.join(os.path.dirname(__file__), "testdata", VFIO_TREE)
+
+
+def test_discovery_against_captured_vfio_host():
+    """Pin discovery against tests/testdata/tpu-host-v5e-vfio, captured
+    from the four-chip v5e host of PR 21's chip runs: no /dev/accel*, an
+    empty /sys/class/accel, four Google PCI functions behind vfio-pci —
+    IOMMU groups NOT in PCI order — and /dev/vfio/{0..3,vfio}."""
+    inv = discovery.discover(root=_vfio_root(), environ={})
+    assert inv.chip_count == 4
+    assert [c.k8s_id for c in inv.chips] == ["tpu-0", "tpu-1", "tpu-2", "tpu-3"]
+    # Chip index = the group node's rank in numeric order (how libtpu
+    # counts TPU_VISIBLE_CHIPS), whatever PCI function sits behind it.
+    assert [(c.device_path, c.pci_address) for c in inv.chips] == [
+        ("/dev/vfio/0", "0000:00:0a.0"),
+        ("/dev/vfio/1", "0000:00:08.0"),
+        ("/dev/vfio/2", "0000:00:09.0"),
+        ("/dev/vfio/3", "0000:00:0b.0"),
+    ]
+    assert all(c.generation == "v5e" and c.numa_node == 0 for c in inv.chips)
+    assert inv.host_bounds == (2, 2, 1)
+    assert inv.shared_device_paths == ("/dev/vfio/vfio",)
+
+
+def test_vfio_machine_granted_one_chip_of_four(tmp_path):
+    """The one-chip machine of PR 21's chip runs: sysfs still shows all
+    four functions, devfs one group node, and the ambient environment
+    still says 2,2,1.  One chip is advertised, as index 0 — the index
+    under which libtpu came up on it."""
+    import os
+    import shutil
+
+    root = tmp_path / "host"
+    shutil.copytree(_vfio_root(), root, symlinks=True)
+    for group in ("0", "1", "3"):
+        os.unlink(root / "dev" / "vfio" / group)
+    inv = discovery.discover(
+        root=str(root),
+        environ={
+            "TPU_CHIPS_PER_HOST_BOUNDS": "2,2,1",
+            "TPU_ACCELERATOR_TYPE": "v5litepod-4",
+        },
+    )
+    assert [(c.index, c.device_path) for c in inv.chips] == [(0, "/dev/vfio/2")]
+    assert inv.host_bounds == (2, 2, 1)
+    assert inv.accelerator_type == "v5litepod-4"
+
+
+def test_accel_host_has_no_shared_nodes(tmp_path):
+    inv = discovery.discover(root=make_fake_tpu_host(tmp_path, n_chips=2), environ={})
+    assert inv.shared_device_paths == ()

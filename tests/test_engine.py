@@ -66,8 +66,8 @@ def test_paged_kernel_path_matches_dense(rng):
 
 
 def test_paged_kernel_path_with_window_matches_dense(rng):
-    """use_kernel + attention_window (windowed serving on the kernel path,
-    VERDICT r2 weak #3): tokens match the dense windowed oracle, and the
+    """use_kernel + attention_window (windowed serving on the kernel
+    path): tokens match the dense windowed oracle, and the
     windowed reclamation test's invariants still hold (pages return)."""
     cfg = _cfg(attention_window=4)
     params = _params(cfg, rng)
@@ -438,7 +438,7 @@ def test_staggered_submission_mid_flight(rng):
 
 def test_admission_burst_batches_prefills(rng):
     """An admission burst must cost ONE prefill dispatch per length
-    bucket, not one per request (VERDICT r2 weak #5) — and the batched
+    bucket, not one per request — and the batched
     path must reproduce the per-request oracle exactly."""
     cfg = _cfg()
     params = _params(cfg, rng)
@@ -561,7 +561,7 @@ def test_kernel_int8_kv_composes_with_window(rng):
 
 
 def test_spec_engine_matches_dense_oracle(rng):
-    """Shared-pool speculative engine (VERDICT r2 weak #4): gamma int8
+    """Shared-pool speculative engine: gamma int8
     self-draft proposals + one multi-token verify per round, concurrent
     slots — every request's output must be EXACTLY its dense greedy
     decode, and the pool must drain clean."""
@@ -1809,10 +1809,10 @@ def test_decode_blocks_engage_while_page_blocked(rng):
 
 
 def test_use_kernel_auto_resolves_to_gather():
-    """Round-5 default flip: use_kernel=None means the gather path on
-    every backend (hardware measured XLA's gather faster at moderate
-    contexts — BASELINE.md round-5 window 1); the kernel is opt-in and,
-    when forced, covers int8 pools too (Mosaic parity proven r5)."""
+    """use_kernel=None means the gather path on every backend (XLA's
+    gather was faster at moderate contexts in the builder session of
+    2026-08-01, record deleted in PR 21, not re-measured); the kernel is
+    opt-in and, when forced, covers int8 pools too."""
     auto = PagedConfig(page_size=4, num_pages=8, max_pages_per_seq=2)
     assert auto.kernel_enabled() is False
     assert auto.kernel_enabled(quant_kv=True) is False

@@ -52,6 +52,19 @@ def test_whole_single_host_no_hostnames():
     assert "TPU_WORKER_HOSTNAMES" not in envs
 
 
+def test_host_showing_fewer_chips_than_its_bounds_is_a_sub_block():
+    """One chip of a 2x2 board (the one-chip v5e machine, PR 21): granting
+    everything the host shows is still a 1,1,1 block, not the host mesh."""
+    inv = make_inventory(n=1, bounds=(2, 2, 1), hostnames=["localhost"])
+    envs = allocation_envs(
+        inv, list(inv.chips), sub_mesh=SubMesh(origin=(0, 0, 0), bounds=(1, 1, 1))
+    )
+    assert envs["TPU_VISIBLE_CHIPS"] == "0"
+    assert envs["TPU_CHIPS_PER_HOST_BOUNDS"] == "1,1,1"
+    assert envs["TPU_WORKER_ID"] == "0"
+    assert "TPU_WORKER_HOSTNAMES" not in envs
+
+
 def test_sub_block_envs_use_block_bounds():
     inv = make_inventory()
     chips = [inv.chips[2], inv.chips[3], inv.chips[4], inv.chips[5]]

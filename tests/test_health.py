@@ -63,6 +63,38 @@ def test_unopenable_busy_device_counts_healthy(tmp_path):
         os.chmod(path, 0o644)
 
 
+def test_vfio_group_node_is_probed_by_presence_never_opened(tmp_path, monkeypatch):
+    """A VFIO group node admits one opener: a probe that opened it could
+    cost a starting workload its chip, so presence is the whole probe —
+    on the Python path and through the native prober alike."""
+    node = tmp_path / "dev" / "vfio" / "2"
+    node.parent.mkdir(parents=True)
+    node.write_text("")
+    vfio_chip = TpuChip(index=0, device_path="/dev/vfio/2")
+
+    class NeverProbe:
+        def probe(self, path):
+            raise AssertionError(f"opened {path}")
+
+        def probe_many(self, paths):
+            assert paths == [], f"opened {paths}"
+            return []
+
+    real_open = os.open
+
+    def guarded_open(path, *a, **kw):
+        assert "vfio" not in str(path), f"opened {path}"
+        return real_open(path, *a, **kw)
+
+    monkeypatch.setattr(os, "open", guarded_open)
+    for prober in (None, NeverProbe()):
+        checker = ChipHealthChecker(root=str(tmp_path), prober=prober)
+        assert checker.check(vfio_chip) is True
+        assert checker.check_many([vfio_chip]) == {"tpu-0": True}
+    node.unlink()
+    assert ChipHealthChecker(root=str(tmp_path), prober=None).check(vfio_chip) is False
+
+
 def test_non_device_file_type_is_unhealthy(tmp_path):
     # A directory where the chardev should be = broken node.
     os.makedirs(os.path.join(str(tmp_path), "dev", "accel0"))

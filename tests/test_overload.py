@@ -328,6 +328,26 @@ def test_submit_side_queue_cap_sheds_with_retry_after(overload_engine):
     _drain(eng, [pinner, occupant, queued])
 
 
+def test_queue_that_idle_slots_absorb_is_not_projected_as_wait(overload_engine):
+    """A drain rate learned from slow serial traffic (chip_smoke.py's
+    cold warm-ups: one finish every ~20 s) must not shed a burst that
+    free slots take at the very next step — only requests queued BEHIND
+    what the slots can absorb wait at all (PR 21 bring-up: 2 of 8
+    concurrent requests into 8 idle slots answered 503)."""
+    eng = overload_engine
+    ctl = _attach(eng, shed_wait_factor=8.0, target_queue_wait_s=0.5)
+    pinner = eng.submit(*LONG)
+    eng.step()  # one slot pinned, one idle
+    ctl._drain_rate = 0.05
+    first = eng.submit([3, 141, 70], 3)  # empty queue
+    second = eng.submit([3, 141, 71], 3)  # one ahead, the idle slot takes it
+    with pytest.raises(ShedError) as e:
+        eng.submit([3, 141, 72], 3)  # one ahead that no slot can take: 20 s
+    assert e.value.kind == SHED_OVERLOAD
+    assert ctl.shed_counts[SHED_OVERLOAD] == 1
+    _drain(eng, [pinner, first, second])
+
+
 def test_aimd_limit_caps_admitted_concurrency(overload_engine):
     """With the limit forced to 1, a 2-slot engine leaves the second
     slot idle; restoring the limit fills it on the next step."""

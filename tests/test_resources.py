@@ -2,7 +2,7 @@
 
 Hermetic coverage of what the reference's generic DPM does (reference
 dpm/lister.go:11-26 Discover/NewPlugin contract; dpm/manager.go:96-136
-start/stop-on-list-diff) and round 1 hardcoded away (VERDICT r1 missing #2):
+start/stop-on-list-diff) and round 1 hardcoded away:
 a second resource appears → its plugin socket registers; it vanishes → the
 socket unregisters; kubelet restarts → every live resource re-registers.
 """
@@ -104,7 +104,7 @@ def test_static_lister_single_resource(host_root, kubelet):
 
 
 def test_add_then_remove_second_resource(host_root, kubelet):
-    """The VERDICT's done-criterion: add then remove a second fake resource
+    """The done-criterion: add then remove a second fake resource
     and observe both plugin sockets register/unregister."""
     lister = PushLister(host_root)
     multi = make_multi(lister, kubelet)

@@ -113,6 +113,41 @@ def test_allocate_single_chip(stub):
     assert car.annotations["tpu.google.com/chips"] == "tpu-1"
 
 
+def test_allocate_on_a_vfio_host_mounts_group_and_container_nodes(tmp_path):
+    """The captured v5e host (tests/testdata/tpu-host-v5e-vfio): a chip's
+    node is its IOMMU group, every container also gets /dev/vfio/vfio,
+    and a busy group node is never opened by the health probe."""
+    root = os.path.join(os.path.dirname(__file__), "testdata", "tpu-host-v5e-vfio")
+    vfio_plugin = TpuDevicePlugin(
+        discover=lambda: discovery.discover(root=root, environ={}),
+        health_checker=ChipHealthChecker(root=root),
+    )
+    server = grpc.server(futures.ThreadPoolExecutor(max_workers=2))
+    add_device_plugin_servicer(vfio_plugin, server)
+    sock = tmp_path / "vfio.sock"
+    server.add_insecure_port(f"unix://{sock}")
+    server.start()
+    try:
+        with grpc.insecure_channel(f"unix://{sock}") as channel:
+            car = DevicePluginStub(channel).Allocate(
+                pb.AllocateRequest(
+                    container_requests=[
+                        pb.ContainerAllocateRequest(devicesIDs=["tpu-2", "tpu-3"])
+                    ]
+                )
+            ).container_responses[0]
+    finally:
+        server.stop(grace=None)
+    assert [d.host_path for d in car.devices] == [
+        "/dev/vfio/2", "/dev/vfio/3", "/dev/vfio/vfio",
+    ]
+    assert car.envs["TPU_VISIBLE_CHIPS"] == "2,3"
+    assert car.envs["TPU_CHIPS_PER_HOST_BOUNDS"] == "2,1,1"
+    assert car.annotations["tpu.google.com/pci-addresses"] == (
+        "0000:00:09.0,0000:00:0b.0"
+    )
+
+
 def test_allocate_full_host(stub):
     resp = stub.Allocate(
         pb.AllocateRequest(

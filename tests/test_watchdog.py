@@ -169,6 +169,19 @@ def test_visible_chip_paths():
     assert visible_chip_paths({"TPU_VISIBLE_CHIPS": "bogus"}, root="/r") == []
 
 
+def test_visible_chip_paths_on_a_vfio_host():
+    """TPU_VISIBLE_CHIPS indexes resolve through discovery, so on the
+    captured v5e host chip 2 is /dev/vfio/2 — not an absent /dev/accel2
+    that would fence the replica as unplugged two seconds after start."""
+    import os
+
+    root = os.path.join(os.path.dirname(__file__), "testdata", "tpu-host-v5e-vfio")
+    assert visible_chip_paths({"TPU_VISIBLE_CHIPS": "2,0"}, root=root) == [
+        os.path.join(root, "dev/vfio/2"),
+        os.path.join(root, "dev/vfio/0"),
+    ]
+
+
 def test_devfs_presence_probe_fires_once_then_rearms(tmp_path):
     paths = _fake_devfs(tmp_path)
     faults: list[dict] = []

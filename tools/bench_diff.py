@@ -1,19 +1,20 @@
 #!/usr/bin/env python3
-"""Diff two driver BENCH_r*.json records into a perf-ledger-ready row.
+"""Diff two benchmark records (``{"n", "rc", "parsed": {...}}`` files)
+into a markdown row.
 
-The driver captures one BENCH_rNN.json per round (headline metric,
-vs_baseline, platform, error state); comparing rounds by eyeballing two
-JSON blobs is how regressions slip.  This tool normalizes two records,
-prints a field-by-field diff, and emits a markdown row shaped for
-docs/perf-ledger.md's "Driver BENCH record history" table — which was
-backfilled from r01..r05 with exactly this tool.
+Comparing rounds by eyeballing two JSON blobs is how regressions slip.
+This tool normalizes two records, prints a field-by-field diff, and
+emits a markdown row.  (The BENCH_rNN.json captures and the
+docs/perf-ledger.md table it was written for were deleted in PR 21; the
+cell benchmark of ROADMAP Speed 1 and the driver's PERF_LEDGER.jsonl
+take their place, and Design 8 decides what of this differ survives.)
 
-    python tools/bench_diff.py BENCH_r04.json BENCH_r05.json
-    python tools/bench_diff.py --row-only BENCH_r01.json BENCH_r05.json
+    python tools/bench_diff.py old.json new.json
+    python tools/bench_diff.py --row-only old.json new.json
 
 A record whose ``parsed`` is null (the bench crashed before printing its
-JSON line — r01's state) renders as "failed"; the row still carries the
-rc and error tail so the ledger shows WHY there is no number.
+JSON line) renders as "failed"; the row still carries the rc and error
+tail so the reader sees WHY there is no number.
 """
 
 from __future__ import annotations
@@ -43,13 +44,6 @@ def load_record(path: str) -> dict:
             platform=parsed.get("platform"),
             error=parsed.get("error"),
         )
-        # Builder-salvaged hardware reference (r05 carries one): the
-        # driver-captured value may be a CPU fallback while the real
-        # chip number rides in this nested record.
-        ref = (parsed.get("builder_tpu_reference") or {}).get("parsed")
-        if ref:
-            rec["tpu_reference_value"] = ref.get("value")
-            rec["tpu_reference_platform"] = ref.get("platform")
         # Serving records carry the overlapped-pipeline block: the
         # discard count is the regression tell (a round whose discards
         # jump while throughput sags means the pipeline stopped staying
@@ -369,17 +363,14 @@ def kernel_regressions(a: dict, b: dict) -> list[str]:
 def _fmt_value(rec: dict) -> str:
     if not rec["parsed"]:
         return f"failed (rc {rec['rc']})"
-    out = f"{rec['value']} ({rec['platform']})"
-    if rec.get("tpu_reference_value") is not None:
-        out += f", tpu ref {rec['tpu_reference_value']}"
-    return out
+    return f"{rec['value']} ({rec['platform']})"
 
 
 def diff_lines(a: dict, b: dict) -> list[str]:
     lines = [f"BENCH r{a['round']:02d} -> r{b['round']:02d}"]
     for field in (
         "metric", "value", "unit", "vs_baseline", "platform", "rc", "error",
-        "tpu_reference_value", "overlap_speedup", "overlap_discards",
+        "overlap_speedup", "overlap_discards",
         "tp_size", "tp_tokens_per_sec", "tp_speedup",
         "tp_scaling_efficiency", "tp_discards", "tp_tokens_match",
         "kernels_min_ratio", "kernels_int8_vs_bf16",
@@ -725,10 +716,10 @@ def ledger_row(a: dict, b: dict) -> str:
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(
         prog="bench-diff",
-        description="diff two BENCH_r*.json records; emit a perf-ledger row",
+        description="diff two benchmark records; emit a markdown row",
     )
-    p.add_argument("old", help="earlier BENCH_rNN.json")
-    p.add_argument("new", help="later BENCH_rNN.json")
+    p.add_argument("old", help="earlier record")
+    p.add_argument("new", help="later record")
     p.add_argument(
         "--row-only",
         action="store_true",

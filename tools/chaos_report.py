@@ -226,7 +226,7 @@ def chaos_summary(results: list[dict]) -> dict:
 
 
 def ledger_row(results: list[dict]) -> str:
-    """One docs/perf-ledger.md-shaped markdown row for the run."""
+    """One markdown row for the run."""
     s = chaos_summary(results)
     measured = (
         f"{s['passed']}/{s['scenarios']} scenarios, "

@@ -11,9 +11,7 @@
 #
 # Extra arguments pass through to `python -m tools.codelint` (e.g.
 # --json -, --pass catalog-drift, --write-baseline).
-# No `set -e`: _env.sh ends in a guarded `[ -d ... ] && case` that
-# legitimately returns non-zero off-hardware; the exec below propagates
-# the lint's own exit code.
+# The exec below propagates the lint's own exit code.
 cd "$(dirname "$0")/.." || exit 1
 . tools/_env.sh
 exec python -m tools.codelint "$@"

@@ -1,7 +1,8 @@
 """Analytic per-stage roofline for ResNet-50 training on a single v5e.
 
 The measurement-backed answer to "why does ResNet-50 MFU cap well below
-the 58% matmul ceiling on this chip" (BASELINE.md round-3/4): computes
+the 58% matmul ceiling on this chip" (builder session 2026-08-01,
+record deleted in PR 21): computes
 FLOPs and HBM bytes per conv site at the headline configuration
 (b128, 224x224, bf16), classifies each against the v5e ridge point, and
 converts the totals into per-step time lower bounds that the measured
@@ -96,7 +97,7 @@ def main() -> None:
     )
     # True-FLOP convention throughout (2 FLOPs/MAC, like the LM 6ND count
     # and bench.py since r4); pre-r4 logs called 3200 ips "20% MFU" from
-    # the MAC-based constant — it is 40% true MFU (BASELINE.md note).
+    # the MAC-based constant — it is 40% true MFU.
     for ips, label in [
         (2070.8, "r3 measured f32-BN"),
         (2630.2, "r3 measured bf16-BN"),
