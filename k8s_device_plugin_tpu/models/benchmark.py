@@ -2667,9 +2667,6 @@ def run_serving(args) -> None:
     trace_spans_recorded = (
         len(spans.snapshot()) + spans.dropped - trace_spans0
     )
-    # Rides GET /debug/profile (and the profile JSON block below): the
-    # live answer to "what does tracing cost on this replica".
-    eng.profiler.note_trace_overhead(trace_overhead)
     trace_block = {
         "overhead": round(trace_overhead, 4),
         "off_tokens_per_sec": round(trace_off_tps, 2),
@@ -2835,8 +2832,6 @@ def run_serving(args) -> None:
                     "step_ms_p99": prof["step_ms"]["p99"],
                     "phase_ms_p50": phase_p50,
                     "occupancy": prof["occupancy"],
-                    # The tracing phase noted it on the profiler, so the
-                    # live GET /debug/profile carries the same number.
                     "trace_overhead": trace_block["overhead"],
                     "incidents": eng.anomaly.snapshot()["incidents_total"],
                 },

@@ -485,9 +485,10 @@ class ServingEngine(
                 observe_step=lambda s: self.anomaly.observe(
                     "engine.step_seconds", s
                 ),
+                seconds=metrics.loop_seconds if metrics else None,
+                counts=metrics.loop_counts if metrics else None,
             )
         )
-        self._prof_timer = None
         self._step_tokens = 0  # tokens emitted by the step in flight
         # Hung-step watchdog (models/engine_watchdog.py), installed by
         # the serving server (EngineServer wires it to its fence path).
@@ -862,6 +863,12 @@ class ServingEngine(
             if T == 1
             else self._block_fn(T, filtered, want_lp, biased)
         )
+        if self.metrics:
+            (
+                self.metrics.decode_dispatches_step
+                if T == 1
+                else self.metrics.decode_dispatches_block
+            ).inc()
         out, ff_tok, ff_pos, ff_key, self.cache = fn(
             self.params, self.cache, dev["tokens"], dev["positions"],
             dev["temps"], dev["aids"], dev["key"],
@@ -991,31 +998,32 @@ class ServingEngine(
         NEXT block is dispatched before this one's readback (gated on
         room >= 2T so the overlapped block cannot overrun any slot's
         budget) — same state machine as the single-step pipeline."""
-        overlap = self._overlap_allowed() and self._block_room(active) >= 2 * T
-        rec = self._take_inflight(T)
-        if rec is None:
-            # Cold (or just-invalidated) pipeline: the frontier ensure
-            # covers this block's writes — and the overlapped block's
-            # too (lookahead 2T-1) when one will follow.
-            active = self._ensure_frontier(
-                active, 2 * T - 1 if overlap else T - 1
+        with self.profiler.phase("dispatch"):
+            overlap = (
+                self._overlap_allowed() and self._block_room(active) >= 2 * T
             )
-            if not active:
-                self._update_gauges()
-                return finished
-            rec = self._dispatch_decode(active, T)
-            if overlap:
-                self._inflight = self._dispatch_decode(active, T)
-            self._mark("dispatch")
-        else:
-            self._record_hit()
-            if overlap:
-                active = self._ensure_frontier(active, 2 * T - 1)
-                # An eviction inside the ensure dirtied the state: then
-                # this step consumes what it has and re-primes next call.
-                if active and self._dev is rec["dev"]:
+            rec = self._take_inflight(T)
+            if rec is None:
+                # Cold (or just-invalidated) pipeline: the frontier ensure
+                # covers this block's writes — and the overlapped block's
+                # too (lookahead 2T-1) when one will follow.
+                active = self._ensure_frontier(
+                    active, 2 * T - 1 if overlap else T - 1
+                )
+                if not active:
+                    self._update_gauges()
+                    return finished
+                rec = self._dispatch_decode(active, T)
+                if overlap:
                     self._inflight = self._dispatch_decode(active, T)
-            self._mark("dispatch")
+            else:
+                self._record_hit()
+                if overlap:
+                    active = self._ensure_frontier(active, 2 * T - 1)
+                    # An eviction inside the ensure dirtied the state: then
+                    # this step consumes what it has and re-primes next call.
+                    if active and self._dev is rec["dev"]:
+                        self._inflight = self._dispatch_decode(active, T)
         return self._consume_block(rec, finished)
 
     def _consume_block(
@@ -1025,73 +1033,69 @@ class ServingEngine(
         per-slot consumption — under overlap this work executes while
         the next block computes on device (the host_gap phase)."""
         T = rec["T"]
-        toks, lps = self._unpack(rec)
-        self._mark("readback")
-        now = time.monotonic()
-        emitted_total = 0
-        for s, req in zip(rec["active"], rec["reqs"]):
-            if self.slots[s] is not req or not self._slot_ready[s]:
-                continue  # evicted between dispatch and sync
-            consumed = 0
-            for j in range(T):
-                tok = int(toks[s, j])
-                # Logprob BEFORE token: a streaming handler thread that
-                # snapshots between the two appends must never see a
-                # token whose logprob is missing.
-                if req.logprobs:
-                    req.token_logprobs.append(float(lps[s, j]))
-                req.tokens.append(tok)
-                self._slot_last[s] = tok
-                consumed += 1
-                emitted_total += 1
-                if (
-                    len(req.tokens) >= req.max_new_tokens
-                    or (self.eos_id is not None and tok == self.eos_id)
-                    or self._hit_stop(req)
-                ):
-                    break
-            self._slot_len[s] += consumed
-            self._observe_itl(s, consumed, now)
-            self._maybe_finish(s)
-            if req.done:
-                finished.append(req)
-            else:
-                self._extend_frontier(s)
-                if self.cfg.attention_window is not None:
-                    self._reclaim_windowed(s)
-        # The block left every row's device length at L+T (at L+2T with
-        # an overlapped block in flight).  When every active slot
-        # consumed all T tokens that IS the host truth (the in-flight
-        # block accounts for its own +T when it is consumed); a
-        # mid-block finish tore its slot down (_clear_slot -> state
-        # dirty), and only then do device lengths disagree — the
-        # in-flight discard re-aligns them, or the direct vector write
-        # below does when nothing was in flight.
-        if self._dev is None:
-            if self._inflight is not None:
-                self._drop_stale_inflight("slot_teardown")
-            else:
-                for name in self._layer_names:
-                    att = self.cache[name]["attn"]
-                    self.cache[name]["attn"] = {
-                        **att,
-                        "seq_lens": self._rep(
-                            jnp.array(self._slot_len, jnp.int32)
-                        ),
-                    }
-        self._mark("host_gap" if self._inflight is not None else "sample")
-        self._step_tokens += emitted_total
-        if self.metrics:
-            self.metrics.steps.inc()
-            self.metrics.tokens.inc(emitted_total)
-        self._update_gauges()
+        with self.profiler.phase("readback"):
+            toks, lps = self._unpack(rec)
+        with self.profiler.phase(
+            "host_gap" if self._inflight is not None else "sample"
+        ):
+            now = time.monotonic()
+            emitted_total = 0
+            for s, req in zip(rec["active"], rec["reqs"]):
+                if self.slots[s] is not req or not self._slot_ready[s]:
+                    continue  # evicted between dispatch and sync
+                consumed = 0
+                for j in range(T):
+                    tok = int(toks[s, j])
+                    # Logprob BEFORE token: a streaming handler thread that
+                    # snapshots between the two appends must never see a
+                    # token whose logprob is missing.
+                    if req.logprobs:
+                        req.token_logprobs.append(float(lps[s, j]))
+                    req.tokens.append(tok)
+                    self._slot_last[s] = tok
+                    consumed += 1
+                    emitted_total += 1
+                    if (
+                        len(req.tokens) >= req.max_new_tokens
+                        or (self.eos_id is not None and tok == self.eos_id)
+                        or self._hit_stop(req)
+                    ):
+                        break
+                self._slot_len[s] += consumed
+                self._observe_itl(s, consumed, now)
+                self._maybe_finish(s)
+                if req.done:
+                    finished.append(req)
+                else:
+                    self._extend_frontier(s)
+                    if self.cfg.attention_window is not None:
+                        self._reclaim_windowed(s)
+            # The block left every row's device length at L+T (at L+2T with
+            # an overlapped block in flight).  When every active slot
+            # consumed all T tokens that IS the host truth (the in-flight
+            # block accounts for its own +T when it is consumed); a
+            # mid-block finish tore its slot down (_clear_slot -> state
+            # dirty), and only then do device lengths disagree — the
+            # in-flight discard re-aligns them, or the direct vector write
+            # below does when nothing was in flight.
+            if self._dev is None:
+                if self._inflight is not None:
+                    self._drop_stale_inflight("slot_teardown")
+                else:
+                    for name in self._layer_names:
+                        att = self.cache[name]["attn"]
+                        self.cache[name]["attn"] = {
+                            **att,
+                            "seq_lens": self._rep(
+                                jnp.array(self._slot_len, jnp.int32)
+                            ),
+                        }
+            self._step_tokens += emitted_total
+            if self.metrics:
+                self.metrics.steps.inc()
+                self.metrics.tokens.inc(emitted_total)
+            self._update_gauges()
         return finished
-
-    def _mark(self, phase: str) -> None:
-        """Attribute the time since the previous mark of the CURRENT step
-        to ``phase`` (engine_profiler.PHASES); no-op outside step()."""
-        if self._prof_timer is not None:
-            self._prof_timer.mark(phase)
 
     def step(self) -> list[Request]:
         """Admit what fits, advance every active slot one token; returns
@@ -1102,7 +1106,7 @@ class ServingEngine(
             if self.spans
             else contextlib.nullcontext()
         )
-        timer = self._prof_timer = self.profiler.timer()
+        self.profiler.begin_step()
         self._step_tokens = 0
         hits0, discards0 = self.overlap_hits, self.overlap_discards
         kv_hits0 = self.kv_retained_hits + self.kv_host_hits
@@ -1117,7 +1121,6 @@ class ServingEngine(
                         return self._step_inner()
                 return self._step_inner()
         finally:
-            self._prof_timer = None
             with self._lock:
                 active = sum(1 for s in self.slots if s is not None)
                 queued = len(self.queue)
@@ -1128,7 +1131,6 @@ class ServingEngine(
                     else 0.0
                 )
             wall = self.profiler.finish_step(
-                timer,
                 active_slots=active,
                 max_slots=self.max_slots,
                 queued=queued,
@@ -1145,32 +1147,32 @@ class ServingEngine(
                 wd.step_finished(wall)
 
     def _step_inner(self) -> list[Request]:
-        # Overload sweeps run BEFORE admission: an expired queued request
-        # must shed (without ever touching pages) rather than admit, and
-        # an infeasible slot must be marked so the cancel sweep below
-        # frees it for the queue head.
-        finished = self._overload_sweep() if self.overload is not None else []
-        finished += self._admit()
-        # Cancelled slots tear down BEFORE the dispatch (no farewell
-        # token).  Only ready slots: a cancelled request mid-prefill
-        # keeps its job's slot/pages intact until activation, whose own
-        # _maybe_finish call then finishes it (this sweep catches
-        # requests cancelled after they were already live).
-        for s in range(self.max_slots):
-            req = self.slots[s]
-            if req is not None and req.cancelled and self._slot_ready[s]:
-                self._maybe_finish(s)
-                finished.append(req)
-        self._mark("schedule")
-        # Advance every in-flight prefill job by ONE chunk (an unchunked
-        # job completes right here, in the same step() it was admitted):
-        # chunking bounds how long active slots stall per step while a
-        # long prompt streams in.
-        for job in list(self._pending):
-            if self._advance_prefill(job):
-                self._pending.remove(job)
-                finished.extend(self._activate(job))
-        self._mark("prefill")
+        with self.profiler.phase("schedule"):
+            # Overload sweeps run BEFORE admission: an expired queued request
+            # must shed (without ever touching pages) rather than admit, and
+            # an infeasible slot must be marked so the cancel sweep below
+            # frees it for the queue head.
+            finished = self._overload_sweep() if self.overload is not None else []
+            finished += self._admit()
+            # Cancelled slots tear down BEFORE the dispatch (no farewell
+            # token).  Only ready slots: a cancelled request mid-prefill
+            # keeps its job's slot/pages intact until activation, whose own
+            # _maybe_finish call then finishes it (this sweep catches
+            # requests cancelled after they were already live).
+            for s in range(self.max_slots):
+                req = self.slots[s]
+                if req is not None and req.cancelled and self._slot_ready[s]:
+                    self._maybe_finish(s)
+                    finished.append(req)
+        with self.profiler.phase("prefill"):
+            # Advance every in-flight prefill job by ONE chunk (an unchunked
+            # job completes right here, in the same step() it was admitted):
+            # chunking bounds how long active slots stall per step while a
+            # long prompt streams in.
+            for job in list(self._pending):
+                if self._advance_prefill(job):
+                    self._pending.remove(job)
+                    finished.extend(self._activate(job))
         active = [
             s
             for s in range(self.max_slots)
@@ -1208,36 +1210,35 @@ class ServingEngine(
             T = min(self._decode_block, 1 << max(0, room.bit_length() - 1))
             if T > 1:
                 return self._block_step(active, finished, T)
-        overlap = self._overlap_allowed()
-        rec = self._take_inflight(1)
-        if rec is None:
-            # Cold (or just-invalidated) pipeline: dispatch this step,
-            # then prime the overlap from its fed-forward state.  The
-            # next write (position len) must be addressable — and the
-            # overlapped write (len+1) too when one will follow, hence
-            # the one-token frontier lookahead; _block_step/_spec_step
-            # run their own ensure with their larger lookaheads.
-            if self._optimistic or overlap:
-                active = self._ensure_frontier(active, 1 if overlap else 0)
-                if not active:
-                    self._update_gauges()
-                    return finished
-            rec = self._dispatch_decode(active)
-            if overlap:
-                self._inflight = self._dispatch_decode(active)
-            self._mark("dispatch")
-        else:
-            self._record_hit()
-            if overlap:
-                # Keep one step in flight: ensure the NEXT write is
-                # addressable, then dispatch before the (blocking)
-                # readback of the consumed step.  An eviction inside the
-                # ensure dirtied the state — then this step consumes
-                # what it has and re-primes next call.
-                active = self._ensure_frontier(active, 1)
-                if active and self._dev is rec["dev"]:
+        with self.profiler.phase("dispatch"):
+            overlap = self._overlap_allowed()
+            rec = self._take_inflight(1)
+            if rec is None:
+                # Cold (or just-invalidated) pipeline: dispatch this step,
+                # then prime the overlap from its fed-forward state.  The
+                # next write (position len) must be addressable — and the
+                # overlapped write (len+1) too when one will follow, hence
+                # the one-token frontier lookahead; _block_step/_spec_step
+                # run their own ensure with their larger lookaheads.
+                if self._optimistic or overlap:
+                    active = self._ensure_frontier(active, 1 if overlap else 0)
+                    if not active:
+                        self._update_gauges()
+                        return finished
+                rec = self._dispatch_decode(active)
+                if overlap:
                     self._inflight = self._dispatch_decode(active)
-            self._mark("dispatch")
+            else:
+                self._record_hit()
+                if overlap:
+                    # Keep one step in flight: ensure the NEXT write is
+                    # addressable, then dispatch before the (blocking)
+                    # readback of the consumed step.  An eviction inside the
+                    # ensure dirtied the state — then this step consumes
+                    # what it has and re-primes next call.
+                    active = self._ensure_frontier(active, 1)
+                    if active and self._dev is rec["dev"]:
+                        self._inflight = self._dispatch_decode(active)
         return self._consume_step(rec, finished)
 
     def _consume_step(
@@ -1247,39 +1248,41 @@ class ServingEngine(
         then per-slot consumption (EOS/stop checks, frontier extension,
         reclamation, metrics) — under overlap this host work executes
         while the next step computes on device (the host_gap phase)."""
-        toks, lps = self._unpack(rec)
-        self._mark("readback")
-        now = time.monotonic()
-        consumed = 0
-        for s, req in zip(rec["active"], rec["reqs"]):
-            if self.slots[s] is not req or not self._slot_ready[s]:
-                continue  # evicted between dispatch and sync
-            tok = int(toks[s])
-            # Logprob BEFORE token (see _consume_block note).
-            if req.logprobs:
-                req.token_logprobs.append(float(lps[s]))
-            req.tokens.append(tok)
-            self._slot_last[s] = tok
-            self._slot_len[s] += 1
-            consumed += 1
-            self._observe_itl(s, 1, now)
-            self._maybe_finish(s)
-            if req.done:
-                finished.append(req)
-            else:
-                self._extend_frontier(s)
-                if self.cfg.attention_window is not None:
-                    self._reclaim_windowed(s)
-        if self._dev is None:
-            # A finish/cancel tore a slot down mid-consume: whatever is
-            # still in flight was dispatched from pre-teardown state.
-            self._drop_stale_inflight("slot_teardown")
-        self._mark("host_gap" if self._inflight is not None else "sample")
-        self._step_tokens += consumed
-        if self.metrics:
-            self.metrics.steps.inc()
-            self.metrics.tokens.inc(consumed)
-        self._update_gauges()
+        with self.profiler.phase("readback"):
+            toks, lps = self._unpack(rec)
+        with self.profiler.phase(
+            "host_gap" if self._inflight is not None else "sample"
+        ):
+            now = time.monotonic()
+            consumed = 0
+            for s, req in zip(rec["active"], rec["reqs"]):
+                if self.slots[s] is not req or not self._slot_ready[s]:
+                    continue  # evicted between dispatch and sync
+                tok = int(toks[s])
+                # Logprob BEFORE token (see _consume_block note).
+                if req.logprobs:
+                    req.token_logprobs.append(float(lps[s]))
+                req.tokens.append(tok)
+                self._slot_last[s] = tok
+                self._slot_len[s] += 1
+                consumed += 1
+                self._observe_itl(s, 1, now)
+                self._maybe_finish(s)
+                if req.done:
+                    finished.append(req)
+                else:
+                    self._extend_frontier(s)
+                    if self.cfg.attention_window is not None:
+                        self._reclaim_windowed(s)
+            if self._dev is None:
+                # A finish/cancel tore a slot down mid-consume: whatever is
+                # still in flight was dispatched from pre-teardown state.
+                self._drop_stale_inflight("slot_teardown")
+            self._step_tokens += consumed
+            if self.metrics:
+                self.metrics.steps.inc()
+                self.metrics.tokens.inc(consumed)
+            self._update_gauges()
         return finished
 
     def _observe_itl(self, slot: int, consumed: int, now: float) -> None:

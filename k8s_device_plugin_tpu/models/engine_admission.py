@@ -25,6 +25,7 @@ from .engine_overload import (
     ShedError,
     parse_priority,
 )
+from .engine_profiler import in_phase
 from .engine_sampling import _token_logprob, filter_top_k_top_p
 from .engine_types import Request
 from .transformer import decode_cache_spec
@@ -415,6 +416,7 @@ class AdmissionMixin:
         self._prefill_cache[key] = fn
         return fn
 
+    @in_phase("schedule.start_prefill")
     def _start_prefill(self, items: list[tuple[int, "Request", list[int], int]]):
         """Create one prefill JOB for a same-length-bucket admission group.
 
@@ -534,16 +536,19 @@ class AdmissionMixin:
         # hit a fresh XLA shape): grace the hung-step deadline.
         self._wd_grace("prefill")
         chunk, pos = job["chunk"], job["pos"]
-        fn = self._prefill_chunk_fn(chunk, job["batch"], job["bucket"])
-        tokens = jax.lax.slice_in_dim(job["rows"], pos, pos + chunk, axis=1)
-        logits_rows, job["cache"] = fn(
-            self.params,
-            job["cache"],
-            tokens,
-            jnp.asarray(pos, jnp.int32),
-            job["last_idx"],
-            job["aids"],
-        )
+        with self.profiler.phase("prefill.chunk"):
+            fn = self._prefill_chunk_fn(chunk, job["batch"], job["bucket"])
+            tokens = jax.lax.slice_in_dim(
+                job["rows"], pos, pos + chunk, axis=1
+            )
+            logits_rows, job["cache"] = fn(
+                self.params,
+                job["cache"],
+                tokens,
+                jnp.asarray(pos, jnp.int32),
+                job["last_idx"],
+                job["aids"],
+            )
         for i in range(len(job["items"])):
             if pos <= job["last_idx_host"][i] < pos + chunk:
                 job["logits"][i] = logits_rows[i]

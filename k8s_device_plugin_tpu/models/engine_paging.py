@@ -16,11 +16,14 @@ from typing import Any, Optional
 import jax.numpy as jnp
 import numpy as np
 
+from .engine_profiler import in_phase
+
 
 class PagingMixin:
     """Page allocation/free, prefix-sharing trie, frontier publication,
     windowed reclamation, and the prefill->pages graft."""
 
+    @in_phase("prefill.graft")
     def _graft(
         self,
         slot: int,
@@ -116,6 +119,7 @@ class PagingMixin:
                     )
             self.cache[name]["attn"] = new_att
 
+    @in_phase("finish.clear_slot")
     def _clear_slot(self, slot: int):
         if self._derive_tables:
             # One chain-row zero; per-layer cache tables are derived
@@ -256,6 +260,7 @@ class PagingMixin:
                     self._child_keys.setdefault(parent, []).append(key)
             parent = self._prefix_pages[key]
 
+    @in_phase("dispatch.frontier")
     def _ensure_frontier(self, active: list[int], lookahead: int) -> list[int]:
         """Make every coming write in [len, len+lookahead] addressable for
         each active slot, then publish the covering pages.

@@ -132,7 +132,8 @@ def build_step_fn(model, filtered: bool, want_lp: bool, biased: bool = False,
               topks=None, topps=None, bias_ids=None, bias_vals=None):
         key, sub = jax.random.split(key)
         if derive_tables:
-            cache = _derived_tables(cache, chain, positions, page_size)
+            with jax.named_scope("derive_tables"):
+                cache = _derived_tables(cache, chain, positions, page_size)
         logits, mut = model.apply(
             {"params": params, "cache": cache},
             tokens,
@@ -140,26 +141,32 @@ def build_step_fn(model, filtered: bool, want_lp: bool, biased: bool = False,
             adapter_ids=aids,
             mutable=["cache"],
         )
-        row = logits[:, -1, :]
-        pick = row
-        if biased:
-            rows = jnp.arange(row.shape[0])[:, None]
-            pick = row.at[rows, bias_ids].add(
-                bias_vals.astype(row.dtype)
+        # Sampling lies outside every Flax module: a scope of its own
+        # names its operations in a device trace.
+        with jax.named_scope("sample"):
+            row = logits[:, -1, :]
+            pick = row
+            if biased:
+                rows = jnp.arange(row.shape[0])[:, None]
+                pick = row.at[rows, bias_ids].add(
+                    bias_vals.astype(row.dtype)
+                )
+            greedy = jnp.argmax(pick, axis=-1).astype(jnp.int32)
+            # One categorical over the batch samples each row
+            # independently; temp<=0 rows take the argmax (their scaled
+            # logits are unused).
+            scaled = pick / jnp.where(temps > 0, temps, 1.0)[:, None]
+            if filtered:
+                scaled = filter_top_k_top_p(scaled, topks, topps)
+            sampled = jax.random.categorical(sub, scaled).astype(jnp.int32)
+            nxt = jnp.where(temps > 0, sampled, greedy)
+            out = (
+                jnp.stack(
+                    [nxt.astype(jnp.float32), _token_logprob(row, nxt)]
+                )
+                if want_lp
+                else nxt
             )
-        greedy = jnp.argmax(pick, axis=-1).astype(jnp.int32)
-        # One categorical over the batch samples each row independently;
-        # temp<=0 rows take the argmax (their scaled logits are unused).
-        scaled = pick / jnp.where(temps > 0, temps, 1.0)[:, None]
-        if filtered:
-            scaled = filter_top_k_top_p(scaled, topks, topps)
-        sampled = jax.random.categorical(sub, scaled).astype(jnp.int32)
-        nxt = jnp.where(temps > 0, sampled, greedy)
-        out = (
-            jnp.stack([nxt.astype(jnp.float32), _token_logprob(row, nxt)])
-            if want_lp
-            else nxt
-        )
         return out, nxt[:, None], positions + 1, key, mut["cache"]
 
     extra = (["chain"] if derive_tables else []) + variant_names(
@@ -203,7 +210,8 @@ def build_block_fn(model, T: int, filtered: bool, want_lp: bool,
         def body(carry, k):
             cache, toks, pos = carry
             if derive_tables:
-                cache = _derived_tables(cache, chain, pos, page_size)
+                with jax.named_scope("derive_tables"):
+                    cache = _derived_tables(cache, chain, pos, page_size)
             logits, mut = model.apply(
                 {"params": params, "cache": cache},
                 toks,
@@ -211,20 +219,21 @@ def build_block_fn(model, T: int, filtered: bool, want_lp: bool,
                 adapter_ids=aids,
                 mutable=["cache"],
             )
-            row = logits[:, -1, :]
-            pick = row
-            if biased:
-                rows = jnp.arange(row.shape[0])[:, None]
-                pick = row.at[rows, bias_ids].add(
-                    bias_vals.astype(row.dtype)
-                )
-            greedy = jnp.argmax(pick, axis=-1).astype(jnp.int32)
-            scaled = pick / jnp.where(temps > 0, temps, 1.0)[:, None]
-            if filtered:
-                scaled = filter_top_k_top_p(scaled, topks, topps)
-            sampled = jax.random.categorical(k, scaled).astype(jnp.int32)
-            nxt = jnp.where(temps > 0, sampled, greedy)
-            ys = (nxt, _token_logprob(row, nxt)) if want_lp else nxt
+            with jax.named_scope("sample"):
+                row = logits[:, -1, :]
+                pick = row
+                if biased:
+                    rows = jnp.arange(row.shape[0])[:, None]
+                    pick = row.at[rows, bias_ids].add(
+                        bias_vals.astype(row.dtype)
+                    )
+                greedy = jnp.argmax(pick, axis=-1).astype(jnp.int32)
+                scaled = pick / jnp.where(temps > 0, temps, 1.0)[:, None]
+                if filtered:
+                    scaled = filter_top_k_top_p(scaled, topks, topps)
+                sampled = jax.random.categorical(k, scaled).astype(jnp.int32)
+                nxt = jnp.where(temps > 0, sampled, greedy)
+                ys = (nxt, _token_logprob(row, nxt)) if want_lp else nxt
             return (mut["cache"], nxt[:, None], pos + 1), ys
 
         (cache, last_tok, last_pos), ys = jax.lax.scan(

@@ -223,106 +223,106 @@ class SpeculativeMixin:
         emit EXACTLY their non-speculative greedy decode; sampled slots
         emit marginally exact filtered target samples (both pinned in
         tests/test_engine.py); speculation changes only the schedule."""
-        active = self._ensure_frontier(active, self._spec_gamma)
-        if not active:
-            self._update_gauges()
-            return finished
-        round_t0 = time.monotonic()
-        tokens = jnp.asarray(self._slot_last, jnp.int32)[:, None]
-        positions = jnp.asarray(self._slot_len, jnp.int32)[:, None]
-        if any(
-            self.slots[s] is not None and self._slot_temp[s] > 0
-            for s in range(self.max_slots)
-        ):
-            temps = jnp.asarray(self._slot_temp, jnp.float32)
-            topks = jnp.asarray(self._slot_topk, jnp.int32)
-            topps = jnp.asarray(self._slot_topp, jnp.float32)
-            self._rng, sub = jax.random.split(self._rng)
-            emitted, a_vec, self.cache = self._spec_round(
-                self.params, self.draft_params, self.cache, tokens,
-                positions, temps, topks, topps, sub,
-            )
-        else:
-            emitted, a_vec, self.cache = self._spec_round_plain(
-                self.params, self.draft_params, self.cache, tokens, positions
-            )
-        emitted = np.asarray(emitted)
-        a_vec = np.asarray(a_vec)
-        self._mark("spec_verify")
-        now = time.monotonic()
-        if self.spans:
-            # One engine-scoped span per draft+verify round: acceptance
-            # attrs make a low-acceptance regime visible right next to
-            # the round's wall time in /debug/state.
-            self.spans.record_span(
-                "spec.verify",
-                ENGINE_TRACE,
-                start_monotonic=round_t0,
-                end_monotonic=now,
-                attrs={
-                    "slots": len(active),
-                    "proposed": int(self._spec_gamma) * len(active),
-                    "accepted": int(sum(a_vec[s] for s in active)),
-                },
-            )
-        gamma = self._spec_gamma
-        emitted_total = 0
-        for s in active:
-            req = self.slots[s]
-            a = int(a_vec[s])
-            # Emit d_1..d_a then the target's own token at position a
-            # (correction on rejection, bonus on full accept).  All a+1
-            # tokens are consumed unless a finish condition truncates —
-            # and truncation only ever coincides with req.done, so live
-            # slots always consume exactly a+1.
-            self.spec_proposed += gamma
-            self.spec_accepted += a
-            if self.metrics:
-                self.metrics.spec_proposed.inc(gamma)
-                self.metrics.spec_accepted.inc(a)
-                if gamma > a:
-                    self.metrics.spec_rejected.inc(gamma - a)
-            round_toks = [int(emitted[s, j]) for j in range(a + 1)]
-            consumed = 0
-            for tok in round_toks:
-                req.tokens.append(tok)
-                self._slot_last[s] = tok
-                consumed += 1
-                emitted_total += 1
-                if (
-                    len(req.tokens) >= req.max_new_tokens
-                    or (self.eos_id is not None and tok == self.eos_id)
-                    or self._hit_stop(req)
-                ):
-                    break
-            self._slot_len[s] += consumed
-            self._observe_itl(s, consumed, now)
-            self._maybe_finish(s)
-            if req.done:
-                finished.append(req)
+        with self.profiler.phase("spec_verify"):
+            active = self._ensure_frontier(active, self._spec_gamma)
+            if not active:
+                self._update_gauges()
+                return finished
+            round_t0 = time.monotonic()
+            tokens = jnp.asarray(self._slot_last, jnp.int32)[:, None]
+            positions = jnp.asarray(self._slot_len, jnp.int32)[:, None]
+            if any(
+                self.slots[s] is not None and self._slot_temp[s] > 0
+                for s in range(self.max_slots)
+            ):
+                temps = jnp.asarray(self._slot_temp, jnp.float32)
+                topks = jnp.asarray(self._slot_topk, jnp.int32)
+                topps = jnp.asarray(self._slot_topp, jnp.float32)
+                self._rng, sub = jax.random.split(self._rng)
+                emitted, a_vec, self.cache = self._spec_round(
+                    self.params, self.draft_params, self.cache, tokens,
+                    positions, temps, topks, topps, sub,
+                )
             else:
-                self._extend_frontier(s)
-                if self.cfg.attention_window is not None:
-                    self._reclaim_windowed(s)
-        # The round left every row's device length at L+gamma+1; re-align
-        # all rows to the host truth in one vector write per layer (idle
-        # and just-cleared rows are 0 in _slot_len, matching _clear_slot).
-        # A FRESH array per layer: sharing one across layers would hand
-        # the next round's donation the same buffer twice, which XLA
-        # rejects (donate(a), donate(a)).
-        for name in self._layer_names:
-            att = self.cache[name]["attn"]
-            self.cache[name]["attn"] = {
-                **att,
-                "seq_lens": jnp.array(self._slot_len, jnp.int32),
-            }
-        # Rounds advance each slot by a data-dependent 1..gamma+1: the
-        # device-resident step state cannot be fed forward (engine.py).
-        self._mark_state_dirty()
-        self._mark("sample")
-        self._step_tokens += emitted_total
-        if self.metrics:
-            self.metrics.steps.inc()
-            self.metrics.tokens.inc(emitted_total)
-        self._update_gauges()
+                emitted, a_vec, self.cache = self._spec_round_plain(
+                    self.params, self.draft_params, self.cache, tokens, positions
+                )
+            emitted = np.asarray(emitted)
+            a_vec = np.asarray(a_vec)
+        with self.profiler.phase("sample"):
+            now = time.monotonic()
+            if self.spans:
+                # One engine-scoped span per draft+verify round: acceptance
+                # attrs make a low-acceptance regime visible right next to
+                # the round's wall time in /debug/state.
+                self.spans.record_span(
+                    "spec.verify",
+                    ENGINE_TRACE,
+                    start_monotonic=round_t0,
+                    end_monotonic=now,
+                    attrs={
+                        "slots": len(active),
+                        "proposed": int(self._spec_gamma) * len(active),
+                        "accepted": int(sum(a_vec[s] for s in active)),
+                    },
+                )
+            gamma = self._spec_gamma
+            emitted_total = 0
+            for s in active:
+                req = self.slots[s]
+                a = int(a_vec[s])
+                # Emit d_1..d_a then the target's own token at position a
+                # (correction on rejection, bonus on full accept).  All a+1
+                # tokens are consumed unless a finish condition truncates —
+                # and truncation only ever coincides with req.done, so live
+                # slots always consume exactly a+1.
+                self.spec_proposed += gamma
+                self.spec_accepted += a
+                if self.metrics:
+                    self.metrics.spec_proposed.inc(gamma)
+                    self.metrics.spec_accepted.inc(a)
+                    if gamma > a:
+                        self.metrics.spec_rejected.inc(gamma - a)
+                round_toks = [int(emitted[s, j]) for j in range(a + 1)]
+                consumed = 0
+                for tok in round_toks:
+                    req.tokens.append(tok)
+                    self._slot_last[s] = tok
+                    consumed += 1
+                    emitted_total += 1
+                    if (
+                        len(req.tokens) >= req.max_new_tokens
+                        or (self.eos_id is not None and tok == self.eos_id)
+                        or self._hit_stop(req)
+                    ):
+                        break
+                self._slot_len[s] += consumed
+                self._observe_itl(s, consumed, now)
+                self._maybe_finish(s)
+                if req.done:
+                    finished.append(req)
+                else:
+                    self._extend_frontier(s)
+                    if self.cfg.attention_window is not None:
+                        self._reclaim_windowed(s)
+            # The round left every row's device length at L+gamma+1; re-align
+            # all rows to the host truth in one vector write per layer (idle
+            # and just-cleared rows are 0 in _slot_len, matching _clear_slot).
+            # A FRESH array per layer: sharing one across layers would hand
+            # the next round's donation the same buffer twice, which XLA
+            # rejects (donate(a), donate(a)).
+            for name in self._layer_names:
+                att = self.cache[name]["attn"]
+                self.cache[name]["attn"] = {
+                    **att,
+                    "seq_lens": jnp.array(self._slot_len, jnp.int32),
+                }
+            # Rounds advance each slot by a data-dependent 1..gamma+1: the
+            # device-resident step state cannot be fed forward (engine.py).
+            self._mark_state_dirty()
+            self._step_tokens += emitted_total
+            if self.metrics:
+                self.metrics.steps.inc()
+                self.metrics.tokens.inc(emitted_total)
+            self._update_gauges()
         return finished

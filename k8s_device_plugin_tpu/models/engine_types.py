@@ -98,6 +98,97 @@ class EngineMetrics:
             "overlap_hits says traffic churns too fast for "
             "--overlap-steps 1 to pay off",
         )
+        # The owner loop's phases (models/engine_profiler.py): one
+        # unlabelled counter of seconds per phase, so a scraper that sums
+        # label sets under the bare name still reads each apart.  The
+        # seven step phases plus idle partition the owner thread's time;
+        # a sub-phase's seconds also count in the phase it nests in.
+        self.loop_seconds = {
+            "schedule": registry.counter(
+                "tpu_engine_loop_schedule_seconds_total",
+                "Owner-loop seconds in admission, overload and cancel sweeps",
+            ),
+            "prefill": registry.counter(
+                "tpu_engine_loop_prefill_seconds_total",
+                "Owner-loop seconds advancing prefill jobs: chunk "
+                "dispatches, the graft into pages, first-token sampling",
+            ),
+            "dispatch": registry.counter(
+                "tpu_engine_loop_dispatch_seconds_total",
+                "Owner-loop seconds enqueueing decode dispatches "
+                "(frontier ensure included)",
+            ),
+            "readback": registry.counter(
+                "tpu_engine_loop_readback_seconds_total",
+                "Owner-loop seconds blocked on the device->host sync of "
+                "a decode dispatch: the one phase that waits for the chip",
+            ),
+            "sample": registry.counter(
+                "tpu_engine_loop_sample_seconds_total",
+                "Owner-loop seconds consuming tokens on the host with "
+                "nothing in flight",
+            ),
+            "host_gap": registry.counter(
+                "tpu_engine_loop_host_gap_seconds_total",
+                "Owner-loop seconds consuming tokens on the host while "
+                "the next dispatch computes on device",
+            ),
+            "spec_verify": registry.counter(
+                "tpu_engine_loop_spec_verify_seconds_total",
+                "Owner-loop seconds in speculative draft+verify rounds",
+            ),
+            "idle": registry.counter(
+                "tpu_engine_loop_idle_seconds_total",
+                "Owner-loop seconds waiting for work (no queued request, "
+                "no occupied slot)",
+            ),
+            "schedule.start_prefill": registry.counter(
+                "tpu_engine_loop_start_prefill_seconds_total",
+                "Seconds building prefill jobs for admission groups "
+                "(counted in schedule too)",
+            ),
+            "prefill.chunk": registry.counter(
+                "tpu_engine_loop_prefill_chunk_seconds_total",
+                "Seconds enqueueing prefill chunk programs (counted in "
+                "prefill too)",
+            ),
+            "prefill.graft": registry.counter(
+                "tpu_engine_loop_graft_seconds_total",
+                "Seconds copying prefilled K/V into pages, eagerly "
+                "(counted in prefill too)",
+            ),
+            "dispatch.frontier": registry.counter(
+                "tpu_engine_loop_frontier_seconds_total",
+                "Seconds making the coming writes addressable: page "
+                "allocation, preemption, publication (counted in "
+                "dispatch too)",
+            ),
+            "finish.clear_slot": registry.counter(
+                "tpu_engine_loop_clear_slot_seconds_total",
+                "Seconds tearing slots down when a request ends or is "
+                "evicted (counted in the phase that ended it)",
+            ),
+        }
+        self.loop_counts = {
+            "prefill.chunk": registry.counter(
+                "tpu_engine_prefill_chunks_total",
+                "Prefill chunk programs enqueued",
+            ),
+            "finish.clear_slot": registry.counter(
+                "tpu_engine_cleared_slots_total",
+                "Slots torn down (finish, cancel, eviction)",
+            ),
+        }
+        self.decode_dispatches_block = registry.counter(
+            "tpu_engine_decode_dispatches_block_total",
+            "Decode dispatches that ran a multi-step block program",
+        )
+        self.decode_dispatches_step = registry.counter(
+            "tpu_engine_decode_dispatches_step_total",
+            "Decode dispatches that ran the single-step program (a host "
+            "round trip per token: what the engine falls back to while "
+            "a request waits to be admitted)",
+        )
         self.step_seconds = registry.histogram(
             "tpu_engine_step_seconds",
             "Wall time of one engine step() call (admission + dispatch + "

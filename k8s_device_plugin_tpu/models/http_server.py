@@ -111,12 +111,15 @@ rest of the models/ stack which benchmarks on synthetic ids):
     response's ``X-Request-Id`` header and ``trace_id`` JSON field, on
     every SSE event, and on every span the request records — one grep
     key from client log to engine telemetry.
-    POST /debug/trace {"seconds": s?}   [opt-in: --debug-trace]
-      -> 200 {"trace_dir": ...} after capturing a jax.profiler trace of
-         the live serving loop (XProf/Perfetto); 409 while one runs;
-         404 unless the operator enabled the endpoint.
-    POST /debug/profile/capture {"steps": n?, "timeout_s": t?}
+    POST /debug/trace {"seconds": s?, "python_frames": b?}
          [opt-in: --debug-trace]
+      -> 200 {"trace_dir": ...} after capturing a jax.profiler trace of
+         the live serving loop (XProf/Perfetto): the device's operations
+         with the owner loop's phases beside them as ``engine.<phase>``
+         events; Python's tracer only with "python_frames": true; 409
+         while one runs; 404 unless the operator enabled the endpoint.
+    POST /debug/profile/capture {"steps": n?, "timeout_s": t?,
+         "python_frames": b?}   [opt-in: --debug-trace]
       -> 200 {"trace_dir", "steps_captured"} after capturing a
          jax.profiler trace spanning the next n engine steps (default 1)
          — the device-op view of exactly the step(s) the host-side
@@ -808,25 +811,60 @@ class EngineServer:
                     ]
                 self._reply(200, out, trace_id)
 
-            def _trace_capture(self) -> None:
-                """POST /debug/trace {"seconds": s?}: capture
-                a jax.profiler trace of the LIVE serving loop (XLA op
-                timelines, HBM, collectives — loads in XProf/Perfetto)
-                for s seconds and reply with the server-chosen trace
-                dir.  The capture rides this handler thread while the
-                owner loop keeps stepping, which is the point; one
-                capture at a time (409 while busy), seconds clamped to
-                (0, 30]."""
-                import math
+            def _capture_body(self) -> dict:
+                """The JSON object a capture endpoint was posted
+                (TypeError/ValueError on anything else)."""
+                length = int(self.headers.get("Content-Length", "0"))
+                body = json.loads(self.rfile.read(length) or b"{}")
+                if not isinstance(body, dict):
+                    raise TypeError(f"body must be an object, got {body!r}")
+                return body
+
+            def _capture(self, prefix: str, python_frames: bool, run) -> Optional[str]:
+                """One jax.profiler capture around ``run()`` into a fresh
+                SERVER-chosen dir (clients must not direct profiler
+                writes at arbitrary paths); replies 409 while another
+                capture runs and 500 if the profiler fails.  Returns the
+                dir, or None once it has replied."""
+                import shutil
                 import tempfile
 
-                import jax
+                from ..utils import tracing
+
+                if not server._trace_lock.acquire(blocking=False):
+                    self._reply(409, {"error": "a trace capture is already running"})
+                    return None
+                try:
+                    # Lock first, THEN mkdtemp: a 409 poll loop must not
+                    # mint an orphan dir per attempt.
+                    tdir = tempfile.mkdtemp(prefix=prefix)
+                    try:
+                        with tracing.trace(tdir, python_frames=python_frames):
+                            run()
+                    except Exception as e:  # profiler state is global: report, not crash
+                        log.warning("profiler capture failed: %s", e)
+                        shutil.rmtree(tdir, ignore_errors=True)
+                        self._reply(500, {"error": f"trace failed: {e}"})
+                        return None
+                    return tdir
+                finally:
+                    server._trace_lock.release()
+
+            def _trace_capture(self) -> None:
+                """POST /debug/trace {"seconds": s?, "python_frames": b?}:
+                capture a jax.profiler trace of the LIVE serving loop
+                (XLA op timelines, HBM, collectives, and the owner
+                loop's ``engine.<phase>`` events beside them — loads in
+                XProf/Perfetto) for s seconds and reply with the
+                server-chosen trace dir.  The capture rides this handler
+                thread while the owner loop keeps stepping, which is the
+                point; one capture at a time (409 while busy), seconds
+                clamped to (0, 30].  Python's tracer stays off (it slows
+                the loop it measures) unless ``python_frames`` is true."""
+                import math
 
                 try:
-                    length = int(self.headers.get("Content-Length", "0"))
-                    body = json.loads(self.rfile.read(length) or b"{}")
-                    if not isinstance(body, dict):
-                        raise TypeError(f"body must be an object, got {body!r}")
+                    body = self._capture_body()
                     seconds = float(body.get("seconds", 2.0))
                     if not math.isfinite(seconds):
                         raise ValueError(f"seconds must be finite, got {seconds}")
@@ -834,63 +872,26 @@ class EngineServer:
                 except (TypeError, ValueError) as e:
                     self._reply(400, {"error": f"bad request: {e}"})
                     return
-                if not server._trace_lock.acquire(blocking=False):
-                    self._reply(409, {"error": "a trace capture is already running"})
-                    return
-                # Lock first, THEN mkdtemp: a 409 poll loop must not mint
-                # an orphan dir per attempt.  The dir is SERVER-chosen —
-                # clients must not direct profiler writes at arbitrary
-                # paths.
-                tdir = tempfile.mkdtemp(prefix="tpu-serving-trace-")
-                started = False
-                try:
-                    jax.profiler.start_trace(tdir)
-                    started = True
-                    time.sleep(seconds)
-                except Exception as e:  # profiler state is global: report, not crash
-                    self._reply(500, {"error": f"trace failed: {e}"})
-                    if not started:
-                        import shutil
-
-                        shutil.rmtree(tdir, ignore_errors=True)
-                    return
-                finally:
-                    if started:
-                        try:
-                            # Always unwound, or the global profiler stays
-                            # started and bricks every later capture.
-                            jax.profiler.stop_trace()
-                        except Exception as e:
-                            # A failed unwind is exactly the bricked
-                            # state the comment above warns about —
-                            # swallowing it silently would make every
-                            # later capture fail with no cause on
-                            # record.
-                            log.warning(
-                                "jax.profiler.stop_trace failed; later "
-                                "captures may be bricked: %s", e,
-                            )
-                    server._trace_lock.release()
-                self._reply(200, {"trace_dir": tdir, "seconds": seconds})
+                tdir = self._capture(
+                    "tpu-serving-trace-",
+                    bool(body.get("python_frames", False)),
+                    lambda: time.sleep(seconds),
+                )
+                if tdir is not None:
+                    self._reply(200, {"trace_dir": tdir, "seconds": seconds})
 
             def _step_capture(self) -> None:
-                """POST /debug/profile/capture {"steps": n?, "timeout_s"?}:
-                capture a jax.profiler trace spanning the next n engine
-                steps — the device-op (XProf/Perfetto) view of exactly
-                what /debug/profile summarizes host-side.  Step
+                """POST /debug/profile/capture {"steps": n?, "timeout_s"?,
+                "python_frames"?}: capture a jax.profiler trace spanning
+                the next n engine steps — the device-op (XProf/Perfetto)
+                view of exactly what /debug/profile summarizes host-side,
+                with the same phases as ``engine.<phase>`` events.  Step
                 completion is watched via the profiler's step counter on
                 the server condition; an idle engine simply times out
                 with steps_captured 0 (capture while traffic flows).
                 Shares the one-capture-at-a-time lock with /debug/trace."""
-                import tempfile
-
-                from ..utils import tracing
-
                 try:
-                    length = int(self.headers.get("Content-Length", "0"))
-                    body = json.loads(self.rfile.read(length) or b"{}")
-                    if not isinstance(body, dict):
-                        raise TypeError(f"body must be an object, got {body!r}")
+                    body = self._capture_body()
                     steps = int(body.get("steps", 1))
                     if not 1 <= steps <= 64:
                         raise ValueError(f"steps must be in [1, 64], got {steps}")
@@ -898,35 +899,32 @@ class EngineServer:
                 except (TypeError, ValueError) as e:
                     self._reply(400, {"error": f"bad request: {e}"})
                     return
-                if not server._trace_lock.acquire(blocking=False):
-                    self._reply(409, {"error": "a trace capture is already running"})
-                    return
-                tdir = tempfile.mkdtemp(prefix="tpu-step-trace-")
                 profiler = server.engine.profiler
                 start = profiler.steps
-                target = start + steps
                 deadline = time.monotonic() + timeout_s
-                try:
-                    with tracing.trace(tdir):
-                        while (
-                            profiler.steps < target
-                            and time.monotonic() < deadline
-                        ):
-                            with server._cond:
-                                server._cond.wait(timeout=0.05)
-                except Exception as e:  # profiler state is global: report
-                    self._reply(500, {"error": f"trace failed: {e}"})
-                    return
-                finally:
-                    server._trace_lock.release()
-                self._reply(
-                    200,
-                    {
-                        "trace_dir": tdir,
-                        "steps_requested": steps,
-                        "steps_captured": min(profiler.steps - start, steps),
-                    },
+
+                def until_stepped():
+                    while (
+                        profiler.steps < start + steps
+                        and time.monotonic() < deadline
+                    ):
+                        with server._cond:
+                            server._cond.wait(timeout=0.05)
+
+                tdir = self._capture(
+                    "tpu-step-trace-",
+                    bool(body.get("python_frames", False)),
+                    until_stepped,
                 )
+                if tdir is not None:
+                    self._reply(
+                        200,
+                        {
+                            "trace_dir": tdir,
+                            "steps_requested": steps,
+                            "steps_captured": min(profiler.steps - start, steps),
+                        },
+                    )
 
             def _shed_reply(
                 self,
@@ -1737,7 +1735,8 @@ class EngineServer:
                     )
                     if not has_work:
                         # Idle: wait for a submit (or shutdown poke).
-                        self._cond.wait(timeout=0.1)
+                        with self.engine.profiler.phase("idle"):
+                            self._cond.wait(timeout=0.1)
                         continue
                 self.engine.step()  # outside the lock: submit never blocks on jit
                 with self._cond:

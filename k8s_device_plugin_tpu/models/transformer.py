@@ -460,13 +460,16 @@ class CausalSelfAttention(nn.Module):
                     num_splits=pg.kernel_num_splits,
                 )[:, None]
             else:
-                # Gather each row's pages into its logical [max_len] view.
-                kr = pk.value[table.value].reshape(
-                    batch, pg.max_len, cfg.kv_heads, cfg.head_dim
-                )
-                vr = pv.value[table.value].reshape(
-                    batch, pg.max_len, cfg.kv_heads, cfg.head_dim
-                )
+                # Gather each row's pages into its logical [max_len] view
+                # (a scope of its own: in a device trace the gathered copy
+                # is otherwise only ``%copy.N`` under ``attn``).
+                with jax.named_scope("paged_gather"):
+                    kr = pk.value[table.value].reshape(
+                        batch, pg.max_len, cfg.kv_heads, cfg.head_dim
+                    )
+                    vr = pv.value[table.value].reshape(
+                        batch, pg.max_len, cfg.kv_heads, cfg.head_dim
+                    )
                 if cfg.quant_kv:
                     # int8 stays the HBM format; the dequant fuses into
                     # the gather/einsum reads (≙ the dense quant_kv path).

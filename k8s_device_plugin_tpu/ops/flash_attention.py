@@ -362,6 +362,7 @@ def _flash_impl(
     )
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",  # stable in a device trace
         grid=(bh, num_q_blocks, num_kv_blocks),
         in_specs=[
             pl.BlockSpec((1, block_q, head_dim), lambda b, qi, ki: (b, qi, 0)),
@@ -610,6 +611,7 @@ def _flash_bwd_pallas(
             block_kv=block_kv,
             num_kv_blocks=num_kv_blocks,
         ),
+        name="flash_attention_bwd_dq",
         grid=(bh, num_q_blocks, num_kv_blocks),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec],
         out_specs=pl.BlockSpec((1, block_q, head_dim), lambda b, qi, ki: (b, qi, 0)),
@@ -641,6 +643,7 @@ def _flash_bwd_pallas(
             num_q_blocks=num_q_blocks,
             group=group,
         ),
+        name="flash_attention_bwd_dkv",
         grid=(batch * kv_heads, num_kv_blocks, group * num_q_blocks),
         in_specs=[
             pl.BlockSpec((1, block_q, head_dim), q_index),

@@ -364,6 +364,7 @@ def _paged_pallas(
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_attention_decode",  # stable in a device trace
         grid_spec=grid_spec,
         out_shape=out_shape,
         # batch and split axes are independent; the page axis carries the

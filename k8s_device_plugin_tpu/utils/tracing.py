@@ -5,8 +5,8 @@ Two layers:
 
 - Workload (device) side: ``trace()`` wraps a region in a jax.profiler trace
   whose output loads in TensorBoard/XProf or Perfetto — XLA op timelines,
-  HBM usage, ICI collective timing.  ``annotate()`` names a region so host
-  Python shows up aligned with device ops.
+  HBM usage, ICI collective timing.  The serving loop's phases show up in
+  it as ``engine.<phase>`` events (models/engine_profiler.py).
 - Daemon (host) side: ``timed_rpc`` decorates gRPC servicer methods with
   wall-time logging, optional metrics-registry observation, AND a
   daemon-side span into the utils/spans.py ring — one tracing story with
@@ -20,7 +20,6 @@ import contextlib
 import functools
 import logging
 import os
-import threading
 import time
 from typing import Iterator, Optional
 
@@ -28,23 +27,19 @@ from .spans import DAEMON_TRACE
 
 log = logging.getLogger(__name__)
 
-# Traces started through this module, counted so annotate() can tell
-# whether naming a region would reach a profiler at all.
-_active_traces = 0
-_active_lock = threading.Lock()
-
-
-def trace_active() -> bool:
-    """True while a jax.profiler trace started via :func:`trace` runs."""
-    return _active_traces > 0
-
-
 @contextlib.contextmanager
-def trace(trace_dir: Optional[str]) -> Iterator[None]:
+def trace(trace_dir: Optional[str], python_frames: bool = False) -> Iterator[None]:
     """Capture a jax.profiler trace of the enclosed region into
     ``trace_dir`` (no-op when trace_dir is falsy, so callers can wire it
-    straight to an optional flag/env)."""
-    global _active_traces
+    straight to an optional flag/env).  The one way this program starts
+    a capture.
+
+    Python's tracer is off unless ``python_frames``: under it
+    ``sys.setprofile`` runs in every thread, which slows the host code
+    being measured and fills the capture with a frame per call.  The
+    host's TraceMe events stay either way: the runtime's own
+    (``PjitFunction(...)``) and the serving loop's ``engine.<phase>``
+    annotations (models/engine_profiler.py)."""
     if not trace_dir:
         yield
         return
@@ -52,33 +47,9 @@ def trace(trace_dir: Optional[str]) -> Iterator[None]:
 
     os.makedirs(trace_dir, exist_ok=True)
     log.info("profiler trace -> %s", trace_dir)
-    with jax.profiler.trace(trace_dir):
-        with _active_lock:
-            _active_traces += 1
-        try:
-            yield
-        finally:
-            with _active_lock:
-                _active_traces -= 1
-
-
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named sub-region inside an active trace (TraceAnnotation).
-
-    A guaranteed no-op when no profiler trace (started via this module)
-    is active or when jax is unavailable, so host-only callers — the
-    plugin daemon runs in an image that need not ship jax — can
-    annotate hot regions unconditionally."""
-    if not trace_active():
-        yield
-        return
-    try:
-        import jax
-    except ImportError:
-        yield
-        return
-    with jax.profiler.TraceAnnotation(name):
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = int(python_frames)
+    with jax.profiler.trace(trace_dir, profiler_options=options):
         yield
 
 

@@ -26,10 +26,23 @@ def test_trace_writes_profile(tmp_path):
     assert files, "profiler produced no output"
 
 
-def test_annotate_runs_inside_trace(tmp_path):
-    with tracing.trace(str(tmp_path / "t")):
-        with tracing.annotate("test-region"):
-            jnp.ones((8, 8)).sum().block_until_ready()
+@pytest.mark.parametrize("python_frames", [False, True])
+def test_trace_runs_pythons_tracer_only_when_asked(tmp_path, python_frames):
+    """The capture holds the host's TraceMe events either way (a
+    TraceAnnotation among them); a ``$file:line fn`` event per Python
+    call only with ``python_frames``."""
+    from chipbench import trace as bench_trace
+
+    def work():
+        return jnp.ones((8, 8)).sum()
+
+    with tracing.trace(str(tmp_path), python_frames=python_frames):
+        with jax.profiler.TraceAnnotation("test-region"):
+            work().block_until_ready()
+    profile = bench_trace.load(bench_trace.find_xplane(str(tmp_path)))
+    names = {e.name for p in profile.planes for ln in p.lines for e in ln.events}
+    assert "test-region" in names
+    assert any(n.startswith("$") for n in names) == python_frames
 
 
 def test_timed_rpc_observes_and_logs(caplog):
@@ -120,33 +133,6 @@ def test_benchmark_pipelined_1f1b_smoke(capsys):
     assert out["model"] == "gpt-pp"
     assert out["schedule"] == "1f1b"
     assert out["throughput"] > 0
-
-
-def test_annotate_noop_outside_trace():
-    """annotate() outside any module-started trace is a pure no-op (and
-    must not import-require jax at all on that path)."""
-    assert not tracing.trace_active()
-    with tracing.annotate("outside"):
-        pass
-
-
-def test_annotate_noop_when_jax_unavailable(monkeypatch):
-    """Host-only callers (the plugin daemon image need not ship jax) can
-    annotate freely: an unimportable jax degrades to a no-op even while
-    a trace is marked active."""
-    import sys
-
-    monkeypatch.setattr(tracing, "_active_traces", 1)
-    monkeypatch.setitem(sys.modules, "jax", None)  # import jax -> ImportError
-    with tracing.annotate("no-jax"):
-        pass
-
-
-def test_trace_active_tracks_module_traces(tmp_path):
-    assert not tracing.trace_active()
-    with tracing.trace(str(tmp_path / "t2")):
-        assert tracing.trace_active()
-    assert not tracing.trace_active()
 
 
 def test_timed_rpc_records_daemon_span():
