@@ -383,6 +383,10 @@ class ServingEngine(
         self._lock = threading.RLock()
         self._next_rid = 0
         self._prefill_cache: dict[int, Any] = {}
+        # Compiled writers into the device cache tree
+        # (engine_paging._cache_write) and their dispatches by operation.
+        self._cache_writers: dict[tuple, Any] = {}
+        self.cache_write_dispatches = {"graft": 0, "slot": 0}
         self._rng = self._rep(jax.random.PRNGKey(0) if rng is None else rng)
         # Device-resident step state: the per-slot arrays the jitted step
         # consumes (tokens/positions/temps/aids/filters/biases/key) live

@@ -429,9 +429,6 @@ class HandoffMixin:
         returns False and the ordinary admission runs (the covered
         chunks still skip via the seeded dense cache).  Caller holds
         the lock; mirrors ``_kv_try_restore_resume``'s discipline."""
-        import jax.numpy as jnp
-        import numpy as _np  # noqa: F401 — rows stay host-side
-
         ps = self.paged.page_size
         eff = req.prompt
         plen = len(eff)
@@ -497,32 +494,12 @@ class HandoffMixin:
         req.tokens.append(first)
 
         # Slot state: the _graft/_activate table discipline without a
-        # graft (every row is already in place) — see the identical
-        # block in _kv_try_restore_resume.
-        n_publish = min((plen + self._spec_gamma) // ps + 1, len(pages))
-        if self._derive_tables:
-            import numpy as np
-
-            full = np.zeros((self.paged.max_pages_per_seq,), np.int32)
-            full[: len(pages)] = pages
-            self._chain = self._chain.at[slot].set(jnp.asarray(full))
-        else:
-            import numpy as np
-
-            row = np.zeros((self.paged.max_pages_per_seq,), np.int32)
-            row[:n_publish] = pages[:n_publish]
-        for name in self._layer_names:
-            att = self.cache[name]["attn"]
-            new_att = {**att, "seq_lens": att["seq_lens"].at[slot].set(plen)}
-            if not self._derive_tables:
-                new_att["page_table"] = (
-                    att["page_table"].at[slot].set(jnp.asarray(row))
-                )
-            self.cache[name]["attn"] = new_att
+        # graft (every row is already in place) — as in
+        # _kv_try_restore_resume.
+        self._set_slot_row(slot, plen, pages)
         self.slots[slot] = req
         self._slot_pages[slot] = pages
         self._slot_page_base[slot] = 0
-        self._slot_visible[slot] = n_publish
         self._slot_len[slot] = plen
         self._slot_last[slot] = first
         self._slot_seq[slot] = self._seq_counter

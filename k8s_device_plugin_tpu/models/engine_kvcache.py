@@ -529,26 +529,10 @@ class KVCacheMixin:
         # pool writes (the rows are already in place) or the admission
         # token (req.tokens already carries it — it is the pending last
         # token the next decode step feeds at position L).
-        n_publish = min((L + self._spec_gamma) // ps + 1, len(pages))
-        if self._derive_tables:
-            full = np.zeros((self.paged.max_pages_per_seq,), np.int32)
-            full[: len(pages)] = pages
-            self._chain = self._chain.at[slot].set(jnp.asarray(full))
-        else:
-            row = np.zeros((self.paged.max_pages_per_seq,), np.int32)
-            row[:n_publish] = pages[:n_publish]
-        for name in self._layer_names:
-            att = self.cache[name]["attn"]
-            new_att = {**att, "seq_lens": att["seq_lens"].at[slot].set(L)}
-            if not self._derive_tables:
-                new_att["page_table"] = (
-                    att["page_table"].at[slot].set(jnp.asarray(row))
-                )
-            self.cache[name]["attn"] = new_att
+        self._set_slot_row(slot, L, pages)
         self.slots[slot] = req
         self._slot_pages[slot] = pages
         self._slot_page_base[slot] = 0
-        self._slot_visible[slot] = n_publish
         self._slot_len[slot] = L
         self._slot_last[slot] = snap["last"]
         self._slot_seq[slot] = self._seq_counter

@@ -10,18 +10,176 @@ engine module docstring.
 
 from __future__ import annotations
 
-import math
 from typing import Any, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .engine_profiler import in_phase
 
 
+# Compiled cache writers: pure functions of their arguments (like
+# engine_sampling's builders), jitted and cached by
+# PagingMixin._cache_write with the cache tree and the chain donated.
+# They walk whatever the tree holds — every layer, every ``pool_*`` leaf
+# (int8 scale pools ride along) — so no engine variant needs its own.
+
+
+def _write_slot_row(cache, chain, slot, length, row, derive_tables: bool):
+    """seq_lens[slot] = length in every layer, and ``row`` into the chain
+    (derive-tables engines: the per-layer tables are derived in-program
+    and overwritten before any read) or into every layer's table."""
+    out = {}
+    for name, layer in cache.items():
+        att = layer["attn"]
+        new_att = {**att, "seq_lens": att["seq_lens"].at[slot].set(length)}
+        if not derive_tables:
+            new_att["page_table"] = att["page_table"].at[slot].set(row)
+        out[name] = {**layer, "attn": new_att}
+    if derive_tables:
+        chain = chain.at[slot].set(row)
+    return out, chain
+
+
+def build_slot_writer(derive_tables: bool):
+    """``set_slot(cache, chain, meta, row)`` with ``meta`` = int32
+    [slot, length] and ``row`` int32 [max_pages_per_seq]."""
+
+    def set_slot(cache, chain, meta, row):
+        return _write_slot_row(cache, chain, meta[0], meta[1], row, derive_tables)
+
+    return set_slot
+
+
+def build_graft_writer(derive_tables: bool):
+    """``graft(cache, chain, dense, meta, row)``: ``meta`` = int32
+    [slot, plen, row_idx, n_shared], ``row`` the slot's device row
+    (int32 [max_pages_per_seq]; its first ceil(plen / page_size)
+    entries are the prompt's pages in either engine kind).
+
+    Per pool: row ``row_idx`` of the matching dense slab
+    (``cached_<x>`` for ``pool_<x>``), positions >= plen zeroed, viewed
+    as whole pages and scattered page-indexed.  Pages below n_shared
+    (a concurrent reader owns them) and pages the prompt does not reach
+    are sent to an out-of-range index and DROPPED, so the program's
+    shape depends on the dense cache's [batch, bucket] alone."""
+
+    def graft(cache, chain, dense, meta, row):
+        slot, plen, row_idx, n_shared = meta[0], meta[1], meta[2], meta[3]
+
+        def write_pages(pool, slab):
+            num_pages, ps = pool.shape[:2]
+            bucket = slab.shape[1]
+            n_pg = -(-bucket // ps)
+            rows = jax.lax.dynamic_index_in_dim(slab, row_idx, 0, keepdims=False)
+            tail = ((0, 0),) * (rows.ndim - 1)
+            rows = jnp.pad(rows, ((0, n_pg * ps - bucket),) + tail)
+            live = (jnp.arange(n_pg * ps) < plen).reshape((-1,) + (1,) * len(tail))
+            rows = jnp.where(live, rows, 0).reshape(n_pg, ps, *rows.shape[1:])
+            page = jnp.arange(n_pg)
+            private = (page >= n_shared) & (page * ps < plen)
+            idx = jnp.where(private, row[:n_pg], num_pages)
+            return pool.at[idx].set(rows.astype(pool.dtype), mode="drop")
+
+        grafted = {}
+        for name, layer in cache.items():
+            att, src = layer["attn"], dense[name]["attn"]
+            pools = {
+                pool: write_pages(att[pool], src["cached_" + pool[len("pool_"):]])
+                for pool in att
+                if pool.startswith("pool_")
+            }
+            grafted[name] = {**layer, "attn": {**att, **pools}}
+        return _write_slot_row(grafted, chain, slot, plen, row, derive_tables)
+
+    return graft
+
+
 class PagingMixin:
     """Page allocation/free, prefix-sharing trie, frontier publication,
     windowed reclamation, and the prefill->pages graft."""
+
+    def _cache_write(self, key: tuple, build, *args) -> None:
+        """Run one compiled writer over the device cache tree:
+        ``self.cache, self._chain = writer(self.cache, self._chain, *args)``
+        with both DONATED, so the pools are written in place — no pool
+        copy, one dispatch whatever the layer count.  ``build`` makes the
+        traced function on first use of ``key``; the jitted program is
+        cached on THIS instance (like _prefill_chunk_fn) and, on a mesh,
+        its outputs are pinned to the shardings the tree already has, so
+        a pool never comes back replicated.  Host-built ``args`` must
+        already be placed (_rep).  Counts one dispatch under the
+        operation, ``key[0]``."""
+        op = key[0]
+        fn = self._cache_writers.get(key)
+        if fn is None:
+            self._wd_grace(f"compile:cache_write_{op}")
+            pinned = None
+            if self.mesh is not None:
+                pinned = jax.tree.map(
+                    lambda leaf: leaf.sharding, (self.cache, self._chain)
+                )
+            fn = self._cache_writers[key] = jax.jit(
+                build(), donate_argnums=(0, 1), out_shardings=pinned
+            )
+        self.cache, self._chain = fn(self.cache, self._chain, *args)
+        self.cache_write_dispatches[op] += 1
+        if self.metrics:
+            self.metrics.cache_write_dispatches.inc(op=op)
+            self.metrics.cache_write_programs.set(self.cache_write_programs())
+
+    def cache_write_programs(self) -> int:
+        """Compiled cache writers this engine holds: one per dense
+        (batch, bucket) shape a graft has seen plus the slot-row writer.
+        Counted from the jit caches, so a writer that recompiled for a
+        prompt length would show."""
+        return sum(fn._cache_size() for fn in list(self._cache_writers.values()))
+
+    def cache_writes_state(self) -> dict:
+        """The ``cache_writes`` block of ``GET /debug/profile``: the same
+        two numbers as tpu_engine_cache_write_dispatches_total and
+        tpu_engine_cache_write_programs."""
+        return {
+            "dispatches": dict(self.cache_write_dispatches),
+            "programs": self.cache_write_programs(),
+        }
+
+    def _slot_row(self, pages: list[int], length: int) -> tuple[np.ndarray, int]:
+        """The device row for a slot holding ``pages`` at ``length``
+        consumed positions, and how many pages it publishes.  Only the
+        pages the NEXT decode step can touch are visible: those covering
+        positions [0, length] (the first decode write lands at position
+        ``length``; a speculative round writes up to length+gamma).  The
+        rest stays at scratch page 0 until the frontier reaches it, so
+        the kernel's pipeline never streams unwritten generation pages.
+        Derive-tables engines record the FULL chain in the [slots,
+        max_pages] chain array and the jitted step computes the visible
+        prefix in-program; speculative engines publish the visible
+        prefix into every layer's table and extend via
+        _extend_frontier."""
+        n_publish = min(
+            (length + self._spec_gamma) // self.paged.page_size + 1, len(pages)
+        )
+        n = len(pages) if self._derive_tables else n_publish
+        row = np.zeros((self.paged.max_pages_per_seq,), np.int32)
+        row[:n] = pages[:n]
+        return row, n_publish
+
+    def _set_slot_row(self, slot: int, length: int, pages: list[int]) -> None:
+        """Point ``slot`` at ``pages`` with ``length`` consumed positions
+        on the device: seq_lens[slot] in every layer and the chain row
+        (or every layer's table row), in ONE dispatch.  For a slot whose
+        K/V rows are already in place (restore-resume, handoff admit)
+        and, with no pages and length 0, for teardown."""
+        row, n_publish = self._slot_row(pages, length)
+        self._cache_write(
+            ("slot",),
+            lambda: build_slot_writer(self._derive_tables),
+            self._rep(np.asarray([slot, length], np.int32)),
+            self._rep(row),
+        )
+        self._slot_visible[slot] = n_publish
 
     @in_phase("prefill.graft")
     def _graft(
@@ -33,11 +191,13 @@ class PagingMixin:
         n_shared: int,
         row_idx: int = 0,
     ):
-        """Scatter a prefilled dense cache's rows into the PRIVATE prompt
-        pages and point the slot's table/length at the full chain — ONE
-        page-indexed scatter per pool per layer (not per page: eager `.at`
-        updates are copy-on-write, so per-page updates would round-trip
-        the whole pool once per page).
+        """Copy row ``row_idx`` of a prefilled dense cache into the
+        slot's PRIVATE prompt pages and point the slot's row/length at
+        the chain (_slot_row) — ONE dispatch of one compiled program
+        (build_graft_writer) over every pool of every layer, keyed by
+        the dense cache's (batch, bucket) shape alone: prompt length,
+        shared-page count and chain are traced arguments, so a new
+        prompt length compiles nothing.
 
         Shared prefix pages (the first ``n_shared``) are never rewritten:
         a concurrent request is reading them, and K/V from a prefill
@@ -45,95 +205,24 @@ class PagingMixin:
         identical — rewriting could perturb an in-flight generation.
         Private pages are written whole; tail slots past plen carry zeros,
         which later appends overwrite before any masked read can see
-        them."""
-        ps = self.paged.page_size
-        n_cover = math.ceil(plen / ps)
-        # Publish only the pages the NEXT decode step can touch: those
-        # covering positions [0, plen] (the first decode write lands at
-        # position plen; a speculative round writes up to plen+gamma).
-        # The rest of the chain stays at scratch page 0 until the
-        # frontier reaches it so the kernel's pipeline never streams
-        # unwritten generation pages.  Derive-tables engines record the
-        # FULL chain in the [slots, max_pages] chain array (one device
-        # write) and the jitted step computes the visible prefix
-        # in-program; speculative engines publish into every layer's
-        # cache table here and extend via _extend_frontier.
-        n_publish = min((plen + self._spec_gamma) // ps + 1, len(pages))
+        them.  No other page of a pool is touched."""
+        row, n_publish = self._slot_row(pages, plen)
+        slab = dense_cache[self._layer_names[0]]["attn"]["cached_key"]
+        self._cache_write(
+            ("graft", *slab.shape[:2]),
+            lambda: build_graft_writer(self._derive_tables),
+            dense_cache,
+            self._rep(np.asarray([slot, plen, row_idx, n_shared], np.int32)),
+            self._rep(row),
+        )
         self._slot_visible[slot] = n_publish
-        if self._derive_tables:
-            full = np.zeros((self.paged.max_pages_per_seq,), np.int32)
-            full[: len(pages)] = pages
-            self._chain = self._chain.at[slot].set(jnp.asarray(full))
-        else:
-            row = np.zeros((self.paged.max_pages_per_seq,), np.int32)
-            row[:n_publish] = pages[:n_publish]
-        lo_tok = n_shared * ps  # first private-covered token position
-        n_priv_cover = n_cover - n_shared
-        cover = jnp.asarray(pages[n_shared:n_cover], jnp.int32)
-        pad = n_cover * ps - plen
-        for name in self._layer_names:
-            att = self.cache[name]["attn"]
-            src = dense_cache[name]["attn"]
-
-            def paged_rows(slab):
-                rows = slab[row_idx, lo_tok:plen]
-                if pad:
-                    rows = jnp.pad(
-                        rows, ((0, pad),) + ((0, 0),) * (rows.ndim - 1)
-                    )
-                return rows.reshape(n_priv_cover, ps, *rows.shape[1:])
-
-            new_att = {
-                **att,
-                "seq_lens": att["seq_lens"].at[slot].set(plen),
-            }
-            if not self._derive_tables:
-                new_att["page_table"] = (
-                    att["page_table"].at[slot].set(jnp.asarray(row))
-                )
-            if n_priv_cover > 0:
-                new_att["pool_key"] = (
-                    att["pool_key"].at[cover].set(paged_rows(src["cached_key"]))
-                )
-                new_att["pool_value"] = (
-                    att["pool_value"].at[cover].set(paged_rows(src["cached_value"]))
-                )
-                if "pool_key_scale" in att:
-                    # int8 KV: the scale rows CACHE alongside the page
-                    # write — the dense prefill quantized once
-                    # (quantize_kv_pair) and its scale slabs scatter
-                    # here with the codes; nothing later (kernel,
-                    # gather, offload, restore) re-derives a scale.
-                    # Pool-byte accounting (_kv_rows_nbytes) counts the
-                    # two f32 scale pools with the codes — pinned in
-                    # tests/test_engine.py.
-                    new_att["pool_key_scale"] = (
-                        att["pool_key_scale"]
-                        .at[cover]
-                        .set(paged_rows(src["cached_key_scale"]))
-                    )
-                    new_att["pool_value_scale"] = (
-                        att["pool_value_scale"]
-                        .at[cover]
-                        .set(paged_rows(src["cached_value_scale"]))
-                    )
-            self.cache[name]["attn"] = new_att
 
     @in_phase("finish.clear_slot")
     def _clear_slot(self, slot: int):
-        if self._derive_tables:
-            # One chain-row zero; per-layer cache tables are derived
-            # in-program and overwritten before any read.
-            self._chain = self._chain.at[slot].set(0)
-        for name in self._layer_names:
-            att = self.cache[name]["attn"]
-            new_att = {
-                **att,
-                "seq_lens": att["seq_lens"].at[slot].set(0),
-            }
-            if not self._derive_tables:
-                new_att["page_table"] = att["page_table"].at[slot].set(0)
-            self.cache[name]["attn"] = new_att
+        """Tear a slot down: zero its device row, length and visible
+        page count (one dispatch, _set_slot_row), release its pages,
+        reset its host scalars."""
+        self._set_slot_row(slot, 0, [])
         for page in self._slot_pages[slot]:
             self._release_page(page)
         self._slot_pages[slot] = []
@@ -147,7 +236,6 @@ class PagingMixin:
         self._slot_bias_vals[slot] = [0.0] * self.MAX_BIAS
         self._slot_aid[slot] = -1
         self._slot_page_base[slot] = 0
-        self._slot_visible[slot] = 0
         self._slot_ready[slot] = False
         self._slot_emit_t[slot] = 0.0
         # Slot scalars changed: the device-resident step state must be

@@ -154,8 +154,8 @@ class EngineMetrics:
             ),
             "prefill.graft": registry.counter(
                 "tpu_engine_loop_graft_seconds_total",
-                "Seconds copying prefilled K/V into pages, eagerly "
-                "(counted in prefill too)",
+                "Seconds copying prefilled K/V into pages: one compiled "
+                "writer dispatch a prompt (counted in prefill too)",
             ),
             "dispatch.frontier": registry.counter(
                 "tpu_engine_loop_frontier_seconds_total",
@@ -179,6 +179,21 @@ class EngineMetrics:
                 "Slots torn down (finish, cancel, eviction)",
             ),
         }
+        self.cache_write_dispatches = registry.counter(
+            "tpu_engine_cache_write_dispatches_total",
+            "Dispatches of the compiled, donated writers into the device "
+            "cache tree (op=graft: a prompt's K/V into its pages, one a "
+            "prefilled request; op=slot: a slot's length and page row, "
+            "one a teardown, restore-resume or handoff admit)",
+            ["op"],
+        )
+        self.cache_write_programs = registry.gauge(
+            "tpu_engine_cache_write_programs",
+            "Compiled cache writers held: one per dense prefill "
+            "(batch, bucket) shape grafted so far plus the slot-row "
+            "writer; growing with the prompt lengths served would mean "
+            "a writer recompiles per length",
+        )
         self.decode_dispatches_block = registry.counter(
             "tpu_engine_decode_dispatches_block_total",
             "Decode dispatches that ran a multi-step block program",

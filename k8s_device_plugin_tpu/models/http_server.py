@@ -1616,7 +1616,13 @@ class EngineServer:
                     # Per-step phase breakdown over the rolling window —
                     # aggregates only, no request-identifying content, so
                     # it stays as open as /metrics.
-                    self._reply(200, server.engine.profiler.snapshot())
+                    self._reply(
+                        200,
+                        {
+                            **server.engine.profiler.snapshot(),
+                            "cache_writes": server.engine.cache_writes_state(),
+                        },
+                    )
                 elif path == "/debug/disagg":
                     # Disaggregation snapshot (models/engine_handoff.py):
                     # role, handoff serve/fetch/publish counters, and

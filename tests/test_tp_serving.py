@@ -1,8 +1,9 @@
 """Tensor-parallel serving: mesh derivation + the sharding contract.
 
 Tier-1 discipline (ISSUE 6 / the conftest budget guard): shape/spec
-units only — no engine steps, no new jit compiles.  The one engine
-construction here reuses the session-scoped ``shared_engine`` fixture's
+units only — no engine steps, no model compiles (the one exception is
+the pair of small cache-writer programs the last test builds on the
+mesh).  The engine constructions here reuse the session-scoped ``shared_engine`` fixture's
 already-initialized params (ctor placement is ``device_put`` +
 ``eval_shape``, which compile nothing); the step/prefill programs stay
 unbuilt because the engine is never stepped.  The full tp=2 serving run
@@ -200,6 +201,50 @@ def test_kernel_engine_sharding_contract_survives_split_k(shared_engine):
     state = eng.debug_state()
     assert state["config"]["kernel"] is True
     assert state["config"]["kernel_splits"] == 2
+
+
+def test_cache_writers_keep_the_sharding_contract(shared_engine):
+    """A graft and a clear on the mesh go through the compiled, donated
+    cache writers (engine_paging._cache_write), whose outputs are pinned
+    to the shardings the tree came with: the lint still passes, the
+    pools are still partitioned on the kv-heads axis (no silent
+    replication by the new programs), and the bytes are the unsharded
+    engine's.  The dense cache is random data: no model program is
+    built."""
+    from k8s_device_plugin_tpu.models.engine import ServingEngine
+    from k8s_device_plugin_tpu.models.transformer import (
+        PagedConfig,
+        decode_cache_spec,
+    )
+
+    cfg, params, _ = shared_engine
+    paged = PagedConfig(page_size=4, num_pages=16, max_pages_per_seq=8)
+    sharded = ServingEngine(cfg, params, paged, max_slots=2, mesh=_mesh2())
+    plain = ServingEngine(cfg, params, paged, max_slots=2)
+    checked = sharded.assert_sharded()
+    spec = decode_cache_spec(sharded._dense_chunk_model(8), 2)
+    rng = np.random.default_rng(0)
+    dense = jax.tree.map(
+        lambda s: jnp.asarray(rng.standard_normal(s.shape), s.dtype), spec
+    )
+    for eng in (sharded, plain):
+        eng._graft(1, dense, [3, 7, 5], 6, 0, row_idx=1)
+        eng._graft(0, dense, [9, 2], 8, 1)
+        eng._clear_slot(0)
+    assert sharded.assert_sharded() == checked
+    for name in sharded._layer_names:
+        for leaf_name in ("pool_key", "pool_value", "seq_lens"):
+            leaf = sharded.cache[name]["attn"][leaf_name]
+            np.testing.assert_array_equal(
+                np.asarray(leaf), np.asarray(plain.cache[name]["attn"][leaf_name])
+            )
+            if leaf_name != "seq_lens":
+                shard = leaf.sharding.shard_shape(leaf.shape)
+                assert shard[2] * 2 == leaf.shape[2], (name, leaf_name)
+    np.testing.assert_array_equal(
+        np.asarray(sharded._chain), np.asarray(plain._chain)
+    )
+    assert np.asarray(sharded._chain)[1].tolist() == [3, 7, 5, 0, 0, 0, 0, 0]
 
 
 def test_engine_ctor_rejects_indivisible_kv_heads(shared_engine):
