@@ -1,7 +1,8 @@
 """Shared by the readers of the device trace: which programs decode, and
-the least time the chip could take for their steps."""
+the least time the chip could take for their steps (FLOPs and bytes as the
+configuration's family counts them, with the run's ``ctx`` to count from)."""
 
-from chipbench import flops, peaks
+from chipbench import families, peaks
 
 
 def decode_events(ctx):
@@ -32,7 +33,8 @@ def step_least_s(ctx, contexts):
     cell's ``count``: weights and heads divide over the chips of a tp mesh."""
     kind = ctx["device"]["kind"]
     peak = peaks.peaks(kind)
-    f, b = flops.llm_decode_step(ctx["cell"].config["model"], contexts)
+    m = ctx["cell"].config["model"]
+    f, b = families.of(m).decode_step(m, contexts, ctx)
     chips = ctx["cell"].chips
     t_f, t_b = f / chips / peak["bf16_flops"], b / chips / peak["hbm_bytes_per_s"]
     return max(t_f, t_b), "flops" if t_f > t_b else "bytes"

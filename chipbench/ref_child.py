@@ -1,6 +1,7 @@
-"""The process that runs the serving reference once the replica has exited
-and given the chip back: reads a sample of served requests, writes every
-gap.  Platform as the run's (the chip, or the CPU in a rehearsal)."""
+"""The process that runs the serving reference (that of the configuration's
+family) once the replica has exited and given the chip back: reads a sample
+of served requests, writes every gap.  Platform as the run's (the chip, or
+the CPU in a rehearsal)."""
 
 from __future__ import annotations
 
@@ -29,15 +30,15 @@ def main(argv=None) -> None:
 
     import jax
 
-    from .reference import llm
+    from . import families
 
     if jax.devices()[0].platform != args.platform:
         raise SystemExit(f"reference: platform {jax.devices()[0].platform}, asked {args.platform}")
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", args.cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    rows = llm.served_gaps(
-        conf, args.seed, sample["cases"], sample["pad_to"], control=bool(args.control)
+    rows = families.of(conf).served_gaps(
+        conf, args.seed, sample["cases"], sample["pad_to"], bool(args.control)
     )
     with open(args.out, "w") as f:
         json.dump({"rows": rows, "seconds": time.monotonic() - t0}, f)

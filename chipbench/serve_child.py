@@ -4,11 +4,13 @@ from a configuration file and ``--seed``.
 
 The shipped ``main()`` cannot express a public model (``intermediate_size =
 hidden * 3``, no window, no ``rope_theta``, ``PRNGKey(0)``, float32
-parameters), and the benchmark may not edit the program; so this launcher
-builds ``GPTConfig`` from the configuration's published keys and makes
-bfloat16 weights on the device in one jitted call.  Everything after that
-is ``main()``'s own wiring with the flags of the configuration's
-``engine`` block (deploy/k8s-deploy-serve-http.yaml).
+parameters), and the benchmark may not edit the program; so the
+configuration's family (``families/<name>.py``, ``llm`` where the file
+names none) builds ``GPTConfig`` and ``PagedConfig`` from the published
+keys, and this launcher makes the family's bfloat16 weights on the device
+in one jitted call.  Everything after that is ``main()``'s own wiring with
+the flags of the configuration's ``engine`` block
+(deploy/k8s-deploy-serve-http.yaml), the same for every family.
 """
 
 from __future__ import annotations
@@ -19,25 +21,6 @@ import os
 import signal
 import sys
 import time
-
-
-def build_gpt_config(model: dict, engine: dict):
-    import jax.numpy as jnp
-
-    from k8s_device_plugin_tpu.models.transformer import GPTConfig
-
-    return GPTConfig(
-        vocab_size=model["vocab_size"],
-        hidden_size=model["hidden_size"],
-        num_layers=model["num_hidden_layers"],
-        num_heads=model["num_attention_heads"],
-        intermediate_size=model["intermediate_size"],
-        max_seq=engine["page_size"] * engine["max_pages_per_seq"],
-        rope_theta=float(model["rope_theta"]),
-        num_kv_heads=model["num_key_value_heads"],
-        attention_window=model.get("sliding_window"),
-        dtype={"bfloat16": jnp.bfloat16, "float32": jnp.float32}[model["torch_dtype"]],
-    )
 
 
 def memory_peak_bytes(jax, say) -> int:
@@ -80,14 +63,13 @@ def main(argv=None) -> None:
     from k8s_device_plugin_tpu.models import http_server as hs
     from k8s_device_plugin_tpu.models.engine import EngineMetrics, ServingEngine
     from k8s_device_plugin_tpu.models.engine_overload import OverloadConfig
-    from k8s_device_plugin_tpu.models.transformer import PagedConfig
     from k8s_device_plugin_tpu.utils import failpoints
     from k8s_device_plugin_tpu.utils import flight as flight_mod
     from k8s_device_plugin_tpu.utils.metrics import MetricsRegistry
     from k8s_device_plugin_tpu.utils.platform import device_facts, enable_compilation_cache
     from k8s_device_plugin_tpu.utils.spans import SpanRecorder
 
-    from . import weights
+    from . import families, weights
 
     say = lambda m: print(m, file=sys.stderr, flush=True)  # noqa: E731
     # Every program is written to the cache, the sub-second ones too: a
@@ -101,13 +83,13 @@ def main(argv=None) -> None:
             f"asked for {args.chips} x {args.platform}, JAX found "
             f"{facts['device_count']} x {facts['platform']}"
         )
-    cfg = build_gpt_config(model, eng)
+    family = families.of(conf)
+    cfg, paged = family.build(model, eng)
     t0 = time.monotonic()
-    params = jax.jit(lambda words: weights.llm_params_tree(model, words))(weights.seed_words(args.seed))
+    params = jax.jit(lambda words: family.params_tree(model, words))(weights.seed_words(args.seed))
     jax.block_until_ready(params)
     say(f"weights: {sum(x.size for x in jax.tree.leaves(params)) / 1e6:.1f} M parameters "
         f"in {time.monotonic() - t0:.1f} s")
-    paged = PagedConfig(eng["page_size"], eng["num_pages"], eng["max_pages_per_seq"])
     tp = int(eng.get("tp", 1))
     mesh = None
     if tp > 1:
