@@ -60,7 +60,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from .engine_admission import AdmissionMixin
 from .engine_handoff import HandoffMixin
 from .engine_kvcache import KVCacheMixin
-from .engine_paging import PagingMixin
+from .engine_paging import PagingMixin, slot_state_bytes
 from .engine_sampling import (  # noqa: F401  (re-export: public surface)
     _token_logprob,
     build_block_fn,
@@ -253,6 +253,25 @@ class ServingEngine(
         model = TransformerLM(self.cfg, decode=True)
         spec = decode_cache_spec(model, max_slots)
         self.cache = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), spec)
+        # Per-slot recurrent state beside the pages (a mixer's
+        # ``slot_*`` leaves, models/ssm.py; 0 for a model without one).
+        # The compiled cache writers carry it through graft and teardown
+        # (engine_paging.py); every path that skips or reorders the work
+        # that BUILDS it refuses below or falls back (restore-resume:
+        # engine_kvcache.py; split roles: engine_handoff.py).
+        self.slot_state_bytes = slot_state_bytes(self.cache)
+        if self.slot_state_bytes and spec_gamma > 0:
+            raise ValueError(
+                "spec_gamma > 0 is not supported on a model with per-slot "
+                "recurrent state (cfg.mixer): a rejected draft would have "
+                "to roll the state back, and the round programs do not"
+            )
+        if self.slot_state_bytes and self.tp_size > 1:
+            raise ValueError(
+                f"tp={self.tp_size} is not supported on a model with "
+                "per-slot recurrent state (cfg.mixer): the sharding "
+                "contract (parallel/serving.py) has no rule for slot_* leaves"
+            )
         if mesh is not None:
             from ..parallel.serving import cache_sharding
 
@@ -409,7 +428,13 @@ class ServingEngine(
         # Speculative engines never overlap: a round's host consumption
         # DECIDES the next dispatch's inputs (data-dependent acceptance),
         # so there is nothing to dispatch ahead.
-        self._overlap_steps = 0 if spec_gamma else overlap_steps
+        # Nor do engines whose slots hold recurrent state: a discarded
+        # dispatch has already advanced every surviving slot's state
+        # (K/V writes are idempotent on re-dispatch, a recurrence is
+        # not), and nothing rolls it back.
+        self._overlap_steps = (
+            0 if spec_gamma or self.slot_state_bytes else overlap_steps
+        )
         self._inflight: Optional[dict] = None
         self.overlap_hits = 0
         self.overlap_discards = 0
@@ -417,6 +442,7 @@ class ServingEngine(
         self.metrics = metrics
         if metrics:
             metrics.tp_size.set(self.tp_size)
+            metrics.slot_state_bytes.set(self.slot_state_bytes)
         # Forensics layer (always on — a production incident cannot ask
         # for instrumentation retroactively, and all three pieces are
         # stdlib-cheap): a bounded flight-recorder black box of typed

@@ -401,15 +401,29 @@ class AdmissionMixin:
             pos = jnp.broadcast_to(
                 pos0 + jnp.arange(chunk)[None, :], (batch, chunk)
             )
+            # Each row's true-last-position logits, valid only when
+            # last_idx falls inside this chunk (the host keeps the row
+            # from the covering chunk).  A function, called where each
+            # path needs it: a model that keeps all logits traces the
+            # operations in the order, and so to the program, it had.
+            def last_row():
+                return jnp.clip(last_idx - pos0, 0, chunk - 1)
+
+            # last_positions: a mixer's recurrent state must not see the
+            # bucket's padding (models/ssm.py); attention ignores it.
+            # logits_at: a config that keeps one logit a prompt sends
+            # only the selected position through the head.
+            keep_one = model.config.logits_to_keep == 1
             logits, mut = model.apply(
                 {"params": params, "cache": cache}, tokens, pos,
                 adapter_ids=aids,
+                last_positions=last_idx,
+                logits_at=last_row() if keep_one else None,
                 mutable=["cache"],
             )
-            # Each row's true-last-position logits, valid only when
-            # last_idx falls inside this chunk (the host keeps the row
-            # from the covering chunk).
-            sel = jnp.clip(last_idx - pos0, 0, chunk - 1)
+            if keep_one:
+                return logits[:, 0], mut["cache"]
+            sel = last_row()
             return logits[jnp.arange(batch), sel], mut["cache"]
 
         fn = jax.jit(run, donate_argnums=(1,))
@@ -420,10 +434,20 @@ class AdmissionMixin:
     def _start_prefill(self, items: list[tuple[int, "Request", list[int], int]]):
         """Create one prefill JOB for a same-length-bucket admission group.
 
-        Length padding is sound because attention is causal — positions
-        >= plen cannot influence logits[plen-1] — and _graft copies only
-        rows [:plen] into pages, so the padded tail's garbage K/V never
-        leaves the throwaway dense cache.  The batch dim is padded to a
+        Length padding is sound for two reasons, one per kind of cached
+        unit.  Attention is causal — positions >= plen cannot influence
+        logits[plen-1] — and _graft copies only rows [:plen] into pages,
+        so the padded tail's garbage K/V never leaves the throwaway
+        dense cache.  A recurrence is causal too, but its STATE is what
+        the graft carries over, and a pad token run through it would
+        stay there: the chunk program hands each row's ``last_idx`` to
+        the model as ``last_positions``, and the mixer (models/ssm.py)
+        freezes the row's state past it (dt = 0: decay 1, input 0) and
+        keeps the convolution's tail at the last REAL inputs, which may
+        lie in an earlier chunk (tests/test_engine_state.py serves every
+        prompt length of one bucket against the plain reference).  A
+        resumed request (prompt + tokens) rebuilds its state by the same
+        path.  The batch dim is padded to a
         power of two (repeating the first prompt; its extra rows are
         discarded), so an admission burst of N prompts costs ONE dispatch
         per chunk instead of N serial prefills, and the number of

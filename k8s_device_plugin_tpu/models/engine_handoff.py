@@ -150,6 +150,14 @@ class HandoffMixin:
     def _validate_role(self, role: str) -> None:
         if role not in ROLES:
             raise ValueError(f"role must be one of {ROLES}, got {role!r}")
+        if role != "unified" and self.slot_state_bytes:
+            # A handoff ships pages; the decode side would skip the
+            # chunks that build the recurrent state (models/ssm.py).
+            raise ValueError(
+                f"role={role!r} is not supported on a model with per-slot "
+                "recurrent state (cfg.mixer): a prefill handoff carries "
+                "K/V pages, not the state the skipped chunks would build"
+            )
         if role != "unified":
             # Both split roles live on the content-addressed KV tiers:
             # the prefill role PUBLISHES into the arena and serves from

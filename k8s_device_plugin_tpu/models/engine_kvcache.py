@@ -176,6 +176,9 @@ class KVCacheMixin:
         self.kv_resumes_recompute = 0
         self.kv_resume_restored_tokens = 0
         self.kv_resume_recomputed_tokens = 0
+        # Restore-resumes refused because the slot holds recurrent state
+        # the snapshot does not carry (_kv_try_restore_resume).
+        self.kv_restore_resume_bypassed = 0
 
     # ------------------------------------------------------------- tier 1
 
@@ -470,6 +473,16 @@ class KVCacheMixin:
         snap = self._kv_arena.get(("snap", req.rid), bump=False)
         if snap is None:
             return False
+        if self.slot_state_bytes:
+            # The snapshot holds K/V rows and scalars, not the slot's
+            # recurrent state (models/ssm.py): skipping prefill would
+            # resume from zeros.  Recompute-resume rebuilds the state
+            # (its retained pages still shrink the graft); counted.
+            self._kv_drop_snapshot(req.rid)
+            self.kv_restore_resume_bypassed += 1
+            if self.metrics:
+                self.metrics.restore_resume_bypassed.inc()
+            return False
         L = snap["len"]
         ps = self.paged.page_size
         eff = req.prompt + req.tokens
@@ -602,5 +615,6 @@ class KVCacheMixin:
                     "recompute": self.kv_resumes_recompute,
                     "restored_tokens": self.kv_resume_restored_tokens,
                     "recomputed_tokens": self.kv_resume_recomputed_tokens,
+                    "restore_bypassed": self.kv_restore_resume_bypassed,
                 },
             }

@@ -23,20 +23,59 @@ from .engine_profiler import in_phase
 # engine_sampling's builders), jitted and cached by
 # PagingMixin._cache_write with the cache tree and the chain donated.
 # They walk whatever the tree holds — every layer, every ``pool_*`` leaf
-# (int8 scale pools ride along) — so no engine variant needs its own.
+# (int8 scale pools ride along), every per-slot ``slot_*`` leaf of a
+# layer's other subtrees — so no engine variant needs its own.
+#
+# Two kinds of cached unit: ``pool_*`` leaves under ``attn`` are pages
+# keyed by a table (what a sequence holds grows with its length);
+# ``slot_*`` leaves (``[slots, ...]``, any subtree but ``attn``: a
+# mixer's recurrent state, models/ssm.py) are one fixed-size row a slot.
+# A graft copies the prefilled row into the slot, a slot-row write
+# without a graft leaves the slot with no state: zeros.
 
 
-def _write_slot_row(cache, chain, slot, length, row, derive_tables: bool):
+def slot_leaves(layer: dict):
+    """(subtree, leaf) names of a layer's per-slot leaves."""
+    return [
+        (sub, leaf)
+        for sub, tree in layer.items()
+        if sub != "attn"
+        for leaf in tree
+        if leaf.startswith("slot_")
+    ]
+
+
+def slot_state_bytes(cache: dict) -> int:
+    """Device bytes of every per-slot leaf of the cache tree (arrays, or
+    the shapes ``decode_cache_spec`` gives)."""
+    return sum(
+        layer[sub][leaf].size * layer[sub][leaf].dtype.itemsize
+        for layer in cache.values()
+        for sub, leaf in slot_leaves(layer)
+    )
+
+
+def _write_slot_row(cache, chain, slot, length, row, derive_tables: bool, dense=None, row_idx=None):
     """seq_lens[slot] = length in every layer, and ``row`` into the chain
     (derive-tables engines: the per-layer tables are derived in-program
-    and overwritten before any read) or into every layer's table."""
+    and overwritten before any read) or into every layer's table.  Row
+    ``slot`` of every per-slot leaf becomes row ``row_idx`` of the dense
+    prefill cache's same leaf, or zeros where no prefill is given."""
     out = {}
     for name, layer in cache.items():
         att = layer["attn"]
         new_att = {**att, "seq_lens": att["seq_lens"].at[slot].set(length)}
         if not derive_tables:
             new_att["page_table"] = att["page_table"].at[slot].set(row)
-        out[name] = {**layer, "attn": new_att}
+        new_layer = {**layer, "attn": new_att}
+        for sub, leaf in slot_leaves(layer):
+            state = 0
+            if dense is not None:
+                state = jax.lax.dynamic_index_in_dim(
+                    dense[name][sub][leaf], row_idx, 0, keepdims=False
+                ).astype(layer[sub][leaf].dtype)
+            new_layer[sub] = {**new_layer[sub], leaf: layer[sub][leaf].at[slot].set(state)}
+        out[name] = new_layer
     if derive_tables:
         chain = chain.at[slot].set(row)
     return out, chain
@@ -44,7 +83,9 @@ def _write_slot_row(cache, chain, slot, length, row, derive_tables: bool):
 
 def build_slot_writer(derive_tables: bool):
     """``set_slot(cache, chain, meta, row)`` with ``meta`` = int32
-    [slot, length] and ``row`` int32 [max_pages_per_seq]."""
+    [slot, length] and ``row`` int32 [max_pages_per_seq].  The slot's
+    per-slot leaves are zeroed: teardown, or a slot pointed at pages
+    whose sequence state nobody carried over."""
 
     def set_slot(cache, chain, meta, row):
         return _write_slot_row(cache, chain, meta[0], meta[1], row, derive_tables)
@@ -63,7 +104,10 @@ def build_graft_writer(derive_tables: bool):
     as whole pages and scattered page-indexed.  Pages below n_shared
     (a concurrent reader owns them) and pages the prompt does not reach
     are sent to an out-of-range index and DROPPED, so the program's
-    shape depends on the dense cache's [batch, bucket] alone."""
+    shape depends on the dense cache's [batch, bucket] alone.
+
+    Per per-slot leaf (``slot_*``): row ``row_idx`` of the dense cache's
+    same leaf, whole, in the same program."""
 
     def graft(cache, chain, dense, meta, row):
         slot, plen, row_idx, n_shared = meta[0], meta[1], meta[2], meta[3]
@@ -91,7 +135,9 @@ def build_graft_writer(derive_tables: bool):
                 if pool.startswith("pool_")
             }
             grafted[name] = {**layer, "attn": {**att, **pools}}
-        return _write_slot_row(grafted, chain, slot, plen, row, derive_tables)
+        return _write_slot_row(
+            grafted, chain, slot, plen, row, derive_tables, dense, row_idx
+        )
 
     return graft
 

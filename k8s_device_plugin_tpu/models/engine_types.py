@@ -194,6 +194,13 @@ class EngineMetrics:
             "writer; growing with the prompt lengths served would mean "
             "a writer recompiles per length",
         )
+        self.slot_state_bytes = registry.gauge(
+            "tpu_engine_slot_state_bytes",
+            "Device bytes of all per-slot cache leaves (a mixer's "
+            "recurrent state and convolution tail, every layer, every "
+            "slot; 0 for a model without one).  Set once at engine "
+            "construction",
+        )
         self.decode_dispatches_block = registry.counter(
             "tpu_engine_decode_dispatches_block_total",
             "Decode dispatches that ran a multi-step block program",
@@ -333,6 +340,12 @@ class EngineMetrics:
             "generated tokens) — preemptions_total minus this is the "
             "victims still waiting",
             ["mode"],
+        )
+        self.restore_resume_bypassed = registry.counter(
+            "tpu_engine_restore_resume_bypassed_total",
+            "Preemption resumes that had a snapshot but re-prefilled "
+            "anyway because the model keeps per-slot recurrent state "
+            "(a mixer's) that the snapshot does not carry",
         )
         self.resume_restored_tokens = registry.counter(
             "tpu_engine_resume_restored_tokens_total",

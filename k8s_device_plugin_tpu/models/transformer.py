@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 from ..ops.flash_attention import flash_attention, mha_reference
 from ..ops.quant import Int8DenseGeneral, dequantize_kv, quantize_kv_pair
+from .ssm import Mamba2Mixer, MambaConfig
 
 # Large-negative logit for top-k filtering: finite (softmax/categorical
 # stay NaN-free even if every logit in a row were filtered) yet far below
@@ -97,6 +98,27 @@ class PagedConfig:
 
 
 @dataclass(frozen=True)
+class Multipliers:
+    """muP-style fixed scalars a published config multiplies activations
+    by (all 1.0: nothing is scaled and nothing is traced for them)."""
+
+    embedding: float = 1.0  # the embedded tokens
+    lm_head: float = 1.0  # the logits
+    attention_in: float = 1.0  # the normed hidden state into attention
+    key: float = 1.0  # the key projection, before the rotation
+    attention_out: float = 1.0  # attention's output into the residual
+    ssm_in: float = 1.0  # the normed hidden state into the mixer
+    ssm_out: float = 1.0  # the mixer's output into the residual
+    mlp_gate: float = 1.0  # the gate projection, inside the activation
+    mlp_down: float = 1.0  # the feed-forward's output into the residual
+
+
+def _scaled(x, m: float):
+    """``x * m``; ``x`` itself where the multiplier is 1 (no operation)."""
+    return x if m == 1.0 else x * m
+
+
+@dataclass(frozen=True)
 class GPTConfig:
     vocab_size: int = 32000
     hidden_size: int = 2048
@@ -156,10 +178,32 @@ class GPTConfig:
     # pages.  Composes with quant_kv (int8 pools + scale pools; the r2
     # exclusion closed in r3 — tests/test_engine.py pins both paths).
     paged: Optional[PagedConfig] = None
+    # Width of one attention head.  None = hidden_size // num_heads, what
+    # every model here had; a published config may give another (20 heads
+    # of 128 on a hidden size of 5120).  Resolved in __post_init__, so a
+    # dataclasses.replace that changes hidden_size or num_heads passes
+    # head_dim=None (or the new width) with them.
+    head_dim: Optional[int] = None
+    rms_norm_eps: float = 1e-6
+    # Positions of a prefilled prompt whose logits are computed (a
+    # published config's ``num_logits_to_keep``).  None = all of them, what
+    # every model here did; 1 = only each row's last real position goes
+    # through the head (the engine's chunk program selects the row first:
+    # with a vocabulary of 261,120 the float32 logits of a 32 x 256 chunk
+    # would be 8.5 GB nobody reads).
+    logits_to_keep: Optional[int] = None
+    multipliers: Multipliers = Multipliers()
+    # A Mamba-2 mixer beside attention in every block (models/ssm.py): ONE
+    # pre-norm feeds both, their scaled outputs are summed into the
+    # residual.  Its recurrent state is per sequence, not per token: the
+    # serving engine keeps it in per-slot leaves beside the paged pools.
+    mixer: Optional[MambaConfig] = None
 
-    @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+    def __post_init__(self):
+        if self.head_dim is None:
+            object.__setattr__(self, "head_dim", self.hidden_size // self.num_heads)
+        if self.logits_to_keep not in (None, 1):
+            raise ValueError(f"logits_to_keep must be None or 1, got {self.logits_to_keep}")
 
     @property
     def kv_heads(self) -> int:
@@ -361,7 +405,7 @@ class CausalSelfAttention(nn.Module):
         }  # [batch, seq, (kv_)heads, head_dim]
         cos, sin = rope_angles(positions, cfg.head_dim, cfg.rope_theta)
         q = apply_rope(proj["query"], cos, sin)
-        k = apply_rope(proj["key"], cos, sin)
+        k = apply_rope(_scaled(proj["key"], cfg.multipliers.key), cos, sin)
         v = proj["value"]
 
         if self.decode and cfg.paged is not None:
@@ -602,12 +646,13 @@ class SwiGluMlp(nn.Module):
         up = _site_call(
             dense_site(cfg, cfg.intermediate_size, name="up"), x, cfg, adapter_ids
         )
-        return _site_call(
+        down = _site_call(
             dense_site(cfg, cfg.hidden_size, name="down"),
-            nn.silu(gate) * up,
+            nn.silu(_scaled(gate, cfg.multipliers.mlp_gate)) * up,
             cfg,
             adapter_ids,
         )
+        return _scaled(down, cfg.multipliers.mlp_down)
 
 
 class DecoderBlock(nn.Module):
@@ -618,8 +663,9 @@ class DecoderBlock(nn.Module):
     append_mode: str = "auto"
 
     @nn.compact
-    def __call__(self, hidden, positions, adapter_ids=None):
-        cfg = self.config
+    def __call__(self, hidden, positions, adapter_ids=None, last_positions=None):
+        cfg, mul = self.config, self.config.multipliers
+        normed = RMSNorm(dtype=cfg.dtype, eps=cfg.rms_norm_eps, name="attn_norm")(hidden)
         attn = CausalSelfAttention(
             cfg,
             decode=self.decode,
@@ -627,11 +673,17 @@ class DecoderBlock(nn.Module):
             append_mode=self.append_mode,
             name="attn",
         )(
-            RMSNorm(dtype=cfg.dtype, name="attn_norm")(hidden),
+            _scaled(normed, mul.attention_in),
             positions,
             adapter_ids,
         )
-        hidden = hidden + attn
+        hidden = hidden + _scaled(attn, mul.attention_out)
+        if cfg.mixer is not None:
+            # The same pre-norm feeds the mixer, side by side with attention.
+            mixed = Mamba2Mixer(cfg, decode=self.decode, name="mixer")(
+                _scaled(normed, mul.ssm_in), positions, last_positions
+            )
+            hidden = hidden + _scaled(mixed, mul.ssm_out)
         if cfg.lora_serve and self.mlp_factory is not None:
             # A swapped-in MLP (MoE) has the plain one-argument call and
             # would silently skip its adapters.
@@ -639,7 +691,7 @@ class DecoderBlock(nn.Module):
         mlp_mod = (
             self.mlp_factory() if self.mlp_factory is not None else SwiGluMlp(cfg, name="mlp")
         )
-        norm_h = RMSNorm(dtype=cfg.dtype, name="mlp_norm")(hidden)
+        norm_h = RMSNorm(dtype=cfg.dtype, eps=cfg.rms_norm_eps, name="mlp_norm")(hidden)
         mlp = (
             mlp_mod(norm_h, adapter_ids) if cfg.lora_serve else mlp_mod(norm_h)
         )
@@ -662,8 +714,15 @@ class TransformerLM(nn.Module):
 
     @nn.compact
     def __call__(
-        self, input_ids, positions=None, output: str = "logits", adapter_ids=None
+        self, input_ids, positions=None, output: str = "logits", adapter_ids=None,
+        last_positions=None, logits_at=None,
     ):
+        """``last_positions`` [batch]: each row's last REAL position, for a
+        model whose mixer carries state through the sequence (positions
+        past it are padding the state must not see); attention is causal
+        and needs none.  ``logits_at`` [batch]: an index into the sequence
+        axis a row; only that position goes through the head, and the
+        logits come back as [batch, 1, vocab]."""
         cfg = self.config
         seq_len = input_ids.shape[-1]
         if positions is None:
@@ -677,6 +736,7 @@ class TransformerLM(nn.Module):
         hidden = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype, name="embed")(
             input_ids
         )
+        hidden = _scaled(hidden, cfg.multipliers.embedding)
         block_cls = (
             nn.remat(DecoderBlock, static_argnums=()) if cfg.remat else DecoderBlock
         )
@@ -688,8 +748,8 @@ class TransformerLM(nn.Module):
                 attention_fn=self.attention_fn,
                 append_mode=self.append_mode,
                 name=f"layer_{i}",
-            )(hidden, positions, adapter_ids)
-        hidden = RMSNorm(dtype=cfg.dtype, name="final_norm")(hidden)
+            )(hidden, positions, adapter_ids, last_positions)
+        hidden = RMSNorm(dtype=cfg.dtype, eps=cfg.rms_norm_eps, name="final_norm")(hidden)
         if output == "hidden":
             # For the fused LM-head + cross-entropy path (ops/fused_xent.py):
             # the caller applies params["lm_head"]["kernel"] chunk-wise so
@@ -700,13 +760,16 @@ class TransformerLM(nn.Module):
             return hidden
         if output != "logits":
             raise ValueError(f"output must be logits|hidden, got {output!r}")
+        if logits_at is not None:
+            hidden = jnp.take_along_axis(hidden, logits_at[:, None, None], axis=1)
         # Logits in float32 for a stable softmax/xent.
-        return _site_call(
+        logits = _site_call(
             dense_site(cfg, cfg.vocab_size, dtype=jnp.float32, name="lm_head"),
             hidden,
             cfg,
             adapter_ids,
         )
+        return _scaled(logits, cfg.multipliers.lm_head)
 
 
 def decode_cache_spec(model: TransformerLM, batch: int):

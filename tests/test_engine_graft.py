@@ -26,6 +26,7 @@ from k8s_device_plugin_tpu.models.transformer import (
 from k8s_device_plugin_tpu.utils.metrics import MetricsRegistry
 
 PS, BUCKET, BATCH = 4, 16, 2
+KINDS = ("bf16", "int8", "spec", "mixer")
 PAGED = PagedConfig(page_size=PS, num_pages=24, max_pages_per_seq=8)
 
 
@@ -47,17 +48,21 @@ def _random_like(tree, seed):
 @pytest.fixture(scope="module")
 def engines():
     """kind -> (engine, registry): bf16 pools, int8 KV with scale pools,
-    and a speculative engine (host-published page_table rows).  Module
+    a speculative engine (host-published page_table rows), and a model
+    with a mixer (per-slot ``slot_*`` leaves beside the pools).  Module
     scope: the writers compile once a kind."""
+    from k8s_device_plugin_tpu.models.ssm import MambaConfig
     from k8s_device_plugin_tpu.ops.quant import quantize_lm_params
 
     out = {}
-    for kind in ("bf16", "int8", "spec"):
+    for kind in KINDS:
         cfg = dataclasses.replace(
             GPTConfig.tiny(),
             max_seq=32,
             dtype=jnp.bfloat16 if kind == "bf16" else jnp.float32,
             quant_kv=kind == "int8",
+            mixer=MambaConfig(d_ssm=64, n_heads=4, head_dim=16, d_state=16, n_groups=2, chunk_size=8)
+            if kind == "mixer" else None,
         )
         params = TransformerLM(cfg).init(
             jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
@@ -90,7 +95,7 @@ def _host(tree):
 
 @pytest.mark.parametrize("n_shared", [0, 1])
 @pytest.mark.parametrize("plen", [8, 6, BUCKET], ids=["edge", "mid", "bucket"])
-@pytest.mark.parametrize("kind", ["bf16", "int8", "spec"])
+@pytest.mark.parametrize("kind", KINDS)
 def test_graft_writes_private_pages_and_nothing_else(engines, kind, plen, n_shared):
     eng, _ = engines[kind]
     eng.cache = _random_like(eng.cache, seed=7)
@@ -125,6 +130,15 @@ def test_graft_writes_private_pages_and_nothing_else(engines, kind, plen, n_shar
             table[slot, :n_publish] = pages[:n_publish]
             assert eng._slot_visible[slot] == n_publish
         np.testing.assert_array_equal(att1["page_table"], table)
+        # The second kind of cached unit: a per-slot leaf takes the
+        # dense cache's whole row; a model without a mixer has none, and
+        # its layers hold what they held.
+        assert set(after[name]) == ({"attn", "mixer"} if kind == "mixer" else {"attn"})
+        for leaf in ("slot_ssm", "slot_conv") if kind == "mixer" else ():
+            want = before[name]["mixer"][leaf].copy()
+            want[slot] = src[name]["mixer"][leaf][row_idx]
+            np.testing.assert_array_equal(after[name]["mixer"][leaf], want, err_msg=f"{name}/{leaf}")
+    assert (eng.slot_state_bytes > 0) == (kind == "mixer")
     chain = chain_before.copy()
     if kind != "spec":
         chain[slot] = 0
@@ -190,9 +204,10 @@ def _lens_and_row(eng, slot):
     return int(att["seq_lens"][slot]), np.asarray(row)[slot].tolist()
 
 
-@pytest.mark.parametrize("kind", ["bf16", "spec"])
+@pytest.mark.parametrize("kind", ["bf16", "spec", "mixer"])
 def test_clear_slot_is_one_dispatch_of_the_slot_writer(engines, kind):
     eng, _ = engines[kind]
+    eng.cache = _random_like(eng.cache, seed=5)
     eng._graft(1, _dense(eng), [5, 9, 3], 8, 0)
     assert _lens_and_row(eng, 1) == (8, [5, 9, 3, 0, 0, 0, 0, 0])
     pools = _host(eng.cache)
@@ -201,6 +216,11 @@ def test_clear_slot_is_one_dispatch_of_the_slot_writer(engines, kind):
     assert eng.cache_write_dispatches["slot"] == n + 1
     assert _lens_and_row(eng, 1) == (0, [0] * 8)
     assert eng._slot_visible[1] == 0
+    for name in eng._layer_names if kind == "mixer" else ():
+        for leaf, was in pools[name]["mixer"].items():  # the slot's rows zeroed, in that one dispatch
+            now = np.asarray(eng.cache[name]["mixer"][leaf])
+            assert not now[1].any() and was[1].any()
+            np.testing.assert_array_equal(now[0], was[0])
     eng._clear_slot(0)
     assert eng.cache_write_programs() <= programs + 1  # built once, then reused
     for name in eng._layer_names:  # a slot write touches no pool
