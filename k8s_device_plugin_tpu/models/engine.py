@@ -406,6 +406,10 @@ class ServingEngine(
         # (engine_paging._cache_write) and their dispatches by operation.
         self._cache_writers: dict[tuple, Any] = {}
         self.cache_write_dispatches = {"graft": 0, "slot": 0}
+        # The chain writer's dispatches (one a frontier pass that grew a
+        # page, optimistic admission) and the pages they published.
+        self.chain_write_dispatches = 0
+        self.chain_pages_written = 0
         self._rng = self._rep(jax.random.PRNGKey(0) if rng is None else rng)
         # Device-resident step state: the per-slot arrays the jitted step
         # consumes (tokens/positions/temps/aids/filters/biases/key) live

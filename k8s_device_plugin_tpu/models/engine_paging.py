@@ -21,10 +21,11 @@ from .engine_profiler import in_phase
 
 # Compiled cache writers: pure functions of their arguments (like
 # engine_sampling's builders), jitted and cached by
-# PagingMixin._cache_write with the cache tree and the chain donated.
-# They walk whatever the tree holds — every layer, every ``pool_*`` leaf
-# (int8 scale pools ride along), every per-slot ``slot_*`` leaf of a
-# layer's other subtrees — so no engine variant needs its own.
+# PagingMixin._cache_writer with the state they write donated: the cache
+# tree and the chain (graft, slot), or the chain alone (chain).  The
+# first two walk whatever the tree holds — every layer, every ``pool_*``
+# leaf (int8 scale pools ride along), every per-slot ``slot_*`` leaf of
+# a layer's other subtrees — so no engine variant needs its own.
 #
 # Two kinds of cached unit: ``pool_*`` leaves under ``attn`` are pages
 # keyed by a table (what a sequence holds grows with its length);
@@ -142,53 +143,118 @@ def build_graft_writer(derive_tables: bool):
     return graft
 
 
+def build_chain_writer():
+    """``write_chain(chain, updates)`` with ``updates`` int32 [n, 3], a row a
+    grown page: (slot, logical page index, page id).  The row count is
+    fixed a program (PagingMixin._chain_updates_len); rows past what a
+    pass grew name slot ``chain.shape[0]``, out of range, and are
+    DROPPED, as build_graft_writer drops the pages a prompt does not
+    reach.  The pools are no operands: one array in, one array out."""
+
+    def write_chain(chain, updates):
+        slot, idx, page = updates[:, 0], updates[:, 1], updates[:, 2]
+        return (chain.at[slot, idx].set(page, mode="drop"),)
+
+    return write_chain
+
+
 class PagingMixin:
     """Page allocation/free, prefix-sharing trie, frontier publication,
     windowed reclamation, and the prefill->pages graft."""
 
+    def _cache_writer(self, key: tuple, build, state: tuple):
+        """The compiled writer ``key`` over ``state``, the device arrays
+        it rewrites: its leading operands, all DONATED, and its whole
+        output.  ``build`` makes the traced function on first use of
+        ``key``; the jitted program is cached on THIS instance (like
+        _prefill_chunk_fn) and, on a mesh, its outputs are pinned to the
+        shardings ``state`` already has, so a pool (or the chain) never
+        comes back replicated."""
+        fn = self._cache_writers.get(key)
+        if fn is None:
+            self._wd_grace(f"compile:cache_write_{key[0]}")
+            pinned = None
+            if self.mesh is not None:
+                pinned = jax.tree.map(lambda leaf: leaf.sharding, state)
+            fn = self._cache_writers[key] = jax.jit(
+                build(),
+                donate_argnums=tuple(range(len(state))),
+                out_shardings=pinned,
+            )
+        return fn
+
     def _cache_write(self, key: tuple, build, *args) -> None:
         """Run one compiled writer over the device cache tree:
         ``self.cache, self._chain = writer(self.cache, self._chain, *args)``
-        with both DONATED, so the pools are written in place — no pool
-        copy, one dispatch whatever the layer count.  ``build`` makes the
-        traced function on first use of ``key``; the jitted program is
-        cached on THIS instance (like _prefill_chunk_fn) and, on a mesh,
-        its outputs are pinned to the shardings the tree already has, so
-        a pool never comes back replicated.  Host-built ``args`` must
-        already be placed (_rep).  Counts one dispatch under the
-        operation, ``key[0]``."""
+        with both DONATED (_cache_writer), so the pools are written in
+        place — no pool copy, one dispatch whatever the layer count.
+        Host-built ``args`` must already be placed (_rep).  Counts one
+        dispatch under the operation, ``key[0]``."""
         op = key[0]
-        fn = self._cache_writers.get(key)
-        if fn is None:
-            self._wd_grace(f"compile:cache_write_{op}")
-            pinned = None
-            if self.mesh is not None:
-                pinned = jax.tree.map(
-                    lambda leaf: leaf.sharding, (self.cache, self._chain)
-                )
-            fn = self._cache_writers[key] = jax.jit(
-                build(), donate_argnums=(0, 1), out_shardings=pinned
-            )
-        self.cache, self._chain = fn(self.cache, self._chain, *args)
+        state = (self.cache, self._chain)
+        self.cache, self._chain = self._cache_writer(key, build, state)(
+            *state, *args
+        )
         self.cache_write_dispatches[op] += 1
         if self.metrics:
             self.metrics.cache_write_dispatches.inc(op=op)
             self.metrics.cache_write_programs.set(self.cache_write_programs())
 
+    def _chain_updates_len(self) -> int:
+        """Rows of the chain writer's ``updates`` operand: what one
+        _ensure_frontier pass can at most grow, every slot by the pages
+        of an overlapped pair of decode blocks and one more."""
+        per_slot = -(-2 * self._decode_block // self.paged.page_size) + 1
+        return self.max_slots * per_slot
+
+    def _chain_write(self, grown: list[tuple[int, int, int]]) -> None:
+        """Publish a pass's page growth, (slot, logical index, page)
+        triples, to the device chain: ONE dispatch of ONE compiled
+        program (build_chain_writer) over the chain alone — the pools
+        are no operands of it, so nothing of the cache tree rides the
+        dispatch.  The operand's shape is fixed (_chain_updates_len), so
+        the number of pages compiles nothing.  No caller outgrows it (a
+        lookahead is at most 2T-1 and speculative engines record
+        nothing); a pass that did would write in several dispatches of
+        the same program rather than compile another shape or stop the
+        loop, which only tests/test_engine_frontier.py reaches."""
+        n = self._chain_updates_len()
+        for at in range(0, len(grown), n):
+            part = grown[at : at + n]
+            updates = np.full((n, 3), self.max_slots, np.int32)
+            updates[: len(part)] = part
+            state = (self._chain,)
+            (self._chain,) = self._cache_writer(
+                ("chain",), build_chain_writer, state
+            )(*state, self._rep(updates))
+            self.chain_write_dispatches += 1
+            self.chain_pages_written += len(part)
+            if self.metrics:
+                self.metrics.chain_write_dispatches.inc()
+                self.metrics.chain_pages_written.inc(len(part))
+                self.metrics.cache_write_programs.set(self.cache_write_programs())
+
     def cache_write_programs(self) -> int:
         """Compiled cache writers this engine holds: one per dense
-        (batch, bucket) shape a graft has seen plus the slot-row writer.
+        (batch, bucket) shape a graft has seen, the slot-row writer and,
+        once optimistic admission grew a page, the chain writer.
         Counted from the jit caches, so a writer that recompiled for a
-        prompt length would show."""
+        prompt length or a page count would show."""
         return sum(fn._cache_size() for fn in list(self._cache_writers.values()))
 
     def cache_writes_state(self) -> dict:
         """The ``cache_writes`` block of ``GET /debug/profile``: the same
-        two numbers as tpu_engine_cache_write_dispatches_total and
-        tpu_engine_cache_write_programs."""
+        numbers as tpu_engine_cache_write_dispatches_total,
+        tpu_engine_cache_write_programs and, under ``chain``,
+        tpu_engine_chain_write_dispatches_total and
+        tpu_engine_chain_pages_written_total."""
         return {
             "dispatches": dict(self.cache_write_dispatches),
             "programs": self.cache_write_programs(),
+            "chain": {
+                "dispatches": self.chain_write_dispatches,
+                "pages": self.chain_pages_written,
+            },
         }
 
     def _slot_row(self, pages: list[int], length: int) -> tuple[np.ndarray, int]:
@@ -415,12 +481,24 @@ class PagingMixin:
         Oldest-first + newest-evicted means the oldest request can never
         be robbed, which is the liveness argument (it eventually owns
         every page its submit-time bound guarantees fit).  Returns the
-        active list minus anything evicted."""
+        active list minus anything evicted.
+
+        Derive-tables engines record the pages handed out during the
+        pass on the host and publish them to the device chain in ONE
+        dispatch (_chain_write) when the pass ends, before it returns —
+        so before any decode dispatch that follows.  An eviction inside
+        the pass zeroes the victim's chain row on the device AT ONCE
+        (_clear_slot) and its freed page ids go to other slots, so what
+        was recorded for a slot that is no longer ready at the end is
+        DROPPED, never flushed: a slot cannot be re-admitted inside a
+        pass, and a write landing after that zero would point an empty
+        row at pages that are somebody else's."""
         if not self._optimistic:
             for s in active:
                 self._extend_frontier(s, lookahead=lookahead)
             return active
         ps = self.paged.page_size
+        grown: list[tuple[int, int, int]] = []  # (slot, logical index, page)
         for s in sorted(active, key=lambda x: self._slot_seq[x]):
             req = self.slots[s]
             if req is None or not self._slot_ready[s]:
@@ -446,7 +524,7 @@ class PagingMixin:
                                 + len(self._slot_pages[s])
                                 - 1
                             )
-                            self._chain = self._chain.at[s, idx].set(page)
+                            grown.append((s, idx, page))
                         continue
                 if not self._preempt_newest(newer_than=self._slot_seq[s]):
                     break
@@ -454,6 +532,9 @@ class PagingMixin:
                 self._evict_slot(s)  # starved even after preempting: resume later
                 continue
             self._extend_frontier(s, lookahead=lookahead)
+        grown = [g for g in grown if self._slot_ready[g[0]]]
+        if grown:
+            self._chain_write(grown)
         return [
             s
             for s in active

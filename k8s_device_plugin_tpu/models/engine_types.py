@@ -190,9 +190,22 @@ class EngineMetrics:
         self.cache_write_programs = registry.gauge(
             "tpu_engine_cache_write_programs",
             "Compiled cache writers held: one per dense prefill "
-            "(batch, bucket) shape grafted so far plus the slot-row "
-            "writer; growing with the prompt lengths served would mean "
-            "a writer recompiles per length",
+            "(batch, bucket) shape grafted so far, the slot-row writer "
+            "and (optimistic admission, once a page was grown) the chain "
+            "writer; growing with the prompt lengths served or the pages "
+            "grown would mean a writer recompiles per length",
+        )
+        self.chain_write_dispatches = registry.counter(
+            "tpu_engine_chain_write_dispatches_total",
+            "Dispatches of the compiled chain writer: one a frontier "
+            "pass in which optimistic admission grew at least one "
+            "generation page, whatever the number of pages and slots",
+        )
+        self.chain_pages_written = registry.counter(
+            "tpu_engine_chain_pages_written_total",
+            "Generation pages published to the device chain by the "
+            "chain writer; over tpu_engine_chain_write_dispatches_total "
+            "it reads the pages a dispatch carries",
         )
         self.slot_state_bytes = registry.gauge(
             "tpu_engine_slot_state_bytes",

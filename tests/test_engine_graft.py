@@ -175,9 +175,12 @@ def test_prompt_lengths_add_no_writer_and_one_dispatch_a_prompt(engines):
     assert grafts() == n + 2 == eng.cache_write_dispatches["graft"]
     eng._graft(0, _dense(eng, bucket=8, batch=1), [4, 5], 5, 0)
     assert gauge() == programs + 1
+    # These engines never decode: no page is grown, so the chain writer
+    # (optimistic admission's third kind of program) is never built.
     assert eng.cache_writes_state() == {
         "dispatches": dict(eng.cache_write_dispatches),
         "programs": int(programs) + 1,
+        "chain": {"dispatches": 0, "pages": 0},
     }
 
 
