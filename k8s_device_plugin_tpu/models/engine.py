@@ -1513,7 +1513,7 @@ def main(argv: Optional[list[str]] = None) -> None:
     import time
 
     from ..utils.platform import enable_compilation_cache
-    from .benchmark import _positive_int
+    from ..utils.platform import positive_int as _positive_int
 
     enable_compilation_cache(log=lambda m: print(m, file=sys.stderr))
 

@@ -2121,7 +2121,7 @@ def main(argv: Optional[list[str]] = None) -> None:
     import jax
     import jax.numpy as jnp
 
-    from .benchmark import _positive_int
+    from ..utils.platform import positive_int as _positive_int
     from .engine import EngineMetrics, _pow2_int
     from .transformer import GPTConfig, PagedConfig, TransformerLM
 

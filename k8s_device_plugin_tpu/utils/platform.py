@@ -1,6 +1,7 @@
 """What every JAX entry point shares about the device it runs on: the
 facts it prints (platform, device_kind, count), the per-chip peak MFU is
-taken against, and the one persistent compile-cache rule.
+taken against, the one persistent compile-cache rule, and the argparse
+type their command lines share.
 
 Platform selection itself is JAX's own: ``JAX_PLATFORMS`` in the pod
 spec (or the smoke's child environment) decides, and a listed platform
@@ -9,6 +10,7 @@ that cannot initialise is an error, never a quiet CPU run.
 
 from __future__ import annotations
 
+import argparse
 import os
 from typing import Callable, Optional
 
@@ -74,7 +76,7 @@ def enable_compilation_cache(
     log: Optional[Callable[[str], None]] = None,
 ) -> str:
     """The one persistent compile-cache rule (serving server, engine CLI,
-    benchmark runner, bench.py).  Where ``JAX_COMPILATION_CACHE_DIR`` is
+    benchmark runner).  Where ``JAX_COMPILATION_CACHE_DIR`` is
     set — the deploy manifests point it at their mounted emptyDir — JAX
     reads it itself and this sets no directory in code; otherwise the
     cache lives at ``<checkout>/.jax_cache``.  Returns the directory in
@@ -96,3 +98,11 @@ def enable_compilation_cache(
     if log is not None:
         log(f"persistent compilation cache at {cache_dir}")
     return cache_dir
+
+
+def positive_int(text: str) -> int:
+    """argparse type: an integer >= 1."""
+    value = int(text)
+    if value < 1:
+        raise argparse.ArgumentTypeError(f"must be >= 1, got {value}")
+    return value

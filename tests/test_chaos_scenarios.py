@@ -17,8 +17,8 @@ JSON result carries the exact figures for the scenario-matrix report:
 
 Every test is `slow`: tier-1 collects this module (imports stay
 jax-free at module scope) and deselects every item; a conftest guard
-fails collection if the marker ever goes missing (the 870s tier-1
-budget has no headroom for fleet simulation).
+fails collection if the marker ever goes missing (fleet simulations
+run for minutes).
 """
 
 import importlib.util

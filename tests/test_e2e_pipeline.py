@@ -44,18 +44,16 @@ print(json.dumps({"devices": n_chips, "result": float(y),
 
 
 @pytest.fixture
-def stack(tmp_path):
+def stack(tmp_path, plugin_dir):
     host_root = make_fake_tpu_host(tmp_path / "host", n_chips=4)
-    plugin_dir = tmp_path / "device-plugins"
-    plugin_dir.mkdir()
-    kubelet = FakeKubelet(str(plugin_dir))
+    kubelet = FakeKubelet(plugin_dir)
     kubelet.start()
     plugin = TpuDevicePlugin(
         discover=lambda: discovery.discover(root=host_root, environ={}),
         health_checker=ChipHealthChecker(root=host_root),
     )
     manager = PluginManager(
-        plugin, plugin_dir=str(plugin_dir), watch_poll_interval=0.1
+        plugin, plugin_dir=plugin_dir, watch_poll_interval=0.1
     )
     manager.start()
     assert kubelet.registered.wait(5)

@@ -127,7 +127,6 @@ def test_page_boundary_crossing(rng):
     assert req.tokens == _oracle(cfg, params, prompt, 9)
 
 
-@pytest.mark.slow  # composition blanket: concurrency blanket; interleaving stays pinned by test_concurrent_submit_while_stepping
 def test_concurrent_requests_independent(rng):
     """Several live slots share one pool; outputs match per-request
     dense decoding (no cross-slot leakage through the pages)."""
@@ -262,11 +261,6 @@ def test_engine_metrics(rng):
     assert "tpu_engine_tokens_total" in text and "tpu_engine_free_pages" in text
 
 
-# Composition blankets ride --slow (the PR 13 buy-back pattern): each
-# feature keeps its own targeted tier-1 pin, and the cross-product runs
-# in the slow tier — tier-1 sits within seconds of its 870s driver
-# timeout on the 1-core box, and these are its priciest redundancy.
-@pytest.mark.slow
 def test_engine_composes_with_gqa_window_and_quant(rng):
     """The serving engine must work for the model features decode supports:
     GQA (grouped cache), sliding-window masking, and int8 weights — each
@@ -293,7 +287,6 @@ def test_engine_composes_with_gqa_window_and_quant(rng):
     assert qreq.tokens == _oracle(qcfg, qparams, prompt, 6)
 
 
-@pytest.mark.slow  # composition blanket: mixed-mode blanket; greedy parity + sampled invariants stay pinned by test_single_request_matches_dense_decode and test_top_k_restricts_every_emitted_token
 def test_mixed_greedy_and_sampled_slots(rng):
     """A sampling request sharing the batch must not perturb a greedy
     neighbor (its tokens still match the dense oracle exactly), sampled
@@ -501,7 +494,6 @@ def test_engine_with_int8_paged_kv(rng):
     assert eng._kv_rows_nbytes(rows) == cfg.num_layers * (codes + scales)
 
 
-@pytest.mark.slow  # composition blanket (see the buy-back note above)
 def test_engine_int8_kv_composes_with_window_and_spec(rng):
     """quant_kv + sliding window + speculation on one engine: the draft
     writes quantized approximate K/V, the verify overwrites quantized
@@ -542,7 +534,6 @@ def test_kernel_with_int8_paged_kv(rng):
     assert len(eng.free_pages) == paged.num_pages - 1
 
 
-@pytest.mark.slow
 def test_kernel_int8_kv_composes_with_window(rng):
     """use_kernel + quant_kv + sliding window: int8 pages stream through
     the windowed kernel mask while reclamation re-points scrolled
@@ -583,7 +574,6 @@ def test_spec_engine_matches_dense_oracle(rng):
     assert len(eng.free_pages) == paged.num_pages - 1
 
 
-@pytest.mark.slow
 def test_spec_engine_composes_with_window_and_kernel(rng):
     """Speculation + sliding window + the paged kernel (single-token
     draft steps ride the kernel, the multi-token verify rides the gather
@@ -646,7 +636,6 @@ def test_spec_engine_validation(rng):
         ServingEngine(cfg, params, paged, spec_gamma=-1, draft_params=qparams)
 
 
-@pytest.mark.slow  # composition blanket (tier-1 budget buy-back, PR 15):
 # spec×sampled mixing in one batch.  The targeted pins stay tier-1 —
 # test_spec_engine_matches_dense_oracle (greedy spec engine) here, and
 # the acceptance-rejection distribution-exactness pins in
@@ -754,7 +743,7 @@ def test_engine_fuzz_random_schedules(rng):
     npr = np.random.RandomState(7)
     # One geometry trial: the second (pow2-ps) geometry is covered
     # by every targeted test above, and the full randomized blanket
-    # (feature-matrix fuzz) rides --slow since ISSUE 13.
+    # is test_engine_feature_matrix_fuzz.
     for trial, (ps, n_pages, mpp, slots) in enumerate(
         [(3, 12, 9, 2)]
     ):
@@ -833,7 +822,6 @@ def test_chunked_prefill_interleaves_with_decode(rng):
     assert len(eng.free_pages) == paged.num_pages - 1
 
 
-@pytest.mark.slow  # composition blanket: chunking x prefix-share composition; each stays pinned by test_chunked_prefill_matches_oracle and test_prefix_sharing_shares_pages_and_preserves_outputs
 def test_chunked_prefill_prefix_share_waits_for_graft(rng):
     """A later request must NOT prefix-share pages whose owner's chunked
     prefill hasn't grafted yet (it would decode against zeros): B (small
@@ -867,7 +855,6 @@ def test_chunked_prefill_prefix_share_waits_for_graft(rng):
     )
 
 
-@pytest.mark.slow  # composition blanket (see the buy-back note above)
 def test_chunked_prefill_composes_with_spec_and_window(rng):
     from k8s_device_plugin_tpu.ops.quant import quantize_lm_params
 
@@ -926,7 +913,6 @@ def _assert_tokens_match_or_quant_tie(
         )
 
 
-@pytest.mark.slow
 def test_engine_feature_matrix_fuzz(rng):
     """Randomized blanket over the COMPOSED feature matrix: window x
     kernel x quant_kv x (speculation | decode blocks) x admission x
@@ -1179,7 +1165,6 @@ def test_decode_block_eos_and_max_new_mid_block(rng):
     assert req2.tokens == _oracle(cfg, params, prompt, 5)
 
 
-@pytest.mark.slow  # composition blanket (see the buy-back note above)
 def test_decode_block_composes_with_window_kernel_and_pages(rng):
     """Blocks cross page boundaries (page_size=2 < T=4), stream through
     the paged kernel, and windowed reclamation still frees scrolled
@@ -1197,7 +1182,6 @@ def test_decode_block_composes_with_window_kernel_and_pages(rng):
     assert len(eng.free_pages) == paged.num_pages - 1
 
 
-@pytest.mark.slow  # composition blanket: sampled decode-block variant; block parity stays pinned by test_decode_block_matches_single_step_greedy
 def test_decode_block_sampled_slots(rng):
     """Sampled slots in a block draw per-step from the same filtered
     distributions (different key schedule than single-stepping, same
@@ -1230,7 +1214,6 @@ def test_decode_block_sampled_slots(rng):
         ctx.append(tok)
 
 
-@pytest.mark.slow  # composition blanket: churn composition; block parity stays pinned by test_decode_block_matches_single_step_greedy and test_decode_blocks_engage_while_page_blocked
 def test_decode_block_stays_fine_grained_under_churn(rng):
     """With queued work the engine must NOT block-decode (admission
     latency); mid-flight submissions still join live and everything
@@ -1378,7 +1361,6 @@ def test_logprobs_match_dense_replay(rng):
     assert plain.token_logprobs == []
 
 
-@pytest.mark.slow  # composition blanket: logprobs x blocks composition; logprobs stay pinned by test_logprobs_match_dense_replay
 def test_logprobs_through_decode_blocks(rng):
     cfg = _cfg()
     params = _params(cfg, rng)
@@ -1483,7 +1465,6 @@ def test_optimistic_preemption_preserves_prefix_sharing(rng):
     assert len(eng.free_pages) == paged.num_pages - 1
 
 
-@pytest.mark.slow  # composition blanket (see the buy-back note above)
 def test_optimistic_composes_with_blocks_and_window(rng):
     """Decode blocks grow their T-token frontier through the optimistic
     allocator, and windowed reclamation returns pages to the shared
@@ -1756,7 +1737,6 @@ def test_steady_state_feeds_device_outputs_forward(rng):
     assert req.tokens == _oracle(cfg, params, prompt, 12)
 
 
-@pytest.mark.slow  # composition blanket: saturation composition; engagement stays pinned by test_decode_blocks_engage_while_page_blocked
 def test_decode_blocks_engage_while_saturated_with_queue(rng):
     """A loaded server (every slot busy, more requests queued) must still
     use decode blocks — no admission is possible until a finish anyway.

@@ -9,7 +9,7 @@ stream, because a restored page carries exactly the bytes the graft (or
 decode append) originally wrote — and recompute at the same length
 bucket writes the same bytes.
 
-Budget note: tier-1 runs within ~20s of its 870s ceiling, so every test
+Budget note: compiles are this suite's cost, so every test
 reuses the session-scoped compiled engine (tests/conftest.py
 ``shared_engine``), keeps prompts inside the length buckets other tests
 already compile (<= 4 tokens -> bucket 4), and samples with plain

@@ -9,7 +9,7 @@ SAME jitted program fed the same state, only issued earlier.  (Every
 dense-oracle test in test_engine.py already runs WITH overlap on — this
 module pins the mode equivalence and the discard machinery.)
 
-Budget note: tier-1 runs within ~30s of its 870s ceiling, so both tests
+Budget note: compiles are this suite's cost, so both tests
 reuse the session-scoped compiled engine (tests/conftest.py
 ``shared_engine``) — no new XLA compiles; prompts stay in the length
 buckets the fixture's first run compiles.

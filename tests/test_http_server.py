@@ -81,7 +81,6 @@ def test_generate_matches_oracle(served):
     assert got["tokens"] == _oracle(cfg, params, prompt, 6)
 
 
-@pytest.mark.slow  # composition blanket: HTTP concurrency blanket; engine-level interleaving stays pinned by test_engine.py::test_concurrent_submit_while_stepping
 def test_concurrent_requests_all_correct(served):
     cfg, params, server = served
     prompts = [[3, 141, 59], [400, 2, 2, 17], [9], [7, 7, 3], [5, 6]]
@@ -291,7 +290,6 @@ def test_stop_sequences_over_http_and_stream(served):
     assert streamed == done["tokens"] == want[:first]
 
 
-@pytest.mark.slow  # composition blanket: opt-in --debug-trace surface; span nesting stays pinned by test_debug_spans_endpoint_shape_and_rid_filter and the forensics drive
 def test_debug_trace_endpoint(served):
     """POST /debug/trace captures a jax.profiler trace of the live loop
     and replies with the dir (which must contain profile output)."""
@@ -616,7 +614,6 @@ def test_sigusr2_dumps_live_engine_flight(served, tmp_path):
         flight_mod.unregister(box)
 
 
-@pytest.mark.slow  # composition blanket: live profiler capture; GET /debug/profile breakdown stays pinned in tier-1 and the forensics drive covers the capture POST
 def test_profile_capture_spans_live_steps(served):
     """POST /debug/profile/capture grabs a jax.profiler trace spanning
     the next engine step(s) of a LIVE serving loop."""
@@ -1111,7 +1108,6 @@ def test_fence_endpoints_healthz_summary_and_admission(served):
     assert got["tokens"] == _oracle(cfg, params, prompt, 6)
 
 
-@pytest.mark.slow
 def test_watchdog_fence_cuts_stream_no_done_event(shared_engine):
     """The hung-step fence end to end on a live server: a readback hang
     (the `engine.readback` hang failpoint — the wedged-DMA shape) trips
@@ -1119,10 +1115,8 @@ def test_watchdog_fence_cuts_stream_no_done_event(shared_engine):
     CUT with no done/error event (the shape the router's zero-drop
     failover resubmits).  Unfence re-arms: the replica serves again.
 
-    Slow-marked (tier-1 runs ~10s from its 870s hard timeout): the same
-    contract is scored with measured precision/recall by the
-    readback-hang chaos scenario; tier-1 keeps the fast fence-endpoint
-    coverage above and the fake-clock watchdog units."""
+    The same contract is scored with measured precision/recall by the
+    readback-hang chaos scenario."""
     from k8s_device_plugin_tpu.models.engine_watchdog import StepWatchdog
     from k8s_device_plugin_tpu.utils import failpoints
 

@@ -42,10 +42,8 @@ def plugin(host_root):
 
 
 @pytest.fixture
-def kubelet(tmp_path):
-    plugin_dir = tmp_path / "device-plugins"
-    plugin_dir.mkdir()
-    kubelet = FakeKubelet(str(plugin_dir))
+def kubelet(plugin_dir):
+    kubelet = FakeKubelet(plugin_dir)
     kubelet.start()
     yield kubelet
     kubelet.stop()
@@ -76,14 +74,12 @@ def test_start_registers_with_kubelet(plugin, kubelet):
     assert not os.path.exists(manager.socket_path)
 
 
-def test_registration_failure_rolls_back_server(plugin, tmp_path):
+def test_registration_failure_rolls_back_server(plugin, plugin_dir):
     # No kubelet at all: registration must fail after retries and the plugin
     # socket must NOT be left behind (≙ dpm/plugin.go:83-87).
-    plugin_dir = tmp_path / "device-plugins"
-    plugin_dir.mkdir()
     manager = PluginManager(
         plugin,
-        plugin_dir=str(plugin_dir),
+        plugin_dir=plugin_dir,
         register_retries=2,
         register_retry_delay=0.05,
     )

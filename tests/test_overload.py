@@ -3,7 +3,7 @@ engine_admission.py): priority ordering, per-tenant fairness, deadline
 expiry/infeasibility sheds, the AIMD limiter's step response, submit-side
 shedding, and the bit-identical-with-controller-off contract.
 
-Budget note: tier-1 runs within ~30s of its 870s ceiling, so the engine
+Budget note: compiles are this suite's cost, so the engine
 tests ride the session-scoped compiled ``shared_engine`` fixture
 (tests/conftest.py) and are shaped so admission never needs a prefill
 program earlier suites haven't compiled: prompts stay in the warmed

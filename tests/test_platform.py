@@ -1,6 +1,6 @@
 """utils/platform.py: the device facts every record names, the peak MFU
 is taken against, and the one compile-cache rule every entry point shares
-(serving server, engine CLI, benchmark runner, bench.py)."""
+(serving server, engine CLI, benchmark runner)."""
 
 import os
 import sys

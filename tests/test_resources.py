@@ -44,10 +44,8 @@ def host_root(tmp_path):
 
 
 @pytest.fixture
-def kubelet(tmp_path):
-    plugin_dir = tmp_path / "device-plugins"
-    plugin_dir.mkdir()
-    kubelet = FakeKubelet(str(plugin_dir))
+def kubelet(plugin_dir):
+    kubelet = FakeKubelet(plugin_dir)
     kubelet.start()
     yield kubelet
     kubelet.stop()
@@ -188,10 +186,8 @@ class VersionRejectingKubelet(FakeKubelet):
         )
 
 
-def test_version_mismatch_logged_and_retried(host_root, tmp_path, caplog):
-    plugin_dir = tmp_path / "device-plugins"
-    plugin_dir.mkdir()
-    kubelet = VersionRejectingKubelet(str(plugin_dir))
+def test_version_mismatch_logged_and_retried(host_root, plugin_dir, caplog):
+    kubelet = VersionRejectingKubelet(plugin_dir)
     kubelet.start()
     manager = PluginManager(
         make_plugin(host_root),
@@ -214,16 +210,14 @@ def test_version_mismatch_logged_and_retried(host_root, tmp_path, caplog):
         kubelet.stop()
 
 
-def test_failed_start_retried_when_kubelet_appears(host_root, tmp_path):
+def test_failed_start_retried_when_kubelet_appears(host_root, plugin_dir):
     """Kubelet down at publish time: the resource must NOT be dropped forever
     — the kubelet-create event retries it (multi-resource parity with the
     single-resource daemon's crash-and-restart behavior)."""
-    plugin_dir = tmp_path / "device-plugins"
-    plugin_dir.mkdir()
     lister = PushLister(host_root)
     multi = MultiResourceManager(
         lister,
-        plugin_dir=str(plugin_dir),
+        plugin_dir=plugin_dir,
         watch_poll_interval=0.05,
         register_retries=1,
         register_retry_delay=0.05,
@@ -236,7 +230,7 @@ def test_failed_start_retried_when_kubelet_appears(host_root, tmp_path):
         assert wait_until(lambda: multi.resources() == [], timeout=5)
 
         # Kubelet comes up; the watcher fires create; the resource recovers.
-        kubelet = FakeKubelet(str(plugin_dir))
+        kubelet = FakeKubelet(plugin_dir)
         kubelet.start()
         assert wait_until(lambda: multi.resources() == ["tpu"], timeout=10)
         assert kubelet.registered.wait(5)

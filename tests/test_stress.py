@@ -9,7 +9,6 @@ assert nothing deadlocks, crashes, or serves a torn snapshot.
 from __future__ import annotations
 
 import os
-import tempfile
 import threading
 import time
 from concurrent import futures
@@ -34,7 +33,7 @@ DURATION_S = 3.0
 
 
 @pytest.fixture()
-def served_plugin(tmp_path):
+def served_plugin(tmp_path, plugin_dir):
     root = make_fake_tpu_host(str(tmp_path / "host"), n_chips=N_CHIPS)
     plugin = TpuDevicePlugin(
         discover=lambda: discovery.discover(root=root, environ={}),
@@ -42,7 +41,7 @@ def served_plugin(tmp_path):
     )
     server = grpc.server(futures.ThreadPoolExecutor(max_workers=THREADS + 4))
     add_device_plugin_servicer(plugin, server)
-    sock = tempfile.mktemp(suffix=".sock")
+    sock = os.path.join(plugin_dir, "plugin.sock")
     server.add_insecure_port(f"unix://{sock}")
     server.start()
     channel = grpc.insecure_channel(f"unix://{sock}")

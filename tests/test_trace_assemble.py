@@ -3,14 +3,18 @@ replica span dumps into per-request timelines, orphan/gap/broken-link
 verdicts, skew normalization, completeness detections, and the file
 loaders.  Pure stdlib — no sockets, no JAX; the live-endpoint mode is
 exercised against real router/replica processes in tests/test_router.py
-and the chaos suite."""
+and the chaos suite.  The scorer the chaos suite joins its detections
+with (tools/chaos_report.py) is pinned here too."""
 
 from __future__ import annotations
 
 import json
 
+import pytest
+
 from k8s_device_plugin_tpu.utils.spans import SpanRecorder, format_span_id
 
+from tools import chaos_report
 from tools import trace_assemble as ta
 
 
@@ -166,16 +170,6 @@ def test_completeness_detections_and_attempt_count_gate():
 
 
 def test_detections_join_with_chaos_report_scoring():
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "chaos_report",
-        os.path.join(os.path.dirname(os.path.dirname(__file__)),
-                     "tools", "chaos_report.py"),
-    )
-    chaos_report = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(chaos_report)
     timelines = ta.assemble(_happy_sources())
     injected = [
         {"cls": "trace_complete", "rid": "t-1", "t0": 999.0, "t1": 1600.0},
@@ -187,6 +181,54 @@ def test_detections_join_with_chaos_report_scoring():
     cls = score["per_class"]["trace_complete"]
     assert cls["tp"] == 1 and cls["fn"] == 1 and cls["fp"] == 0
     assert cls["precision"] == 1.0 and cls["recall"] == 0.5
+
+
+def test_chaos_report_scoring_and_summary():
+    """tools/chaos_report.py: the precision/recall join semantics the
+    scenario matrix depends on — window+key matching, multi-report
+    faults not double-counted as FPs, worst-class summary — pinned
+    hermetically (no fleet needed)."""
+    injected = [
+        {"cls": "chip_unplug", "node": 0, "device": "tpu-1",
+         "t0": 100.0, "t1": 101.0},
+        {"cls": "chip_unplug", "node": 2, "device": "tpu-3",
+         "t0": 100.0, "t1": 101.0},
+    ]
+    detected = [
+        # Matches fault 1 (in window, keys agree)...
+        {"cls": "chip_unplug", "node": 0, "device": "tpu-1", "ts": 100.4},
+        # ...a cooldown re-fire of the SAME fault: matched window, not FP.
+        {"cls": "chip_unplug", "node": 0, "device": "tpu-1", "ts": 100.9},
+        # A detection nothing injected: false positive.
+        {"cls": "chip_unplug", "node": 5, "device": "tpu-0", "ts": 100.5},
+    ]
+    score = chaos_report.score_detections(injected, detected, grace_s=1.0)
+    c = score["per_class"]["chip_unplug"]
+    assert (c["tp"], c["fp"], c["fn"]) == (1, 1, 1)
+    assert c["precision"] == pytest.approx(2 / 3)
+    assert c["recall"] == pytest.approx(0.5)
+    assert c["latency_p50_s"] == pytest.approx(0.4)
+    results = [
+        {"scenario": "s1", "score": score, "slo": {"pass": True},
+         "pass": False},
+        {"scenario": "s2",
+         "score": chaos_report.score_detections(
+             [{"cls": "drift", "t0": 0.0, "t1": 1.0}],
+             [{"cls": "drift", "ts": 0.5}],
+         ),
+         "slo": {"pass": False}, "pass": True},
+    ]
+    summary = chaos_report.chaos_summary(results)
+    assert summary["scenarios"] == 2
+    assert summary["passed"] == 1
+    assert summary["precision"] == pytest.approx(2 / 3, abs=1e-3)  # worst class
+    assert summary["recall"] == 0.5  # worst class
+    assert summary["slo_pass"] is False
+    matrix = chaos_report.render_matrix(results)
+    assert "| s1 | chip_unplug |" in matrix
+    assert "| s2 | drift |" in matrix
+    row = chaos_report.ledger_row(results)
+    assert "1/2 scenarios" in row and "SLO FAIL" in row
 
 
 def test_engine_and_daemon_traces_are_not_timelines():

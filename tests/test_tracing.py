@@ -83,7 +83,6 @@ def test_benchmark_gpt_train_smoke(capsys):
     assert out["throughput"] > 0
 
 
-@pytest.mark.slow  # composition blanket: decode benchmark smoke; the harness stays pinned by test_benchmark_gpt_train_smoke and test_benchmark_sampled_decode_smoke
 def test_benchmark_gpt_decode_smoke(capsys, tmp_path):
     from k8s_device_plugin_tpu.models import benchmark
 
@@ -117,7 +116,6 @@ def test_benchmark_sampled_decode_smoke(capsys):
     assert out["throughput"] > 0
 
 
-@pytest.mark.slow  # composition blanket: pipelined benchmark smoke; the harness stays pinned by test_benchmark_gpt_train_smoke
 def test_benchmark_pipelined_1f1b_smoke(capsys):
     from k8s_device_plugin_tpu.models import benchmark
 

@@ -12,9 +12,8 @@ collects what the stack's OWN detectors reported (flight events,
   what our detectors catch".
 - :func:`render_matrix` renders a markdown scenario-matrix table
   (docs/chaos.md embeds one).
-- :func:`chaos_summary` / :func:`ledger_row` fold a result set into the
-  JSON `chaos` block `tools/bench_diff.py` understands and a
-  perf-ledger-shaped markdown row.
+- :func:`chaos_summary` / :func:`ledger_row` fold a result set into
+  one JSON `chaos` block and one markdown row.
 
 Usage (scenario tests write one JSON result per scenario into
 $TPU_CHAOS_RESULTS_DIR):
@@ -201,9 +200,8 @@ def render_matrix(results: list[dict]) -> str:
 
 
 def chaos_summary(results: list[dict]) -> dict:
-    """The `chaos` JSON block bench records carry (parsed by
-    tools/bench_diff.py): scenario counts plus the WORST per-class
-    precision/recall across the whole run — a single regressing
+    """The `chaos` JSON block of a run: scenario counts plus the WORST
+    per-class precision/recall across the whole run — a single regressing
     detector must drag the headline number, not hide in an average."""
     precisions: list[float] = []
     recalls: list[float] = []

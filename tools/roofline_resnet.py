@@ -95,8 +95,8 @@ def main() -> None:
         f"train-step lower bounds (b{B}, optimistic bytes): "
         f"MXU {t_mxu*1e3:.1f} ms, HBM {t_bw*1e3:.1f} ms"
     )
-    # True-FLOP convention throughout (2 FLOPs/MAC, like the LM 6ND count
-    # and bench.py since r4); pre-r4 logs called 3200 ips "20% MFU" from
+    # True-FLOP convention throughout (2 FLOPs/MAC, like the LM 6ND
+    # count); pre-r4 logs called 3200 ips "20% MFU" from
     # the MAC-based constant — it is 40% true MFU.
     for ips, label in [
         (2070.8, "r3 measured f32-BN"),
