@@ -402,6 +402,12 @@ class ServingEngine(
         self._lock = threading.RLock()
         self._next_rid = 0
         self._prefill_cache: dict[int, Any] = {}
+        # The compiled makers of a prefill job's zero dense cache by
+        # (bucket, batch) (engine_admission._zero_prefill_cache), the
+        # jobs started and the makers' dispatches: one a job.
+        self._prefill_cache_makers: dict[tuple[int, int], Any] = {}
+        self.prefill_jobs = 0
+        self.prefill_cache_dispatches = 0
         # Compiled writers into the device cache tree
         # (engine_paging._cache_write) and their dispatches by operation.
         self._cache_writers: dict[tuple, Any] = {}

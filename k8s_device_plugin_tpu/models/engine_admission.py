@@ -430,6 +430,52 @@ class AdmissionMixin:
         self._prefill_cache[key] = fn
         return fn
 
+    def _zero_prefill_cache(self, bucket: int, batch: int):
+        """The zero dense cache a prefill job starts from: ONE dispatch
+        of one compiled program of NO operands per (bucket, batch),
+        whatever the number of leaves (K, V and an index a layer, a
+        mixer's state and convolution tail beside them) — an eager
+        ``jnp.zeros`` a leaf costs the owner loop a dispatch each, the
+        dominant per-admission host cost.  The spec is an abstract
+        trace of the whole model (~100ms of host work) and depends only
+        on (bucket, batch): it is traced once, when the maker is built,
+        and the maker is cached on THIS instance like
+        _prefill_chunk_fn.  Every leaf comes back as its own buffer,
+        uncommitted on the default device, like the chunk program's
+        own output: that program donates the whole tree and is
+        compiled once a key."""
+        key = (bucket, batch)
+        make = self._prefill_cache_makers.get(key)
+        if make is None:
+            self._wd_grace(f"compile:prefill_cache_{batch}x{bucket}")
+            spec = decode_cache_spec(self._dense_chunk_model(bucket), batch)
+
+            def zero_cache():
+                return jax.tree.map(
+                    lambda s: jnp.zeros(s.shape, s.dtype), spec
+                )
+
+            make = self._prefill_cache_makers[key] = jax.jit(zero_cache)
+        self.prefill_cache_dispatches += 1
+        if self.metrics:
+            self.metrics.prefill_cache_dispatches.inc()
+        return make()
+
+    def prefill_cache_state(self) -> dict:
+        """The ``prefill_cache`` block of ``GET /debug/profile``: the
+        admission groups started (tpu_engine_prefill_jobs_total), the
+        dispatches of the zero-cache makers
+        (tpu_engine_prefill_cache_dispatches_total; over the jobs it
+        reads 1.0) and the compiled makers held, counted from the jit
+        caches: one per (bucket, batch) met, so a maker that recompiled
+        per prompt length would show."""
+        makers = list(self._prefill_cache_makers.values())
+        return {
+            "jobs": self.prefill_jobs,
+            "dispatches": self.prefill_cache_dispatches,
+            "programs": sum(fn._cache_size() for fn in makers),
+        }
+
     @in_phase("schedule.start_prefill")
     def _start_prefill(self, items: list[tuple[int, "Request", list[int], int]]):
         """Create one prefill JOB for a same-length-bucket admission group.
@@ -486,17 +532,10 @@ class AdmissionMixin:
             it[1].adapter if it[1].adapter is not None else -1 for it in items
         ]
         aids += [aids[0]] * (batch - n)  # pad rows are discarded anyway
-        # decode_cache_spec is an abstract trace of the whole model
-        # (~100ms of host work) and depends only on (bucket, batch):
-        # cache it like _dense_chunk_models, or EVERY admission pays a
-        # model trace before its prefill even dispatches — the dominant
-        # per-admission host cost on fast backends.
-        spec_key = (bucket, batch)
-        spec = self._prefill_cache.get(("spec", spec_key))
-        if spec is None:
-            spec = decode_cache_spec(self._dense_chunk_model(bucket), batch)
-            self._prefill_cache[("spec", spec_key)] = spec
-        cache = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), spec)
+        self.prefill_jobs += 1
+        if self.metrics:
+            self.metrics.prefill_jobs.inc()
+        cache = self._zero_prefill_cache(bucket, batch)
         ps = self.paged.page_size
         skip = 0
         if self._handoff_skip_covered:

@@ -207,6 +207,17 @@ class EngineMetrics:
             "chain writer; over tpu_engine_chain_write_dispatches_total "
             "it reads the pages a dispatch carries",
         )
+        self.prefill_jobs = registry.counter(
+            "tpu_engine_prefill_jobs_total",
+            "Prefill jobs started: one an admission group (the prompts "
+            "of one length bucket admitted in one pass)",
+        )
+        self.prefill_cache_dispatches = registry.counter(
+            "tpu_engine_prefill_cache_dispatches_total",
+            "Dispatches of the compiled makers of a prefill job's zero "
+            "dense cache; over tpu_engine_prefill_jobs_total it reads "
+            "1.0: one dispatch a job, whatever the leaves of the tree",
+        )
         self.slot_state_bytes = registry.gauge(
             "tpu_engine_slot_state_bytes",
             "Device bytes of all per-slot cache leaves (a mixer's "

@@ -1621,6 +1621,7 @@ class EngineServer:
                         {
                             **server.engine.profiler.snapshot(),
                             "cache_writes": server.engine.cache_writes_state(),
+                            "prefill_cache": server.engine.prefill_cache_state(),
                         },
                     )
                 elif path == "/debug/disagg":

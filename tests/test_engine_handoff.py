@@ -249,9 +249,13 @@ def test_decode_role_skips_covered_prefill_bit_identical(tiered_engine):
     eng.role = "decode"
     eng._handoff_skip_covered = True
     skipped0, restores0 = eng.handoff_skipped_tokens, eng.kv_restores
+    dispatches0 = eng.prefill_cache_dispatches
     got = eng.run([(prompt, 6)])[0].tokens
     assert got == ref, "handed-off decode must be bit-identical"
     assert eng.handoff_skipped_tokens > skipped0, "prefill was not skipped"
+    # The seed wrote into the tree the compiled maker returned
+    # (_zero_prefill_cache): one dispatch for the one job.
+    assert eng.prefill_cache_dispatches == dispatches0 + 1
     assert eng.kv_restores > restores0, "pages were not restored"
     _reseed()
     got_sampled = eng.run([(prompt, 6)], temperature=0.7, top_k=40)[0].tokens

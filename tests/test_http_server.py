@@ -536,6 +536,9 @@ def test_debug_endpoints_smoke(served):
     )
     assert prof["step_ms"]["p99"] >= prof["step_ms"]["p50"] > 0
     assert prof["occupancy"]["mean_kv_page_utilization"] >= 0.0
+    # A prefill job's zero cache is one dispatch of its compiled maker.
+    made = prof["prefill_cache"]
+    assert made["jobs"] == made["dispatches"] >= made["programs"] >= 1
     inc = _get(server.port, "/debug/incidents")
     assert "incidents" in inc and "detectors" in inc
     fl = _get(server.port, "/debug/flight")
