@@ -60,7 +60,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from .engine_admission import AdmissionMixin
 from .engine_handoff import HandoffMixin
 from .engine_kvcache import KVCacheMixin
-from .engine_paging import PagingMixin, slot_state_bytes
+from .engine_paging import PagingMixin, cache_bytes_per_token, slot_state_bytes
 from .engine_sampling import (  # noqa: F401  (re-export: public surface)
     _token_logprob,
     build_block_fn,
@@ -80,6 +80,7 @@ from ..utils.flight import FlightRecorder
 from ..utils.platform import device_facts
 from ..utils.spans import ENGINE_TRACE, SpanRecorder
 from .engine_profiler import EngineProfiler
+from .moe import STATS
 from .transformer import (
     GPTConfig,
     PagedConfig,
@@ -176,6 +177,25 @@ class ServingEngine(
                 f"prefill_chunk must be a power of two, got {prefill_chunk}"
             )
         self._prefill_chunk = prefill_chunk
+        if cfg.mla is not None:
+            # What assumed K and V pools, or one attention and one MLP a
+            # block, refuses rather than serve wrong numbers (docs/serving.md,
+            # "Latent attention and expert layers"): here, in
+            # _validate_role, and in the modules themselves (quant, LoRA,
+            # use_kernel: raised when the cache tree is traced below).
+            what = "latent attention and expert layers (cfg.mla, cfg.moe)"
+            if spec_gamma > 0:
+                raise ValueError(
+                    f"spec_gamma > 0 is not supported with {what}: the self-draft is the "
+                    "model int8-quantized, which neither has, and the round programs "
+                    "carry no routing counts"
+                )
+            if mesh is not None and dict(mesh.shape).get(tp_axis, 1) > 1:
+                raise ValueError(
+                    f"tp={dict(mesh.shape)[tp_axis]} is not supported with {what}: the "
+                    "sharding contract (parallel/serving.py) splits pools on a kv-heads "
+                    "axis a latent row does not have, and has no rule for experts"
+                )
         if spec_gamma > 0:
             # Shared-pool speculation: the draft writes its (approximate)
             # K/V at the frontier and the verify pass overwrites those
@@ -260,6 +280,7 @@ class ServingEngine(
         # that BUILDS it refuses below or falls back (restore-resume:
         # engine_kvcache.py; split roles: engine_handoff.py).
         self.slot_state_bytes = slot_state_bytes(self.cache)
+        self.cache_bytes_per_token = cache_bytes_per_token(self.cache)
         if self.slot_state_bytes and spec_gamma > 0:
             raise ValueError(
                 "spec_gamma > 0 is not supported on a model with per-slot "
@@ -453,6 +474,21 @@ class ServingEngine(
         if metrics:
             metrics.tp_size.set(self.tp_size)
             metrics.slot_state_bytes.set(self.slot_state_bytes)
+            metrics.cache_bytes_per_token.set(self.cache_bytes_per_token)
+        # Routing counts of a model with expert layers (models/moe.py),
+        # summed on the host from what the decode programs pack behind
+        # their tokens and the prefill chunks hand back (_moe_fold): rows
+        # are the expert layers, columns moe.STATS then a count per held
+        # expert.  None for a model without them.
+        self._moe_shape = None
+        if cfg.moe is not None:
+            self._moe_shape = (cfg.num_layers // 2, cfg.moe.stats_width)
+            self.moe_counts = {
+                phase: np.zeros(self._moe_shape, np.int64) for phase in ("decode", "prefill")
+            }
+            if metrics:
+                # The series exist from the start: a scrape reads 0, not nothing.
+                self._moe_fold("decode", np.zeros(self._moe_shape, np.int64))
         # Forensics layer (always on — a production incident cannot ask
         # for instrumentation retroactively, and all three pieces are
         # stdlib-cheap): a bounded flight-recorder black box of typed
@@ -919,6 +955,10 @@ class ServingEngine(
             "T": T,
             "out": out,
             "want_lp": want_lp,
+            "moe_shape": self._moe_shape,
+            # The readback's shape before the routing counts were packed
+            # behind it (engine_sampling.pack_stats).
+            "out_shape": (2,) * want_lp + (self.max_slots,) + (T,) * (T > 1),
             "active": list(active),
             "reqs": [self.slots[s] for s in active],
             "dev": self._feed_forward(dev, ff_tok, ff_pos, ff_key),
@@ -997,6 +1037,10 @@ class ServingEngine(
         # per step.
         hit = failpoints.fire("engine.readback")
         arr = np.asarray(rec["out"])
+        if rec.get("moe_shape"):
+            n = rec["moe_shape"][0] * rec["moe_shape"][1]
+            rec["moe_stats"] = arr[-n:].astype(np.int64).reshape(rec["moe_shape"])
+            arr = arr[:-n].reshape(rec["out_shape"])
         if rec["want_lp"]:
             toks, lps = arr[0].astype(np.int64), arr[1]
         else:
@@ -1012,6 +1056,46 @@ class ServingEngine(
             flat = toks.view(np.uint8).reshape(-1)
             flat[: max(1, min(nbytes, flat.size))] ^= 0x01
         return toks, lps
+
+    def _moe_fold(self, phase: str, stats) -> None:
+        """Add one dispatch's routing counts ([expert layers, stats],
+        already on the host) to the engine's sums and counters.  A decode
+        dispatch that was discarded unread is not counted, like its
+        tokens; a finished slot's tail iterations inside a block are."""
+        if stats is None:
+            return
+        self.moe_counts[phase] += stats
+        m = self.metrics
+        if not m:
+            return
+        col = {name: int(stats[:, i].sum()) for i, name in enumerate(STATS)}
+        for kind in ("held", "identity", "absent"):
+            m.moe_assignments.inc(col[kind], kind=kind)
+        m.moe_identity.inc(col["identity"])
+        m.moe_dropped.inc(col["dropped"])
+        per_expert = self.moe_counts["decode"] + self.moe_counts["prefill"]
+        m.moe_expert_peak.set(int(per_expert[:, len(STATS):].max()))
+        for layer, row in enumerate(stats[:, len(STATS):]):
+            for expert, n in zip(self.cfg.moe.held, row):
+                if n:
+                    m.moe_expert_tokens.inc(int(n), layer=str(layer), expert=str(expert))
+        if phase == "decode":
+            m.moe_decode_touched.inc(col["touched"])
+            m.moe_decode_layer_steps.inc(col["active"])
+
+    def moe_state(self) -> Optional[dict]:
+        """The ``moe`` block of ``GET /debug/profile``: the same sums as
+        the tpu_engine_moe_* counters, by phase; None for a model without
+        expert layers."""
+        if self._moe_shape is None:
+            return None
+        out = {"held_experts": list(self.cfg.moe.held), "cache_bytes_per_token": self.cache_bytes_per_token}
+        for phase, counts in self.moe_counts.items():
+            out[phase] = {
+                **{name: int(counts[:, i].sum()) for i, name in enumerate(STATS)},
+                "expert_tokens": counts[:, len(STATS):].tolist(),
+            }
+        return out
 
     def _record_hit(self) -> None:
         self.overlap_hits += 1
@@ -1078,6 +1162,7 @@ class ServingEngine(
         with self.profiler.phase(
             "host_gap" if self._inflight is not None else "sample"
         ):
+            self._moe_fold("decode", rec.get("moe_stats"))
             now = time.monotonic()
             emitted_total = 0
             for s, req in zip(rec["active"], rec["reqs"]):
@@ -1293,6 +1378,7 @@ class ServingEngine(
         with self.profiler.phase(
             "host_gap" if self._inflight is not None else "sample"
         ):
+            self._moe_fold("decode", rec.get("moe_stats"))
             now = time.monotonic()
             consumed = 0
             for s, req in zip(rec["active"], rec["reqs"]):

@@ -15,6 +15,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..utils import failpoints
 from ..utils.spans import new_trace_id
@@ -26,7 +27,7 @@ from .engine_overload import (
     parse_priority,
 )
 from .engine_profiler import in_phase
-from .engine_sampling import _token_logprob, filter_top_k_top_p
+from .engine_sampling import _token_logprob, filter_top_k_top_p, moe_stats
 from .engine_types import Request
 from .transformer import decode_cache_spec
 
@@ -397,7 +398,9 @@ class AdmissionMixin:
         self._wd_grace(f"compile:prefill_{chunk}x{batch}x{bucket}")
         model = self._dense_chunk_model(bucket)
 
-        def run(params, cache, tokens, pos0, last_idx, aids):
+        moe = model.config.moe is not None
+
+        def run(params, cache, tokens, pos0, last_idx, aids, *real_rows):
             pos = jnp.broadcast_to(
                 pos0 + jnp.arange(chunk)[None, :], (batch, chunk)
             )
@@ -414,17 +417,30 @@ class AdmissionMixin:
             # logits_at: a config that keeps one logit a prompt sends
             # only the selected position through the head.
             keep_one = model.config.logits_to_keep == 1
+            more = {}
+            if moe:
+                # A model with expert layers (models/moe.py) is told its
+                # real tokens: a prompt's positions, in the job's real
+                # rows (``real_rows``: the batch is padded to a power of
+                # two).  The rest routes nowhere and counts nothing.
+                more["token_mask"] = (pos <= last_idx[:, None]) & (
+                    jnp.arange(batch)[:, None] < real_rows[0]
+                )
             logits, mut = model.apply(
                 {"params": params, "cache": cache}, tokens, pos,
                 adapter_ids=aids,
                 last_positions=last_idx,
                 logits_at=last_row() if keep_one else None,
-                mutable=["cache"],
+                mutable=["cache", "moe_stats"] if moe else ["cache"],
+                **more,
             )
+            # The chunk's routing counts leave as a third output, read
+            # when the job's logits are (_activate): no sync of its own.
+            stats = (moe_stats(mut),) if moe else ()
             if keep_one:
-                return logits[:, 0], mut["cache"]
+                return (logits[:, 0], mut["cache"], *stats)
             sel = last_row()
-            return logits[jnp.arange(batch), sel], mut["cache"]
+            return (logits[jnp.arange(batch), sel], mut["cache"], *stats)
 
         fn = jax.jit(run, donate_argnums=(1,))
         self._prefill_cache[key] = fn
@@ -590,6 +606,11 @@ class AdmissionMixin:
                 "cache": cache,
                 "pos": skip,
                 "logits": [None] * n,
+                # Expert layers only: the real rows of the padded batch,
+                # an operand of the chunk program, and the chunks' routing
+                # counts, still on the device.
+                "real_rows": [jnp.asarray(n, jnp.int32)] if self._moe_shape else [],
+                "moe_stats": [],
             }
         )
 
@@ -604,14 +625,16 @@ class AdmissionMixin:
             tokens = jax.lax.slice_in_dim(
                 job["rows"], pos, pos + chunk, axis=1
             )
-            logits_rows, job["cache"] = fn(
+            logits_rows, job["cache"], *stats = fn(
                 self.params,
                 job["cache"],
                 tokens,
                 jnp.asarray(pos, jnp.int32),
                 job["last_idx"],
                 job["aids"],
+                *job["real_rows"],
             )
+            job["moe_stats"] += stats
         for i in range(len(job["items"])):
             if pos <= job["last_idx_host"][i] < pos + chunk:
                 job["logits"][i] = logits_rows[i]
@@ -1038,6 +1061,10 @@ class AdmissionMixin:
             self._maybe_finish(slot)
             if req.done:
                 finished.append(req)
+        # The job's routing counts: its chunks are done (the first tokens
+        # above were sampled from their logits), so this waits for nothing.
+        for stats in job["moe_stats"]:
+            self._moe_fold("prefill", np.asarray(stats).astype(np.int64))
         # Activated slots carry fresh scalars (last token, length, sampler
         # settings, adapter): rebuild the device step state (engine.py).
         self._mark_state_dirty()

@@ -158,6 +158,12 @@ class HandoffMixin:
                 "recurrent state (cfg.mixer): a prefill handoff carries "
                 "K/V pages, not the state the skipped chunks would build"
             )
+        if role != "unified" and self.cfg.mla is not None:
+            raise ValueError(
+                f"role={role!r} is not supported with latent attention or expert "
+                "layers (cfg.mla, cfg.moe): a prefill handoff is not tried on a "
+                "latent pool, and the chunks it skips would lose their routing counts"
+            )
         if role != "unified":
             # Both split roles live on the content-addressed KV tiers:
             # the prefill role PUBLISHES into the arena and serves from

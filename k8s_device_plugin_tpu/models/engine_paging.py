@@ -46,6 +46,17 @@ def slot_leaves(layer: dict):
     ]
 
 
+def cache_bytes_per_token(cache: dict) -> int:
+    """Device bytes one cached position takes over all layers: a row of
+    every ``pool_*`` leaf (K and V with their scales, or one latent)."""
+    return sum(
+        leaf.size // (leaf.shape[0] * leaf.shape[1]) * leaf.dtype.itemsize
+        for layer in cache.values()
+        for name, leaf in layer["attn"].items()
+        if name.startswith("pool_")
+    )
+
+
 def slot_state_bytes(cache: dict) -> int:
     """Device bytes of every per-slot leaf of the cache tree (arrays, or
     the shapes ``decode_cache_spec`` gives)."""
@@ -319,7 +330,10 @@ class PagingMixin:
         which later appends overwrite before any masked read can see
         them.  No other page of a pool is touched."""
         row, n_publish = self._slot_row(pages, plen)
-        slab = dense_cache[self._layer_names[0]]["attn"]["cached_key"]
+        att = dense_cache[self._layer_names[0]]["attn"]
+        # Any dense slab: every ``cached_*`` leaf is [batch, bucket, ...]
+        # (K and V, int8 scales beside them, or one latent row).
+        slab = next(att[leaf] for leaf in sorted(att) if leaf.startswith("cached_"))
         self._cache_write(
             ("graft", *slab.shape[:2]),
             lambda: build_graft_writer(self._derive_tables),

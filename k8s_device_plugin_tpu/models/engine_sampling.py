@@ -96,6 +96,36 @@ def _derived_tables(cache, chain, pos, page_size):
     }
 
 
+def _moe_apply(model, chain):
+    """What ``model.apply`` needs beyond the cache for a model with expert
+    layers (models/moe.py): the ``moe_stats`` collection mutable, and the
+    real-token mask.  A slot is live from its graft to its teardown, which
+    is while its chain row names a first page (page 0 is the scratch page
+    and is never handed out): an idle slot's row routes nowhere, touches no
+    expert and counts nothing.  Nothing for a model without them: its
+    program stays what it was."""
+    if model.config.moe is None:
+        return ["cache"], {}
+    if chain is None:
+        raise ValueError("a model with expert layers (cfg.moe) decodes with derived tables (no spec_gamma)")
+    return ["cache", "moe_stats"], {"token_mask": chain[:, :1] > 0}
+
+
+def moe_stats(mut) -> jax.Array:
+    """The routing counts one ``model.apply`` sowed, [expert layers,
+    MoeConfig.stats_width] int32, in layer order."""
+    col = mut["moe_stats"]
+    return jnp.stack([col[name]["moe"]["counts"][0] for name in sorted(col, key=lambda n: int(n.rsplit("_", 1)[1]))])
+
+
+def pack_stats(out, stats):
+    """The routing counts INSIDE the packed readback: flattened behind the
+    tokens (and logprobs), in ``out``'s dtype (a block's count is far
+    below 2**24, which float32 holds exactly), so the host still syncs ONE
+    array a dispatch (ServingEngine._unpack splits it)."""
+    return jnp.concatenate([out.reshape(-1), stats.reshape(-1).astype(out.dtype)])
+
+
 def build_step_fn(model, filtered: bool, want_lp: bool, biased: bool = False,
                   derive_tables: bool = False):
     """Build the jitted single-token decode step.  ``filtered`` compiles
@@ -134,12 +164,14 @@ def build_step_fn(model, filtered: bool, want_lp: bool, biased: bool = False,
         if derive_tables:
             with jax.named_scope("derive_tables"):
                 cache = _derived_tables(cache, chain, positions, page_size)
+        mutable, more = _moe_apply(model, chain)
         logits, mut = model.apply(
             {"params": params, "cache": cache},
             tokens,
             positions,
             adapter_ids=aids,
-            mutable=["cache"],
+            mutable=mutable,
+            **more,
         )
         # Sampling lies outside every Flax module: a scope of its own
         # names its operations in a device trace.
@@ -167,6 +199,8 @@ def build_step_fn(model, filtered: bool, want_lp: bool, biased: bool = False,
                 if want_lp
                 else nxt
             )
+            if "moe_stats" in mut:
+                out = pack_stats(out, moe_stats(mut))
         return out, nxt[:, None], positions + 1, key, mut["cache"]
 
     extra = (["chain"] if derive_tables else []) + variant_names(
@@ -207,8 +241,11 @@ def build_block_fn(model, T: int, filtered: bool, want_lp: bool,
               topks=None, topps=None, bias_ids=None, bias_vals=None):
         key, sub = jax.random.split(key)
 
+        mutable, more = _moe_apply(model, chain)
+        moe = model.config.moe
+
         def body(carry, k):
-            cache, toks, pos = carry
+            cache, toks, pos, *stats = carry
             if derive_tables:
                 with jax.named_scope("derive_tables"):
                     cache = _derived_tables(cache, chain, pos, page_size)
@@ -217,8 +254,12 @@ def build_block_fn(model, T: int, filtered: bool, want_lp: bool,
                 toks,
                 pos,
                 adapter_ids=aids,
-                mutable=["cache"],
+                mutable=mutable,
+                **more,
             )
+            if moe is not None:
+                # The counts ride the carry and leave with the tokens.
+                stats = [stats[0] + moe_stats(mut)]
             with jax.named_scope("sample"):
                 row = logits[:, -1, :]
                 pick = row
@@ -234,16 +275,21 @@ def build_block_fn(model, T: int, filtered: bool, want_lp: bool,
                 sampled = jax.random.categorical(k, scaled).astype(jnp.int32)
                 nxt = jnp.where(temps > 0, sampled, greedy)
                 ys = (nxt, _token_logprob(row, nxt)) if want_lp else nxt
-            return (mut["cache"], nxt[:, None], pos + 1), ys
+            return (mut["cache"], nxt[:, None], pos + 1, *stats), ys
 
-        (cache, last_tok, last_pos), ys = jax.lax.scan(
-            body, (cache, tokens, positions), jax.random.split(sub, T)
+        stats0 = []
+        if moe is not None:
+            stats0 = [jnp.zeros((model.config.num_layers // 2, moe.stats_width), jnp.int32)]
+        (cache, last_tok, last_pos, *stats), ys = jax.lax.scan(
+            body, (cache, tokens, positions, *stats0), jax.random.split(sub, T)
         )
         if want_lp:
             toks, lps = ys
             out = jnp.stack([toks.T.astype(jnp.float32), lps.T])
         else:
             out = ys.T  # [slots, T]
+        if stats:
+            out = pack_stats(out, stats[0])
         return out, last_tok, last_pos, key, cache
 
     # Same variant-signature split as build_step_fn: the common path

@@ -225,6 +225,61 @@ class EngineMetrics:
             "slot; 0 for a model without one).  Set once at engine "
             "construction",
         )
+        self.cache_bytes_per_token = registry.gauge(
+            "tpu_engine_cache_bytes_per_token",
+            "Device bytes one cached position takes over all layers: a "
+            "row of every page pool (K and V, int8 scales beside them, "
+            "or one latent row an attention).  Set once at engine "
+            "construction",
+        )
+        # Routing counts of a model with expert layers (models/moe.py):
+        # summed on the device over a decode block, read inside the
+        # block's one readback, handed back by each prefill chunk.
+        self.moe_assignments = registry.counter(
+            "tpu_engine_moe_assignments_total",
+            "Expert assignments of real tokens (top-k a token and expert "
+            "layer) by kind: held = a routed expert this replica holds "
+            "and computes, identity = a zero-computation expert, absent "
+            "= a routed expert another chip of the deployment holds "
+            "(its part of the result is left out here)",
+            ["kind"],
+        )
+        self.moe_identity = registry.counter(
+            "tpu_engine_moe_identity_assignments_total",
+            "The identity series of tpu_engine_moe_assignments_total "
+            "under a name of its own, for a scraper that sums a name's "
+            "label sets (chipbench/run.py parse_exposition)",
+        )
+        self.moe_expert_peak = registry.gauge(
+            "tpu_engine_moe_expert_tokens_peak",
+            "The largest series of tpu_engine_moe_expert_tokens_total: "
+            "the busiest held expert's tokens over the replica's life; "
+            "over that counter's mean series it reads the load's peak "
+            "over its mean",
+        )
+        self.moe_dropped = registry.counter(
+            "tpu_engine_moe_dropped_assignments_total",
+            "Assignments to held experts that no branch of the expert "
+            "layer computed: 0 while the layer is dropless",
+        )
+        self.moe_expert_tokens = registry.counter(
+            "tpu_engine_moe_expert_tokens_total",
+            "Tokens each held expert computed, by expert layer and the "
+            "expert's published index",
+            ["layer", "expert"],
+        )
+        self.moe_decode_touched = registry.counter(
+            "tpu_engine_moe_decode_experts_touched_total",
+            "Held experts with at least one token, summed over decode "
+            "steps and expert layers; over "
+            "tpu_engine_moe_decode_layer_steps_total it reads the held "
+            "experts whose weights a decode step reads a layer",
+        )
+        self.moe_decode_layer_steps = registry.counter(
+            "tpu_engine_moe_decode_layer_steps_total",
+            "(decode step, expert layer) pairs in which a real token "
+            "was routed",
+        )
         self.decode_dispatches_block = registry.counter(
             "tpu_engine_decode_dispatches_block_total",
             "Decode dispatches that ran a multi-step block program",

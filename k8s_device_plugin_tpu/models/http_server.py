@@ -1616,12 +1616,16 @@ class EngineServer:
                     # Per-step phase breakdown over the rolling window —
                     # aggregates only, no request-identifying content, so
                     # it stays as open as /metrics.
+                    # Routing counts by phase; absent for a model
+                    # without expert layers.
+                    moe = server.engine.moe_state()
                     self._reply(
                         200,
                         {
                             **server.engine.profiler.snapshot(),
                             "cache_writes": server.engine.cache_writes_state(),
                             "prefill_cache": server.engine.prefill_cache_state(),
+                            **({} if moe is None else {"moe": moe}),
                         },
                     )
                 elif path == "/debug/disagg":

@@ -31,6 +31,8 @@ import jax.numpy as jnp
 
 from ..ops.flash_attention import flash_attention, mha_reference
 from ..ops.quant import Int8DenseGeneral, dequantize_kv, quantize_kv_pair
+from .mla import LatentAttention, MlaConfig
+from .moe import ExpertLayer, MoeConfig
 from .ssm import Mamba2Mixer, MambaConfig
 
 # Large-negative logit for top-k filtering: finite (softmax/categorical
@@ -198,12 +200,37 @@ class GPTConfig:
     # residual.  Its recurrent state is per sequence, not per token: the
     # serving engine keeps it in per-slot leaves beside the paged pools.
     mixer: Optional[MambaConfig] = None
+    # Latent attention in place of grouped-query attention (models/mla.py):
+    # a token's cache row is one latent and one rotary key for all heads
+    # (``pool_latent`` / ``cached_latent`` where K and V pools were);
+    # ``num_kv_heads`` and ``head_dim`` are then unused.  Comes with
+    # ``moe``: one block has both.
+    mla: Optional[MlaConfig] = None
+    # Shortcut-connected expert layers (models/moe.py, ``ShortcutBlock``):
+    # the decoder is pairs of blocks, ``num_layers`` counts the blocks (one
+    # attention and one cache-tree layer each, so an even number), and the
+    # expert layer of a pair reads the first block's post-attention norm and
+    # joins the residual at the END of the second: it runs beside a whole
+    # attention and feed-forward.
+    moe: Optional[MoeConfig] = None
 
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.hidden_size // self.num_heads)
         if self.logits_to_keep not in (None, 1):
             raise ValueError(f"logits_to_keep must be None or 1, got {self.logits_to_keep}")
+        if (self.mla is None) != (self.moe is None):
+            raise ValueError(
+                "cfg.mla and cfg.moe come together: ShortcutBlock is the one block "
+                "with latent attention and the one with an expert layer"
+            )
+        if self.moe is not None and self.num_layers % 2:
+            raise ValueError(
+                f"num_layers {self.num_layers} must be even with cfg.moe: it counts "
+                "the blocks of shortcut-connected pairs"
+            )
+        if self.moe is not None and self.mixer is not None:
+            raise ValueError("cfg.moe (ShortcutBlock) has no mixer beside its attention")
 
     @property
     def kv_heads(self) -> int:
@@ -698,6 +725,38 @@ class DecoderBlock(nn.Module):
         return hidden + mlp
 
 
+class ShortcutBlock(nn.Module):
+    """One block of a shortcut-connected pair (``cfg.moe``).  With ``x`` the
+    pair's input, ``A`` attention, ``F`` the dense SwiGLU, ``M`` the expert
+    layer::
+
+        a = x + A_0(norm x);   u = norm a;   m = M(u);   y = a + F_0(u)        first block
+        z = y + A_1(norm y);   out = z + F_1(norm z) + m                       second block
+
+    The first block (``first``) returns ``(y, m)``, the second takes ``m``
+    as ``pending`` and returns ``(out, None)``.  Parameter and cache names
+    are ``DecoderBlock``'s (``attn_norm``, ``attn``, ``mlp_norm``, ``mlp``)
+    with ``moe`` beside them in a first block."""
+
+    config: GPTConfig
+    first: bool
+    decode: bool = False
+    append_mode: str = "auto"
+
+    @nn.compact
+    def __call__(self, hidden, positions, pending=None, token_mask=None):
+        cfg = self.config
+        normed = RMSNorm(dtype=cfg.dtype, eps=cfg.rms_norm_eps, name="attn_norm")(hidden)
+        hidden = hidden + LatentAttention(
+            cfg, decode=self.decode, append_mode=self.append_mode, name="attn"
+        )(normed, positions)
+        u = RMSNorm(dtype=cfg.dtype, eps=cfg.rms_norm_eps, name="mlp_norm")(hidden)
+        if self.first:
+            pending = ExpertLayer(cfg, name="moe")(u, token_mask)
+            return hidden + SwiGluMlp(cfg, name="mlp")(u), pending
+        return hidden + SwiGluMlp(cfg, name="mlp")(u) + pending, None
+
+
 class TransformerLM(nn.Module):
     """Decoder-only LM: embed -> N pre-norm blocks -> RMSNorm -> vocab logits.
 
@@ -715,14 +774,17 @@ class TransformerLM(nn.Module):
     @nn.compact
     def __call__(
         self, input_ids, positions=None, output: str = "logits", adapter_ids=None,
-        last_positions=None, logits_at=None,
+        last_positions=None, logits_at=None, token_mask=None,
     ):
         """``last_positions`` [batch]: each row's last REAL position, for a
         model whose mixer carries state through the sequence (positions
         past it are padding the state must not see); attention is causal
         and needs none.  ``logits_at`` [batch]: an index into the sequence
         axis a row; only that position goes through the head, and the
-        logits come back as [batch, 1, vocab]."""
+        logits come back as [batch, 1, vocab].  ``token_mask`` [batch,
+        seq] bool: the real tokens, for a model with expert layers (an idle
+        slot's or a padded position's row routes nowhere and counts
+        nothing; None = every token is real)."""
         cfg = self.config
         seq_len = input_ids.shape[-1]
         if positions is None:
@@ -740,7 +802,16 @@ class TransformerLM(nn.Module):
         block_cls = (
             nn.remat(DecoderBlock, static_argnums=()) if cfg.remat else DecoderBlock
         )
+        pending = None  # a pair's expert output, on its way to the pair's end
         for i in range(cfg.num_layers):
+            if cfg.moe is not None:
+                if self.mlp_factory is not None or self.attention_fn is not None or cfg.remat:
+                    raise ValueError("cfg.moe (ShortcutBlock) takes no mlp_factory, attention_fn or remat")
+                hidden, pending = ShortcutBlock(
+                    cfg, first=i % 2 == 0, decode=self.decode,
+                    append_mode=self.append_mode, name=f"layer_{i}",
+                )(hidden, positions, pending, token_mask)
+                continue
             hidden = block_cls(
                 cfg,
                 decode=self.decode,
