@@ -74,13 +74,14 @@ from .engine_types import (  # noqa: F401  (re-export: public surface)
     Request,
     _pow2_int,
 )
+from ..ops.expert_ffn import on_kernel_lane
 from ..utils import failpoints
 from ..utils.anomaly import AnomalyMonitor
 from ..utils.flight import FlightRecorder
 from ..utils.platform import device_facts
 from ..utils.spans import ENGINE_TRACE, SpanRecorder
 from .engine_profiler import EngineProfiler
-from .moe import STATS
+from .moe import STATS, few_tokens
 from .transformer import (
     GPTConfig,
     PagedConfig,
@@ -483,6 +484,10 @@ class ServingEngine(
         self._moe_shape = None
         if cfg.moe is not None:
             self._moe_shape = (cfg.num_layers // 2, cfg.moe.stats_width)
+            # Whether a decode step's experts run the grouped-FFN kernel
+            # is a fact of the compiled program: the backend's lane and
+            # the step's row count (one row a slot).
+            self.moe_expert_kernel = on_kernel_lane() and few_tokens(self.max_slots)
             self.moe_counts = {
                 phase: np.zeros(self._moe_shape, np.int64) for phase in ("decode", "prefill")
             }
@@ -1082,6 +1087,8 @@ class ServingEngine(
         if phase == "decode":
             m.moe_decode_touched.inc(col["touched"])
             m.moe_decode_layer_steps.inc(col["active"])
+            if self.moe_expert_kernel:
+                m.moe_kernel_layer_steps.inc(col["active"])
 
     def moe_state(self) -> Optional[dict]:
         """The ``moe`` block of ``GET /debug/profile``: the same sums as
@@ -1089,7 +1096,10 @@ class ServingEngine(
         expert layers."""
         if self._moe_shape is None:
             return None
-        out = {"held_experts": list(self.cfg.moe.held), "cache_bytes_per_token": self.cache_bytes_per_token}
+        out = {
+            "held_experts": list(self.cfg.moe.held), "cache_bytes_per_token": self.cache_bytes_per_token,
+            "expert_kernel": self.moe_expert_kernel,
+        }
         for phase, counts in self.moe_counts.items():
             out[phase] = {
                 **{name: int(counts[:, i].sum()) for i, name in enumerate(STATS)},

@@ -280,6 +280,13 @@ class EngineMetrics:
             "(decode step, expert layer) pairs in which a real token "
             "was routed",
         )
+        self.moe_kernel_layer_steps = registry.counter(
+            "tpu_engine_moe_kernel_layer_steps_total",
+            "Those of tpu_engine_moe_decode_layer_steps_total whose "
+            "experts ran as one grouped-FFN kernel (ops/expert_ffn.py: "
+            "a touched expert's weights read once, in place): all of "
+            "them on a TPU backend, none elsewhere",
+        )
         self.decode_dispatches_block = registry.counter(
             "tpu_engine_decode_dispatches_block_total",
             "Decode dispatches that ran a multi-step block program",

@@ -20,32 +20,43 @@ without the exchange that would bring other chips' tokens here and take
 these tokens there; nothing stands in for it.
 
 **Dropless, static shapes** (``moe.experts``).  No capacity, no dropped
-token: each held expert in turn (one ``fori_loop`` body: unrolled, a 64 x
-256 chunk's program took three times as long to compile, 29 such programs a
-warm-up) runs ONE branch on its token count ``n`` (``lax.switch``, a real
-branch on the chip):
+token; what runs depends on how many tokens came, which the code reads off
+its input's shape:
 
-- ``n == 0``: nothing; the expert's weights are not read.  A decode step of
-  64 tokens touches 6 to 10 of 16 held experts, and a decode step is bound
-  by the bytes it reads.
-- ``n <= C``: its tokens are gathered into ``C`` rows, run, and scattered
-  back weighted.  ``C`` (``gather_rows``) is all the tokens where they are
-  few (then there is no gathering: the expert runs over all of them) and an
-  eighth of them where they are many: 2 x 3 x h x f FLOPs a row, so a 64 x
-  256 prefill chunk costs 16 x 2,048 rows where every expert over every
-  token would cost 16 x 16,384 (as much again as the rest of the chunk).
-- ``n > C``: the expert runs over EVERY token under its weights (0 where it
-  was not chosen).  Any routing gives the reference's numbers, all of a
-  chunk's tokens on one expert included; the cost is only paid then.
+- FEW tokens (``few_tokens``: up to 256: every decode block and single
+  step, a one-prompt chunk of 256): an expert runs over all of them or not
+  at all, and the layer is ONE call of ``ops/expert_ffn.py``.  On a TPU
+  that is a Pallas kernel whose grid walks the touched experts: a block of
+  expert ``e`` is DMA'd from the stack straight into VMEM by an index map
+  that reads ``e`` from a prefetched scalar, an untouched expert's weights
+  are not read.  A decode step of 64 tokens touches 6 to 10 of 16 held
+  experts, and a decode step is bound by the bytes it reads.  Elsewhere it
+  is the loop below with two branches (none / every token).
+- MANY tokens (chunks of two prompts and more): each held expert in turn
+  (one ``fori_loop`` body: unrolled, a 64 x 256 chunk's program took three
+  times as long to compile, 29 such programs a warm-up) runs ONE branch on
+  its token count ``n`` (``lax.switch``, a real branch on the chip).
+  ``n == 0``: nothing; the expert's weights are not read.  ``n <= C``
+  (``gather_rows``, an eighth of the tokens, never fewer than 256): its
+  tokens are gathered into ``C`` rows, run, and scattered back weighted: 2
+  x 3 x h x f FLOPs a row, so a 64 x 256 prefill chunk costs 16 x 2,048
+  rows where every expert over every token would cost 16 x 16,384 (as much
+  again as the rest of the chunk).  ``n > C``: the expert runs over EVERY
+  token under its weights (0 where it was not chosen).  Any routing gives
+  the reference's numbers, all of a chunk's tokens on one expert included;
+  the cost is only paid then.  There the layer is bound by FLOPs and the
+  compiler's matmuls stay.
 
-What it costs, read on the chip: the slice of a touched expert is COPIED out
-of the stack before the matmuls read it (a static slice in an unrolled loop
-just the same), three times the touched experts' bytes; and every held
-expert over every token as three matmuls over the whole stack (no loop, no
-branch, all sixteen read once) was slower still and held 1.8 GB more
-(1,654-1,750 tokens/s against 1,853-1,907, PERF.md Findings PR 32): the
-stack is laid out anew for it.  A grouped matmul over the sorted
-assignments is the lever (ROADMAP Reach A2).
+What it costs, read on the chip (PERF.md Findings, PR 33).  The loop does
+NOT copy a touched expert's slice: the ``dynamic-slice`` is fused into the
+matmul that reads it, three fusions an expert at 37-40 us each (645 GB/s,
+79 % of the chip's 819), 0.978 ms a layer alone at 64 rows and 8 of 16
+touched.  The kernel takes 0.842 ms there (717 GB/s) and 0.90 ms where the
+loop takes 1.30 at 256 rows.  Every held expert over every token as three
+matmuls over the whole stack (no loop, no branch, all sixteen read once)
+was slower than the loop and held 1.8 GB more (1,654-1,750 tokens/s
+against 1,853-1,907, PR 32).  The many-token branches are untouched: a
+gather, three matmuls and a scatter-add an expert (ROADMAP Reach A2).
 
 **Counts** (``moe.route``).  Each call sows one int32 vector into the
 ``moe_stats`` collection (``STATS``: assignments to held, identity and
@@ -65,6 +76,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..ops.expert_ffn import expert_ffn, expert_slice_ffn
 
 # Leading entries of the sown vector; a count per held expert follows.
 STATS = ("held", "identity", "absent", "dropped", "touched", "active")
@@ -101,11 +114,52 @@ class MoeConfig:
         return len(STATS) + len(self.held)
 
 
+def few_tokens(n_tokens: int) -> bool:
+    """Whether ``n_tokens`` are few: the gathered branch would hold them
+    all, so an expert runs over every token or not at all, one call of
+    ops/expert_ffn.py (every decode step of up to 256 slots, a one-prompt
+    chunk of 256)."""
+    return gather_rows(n_tokens) >= n_tokens
+
+
 def gather_rows(n_tokens: int) -> int:
     """Rows of the gathered branch: every token up to 256, then an eighth
     of them, never fewer than 256 (under uniform routing a held expert's
     mean share is top_k / (E + Z) of the tokens: a 64th at 12 of 768)."""
     return n_tokens if n_tokens <= 256 else max(256, n_tokens // 8)
+
+
+def _many_token_experts(u, weight_of, counts, w_gate, w_up, w_down):
+    """``moe.experts`` over many tokens [n, h] (chunks of two prompts and
+    more): each held expert in turn runs one of three branches on its
+    count.  Returns (out [n, h] float32, assignments computed)."""
+    n, cap = u.shape[0], gather_rows(u.shape[0])
+
+    def ffn(rows, e):
+        return expert_slice_ffn(rows, e, w_gate, w_up, w_down)
+
+    def skip(e, out):
+        return out, jnp.zeros((), jnp.int32)
+
+    def full(e, out):
+        return out + ffn(u, e) * weight_of[e][:, None], counts[e]
+
+    def gathered(e, out):
+        (idx,) = jnp.nonzero(weight_of[e] != 0, size=cap, fill_value=n)
+        rows = jnp.take(u, idx, axis=0, mode="fill", fill_value=0)
+        wt = jnp.take(weight_of[e], idx, mode="fill", fill_value=0.0)
+        out = out.at[idx].add(ffn(rows, e) * wt[:, None], mode="drop")
+        return out, jnp.sum(idx < n, dtype=jnp.int32)
+
+    def one_expert(e, carry):
+        out, computed = carry
+        branch = (counts[e] > 0).astype(jnp.int32) + (counts[e] > cap)
+        out, done = jax.lax.switch(branch, (skip, gathered, full), e, out)
+        return out, computed + done
+
+    return jax.lax.fori_loop(
+        0, counts.shape[0], one_expert, (jnp.zeros(u.shape, jnp.float32), jnp.zeros((), jnp.int32))
+    )
 
 
 class ExpertLayer(nn.Module):
@@ -151,46 +205,13 @@ class ExpertLayer(nn.Module):
             is_zero = (ids >= mc.n_routed) & valid[:, None]
             zero_w = jnp.sum(jnp.where(is_zero, w, 0.0), axis=1)  # [n]
 
-        def ffn(rows, e):
-            """Held expert ``e`` (traced) over rows [r, h]; float32 out.  The
-            expert's slice of the stacked weights is taken HERE, inside the
-            branch that runs it: a branch not taken reads nothing."""
-            gate_w, up_w, down_w = (jax.lax.dynamic_index_in_dim(w, e, 0, keepdims=False) for w in (w_gate, w_up, w_down))
-            gate = jnp.dot(rows, gate_w, preferred_element_type=f32)
-            up = jnp.dot(rows, up_w, preferred_element_type=f32)
-            return jnp.dot((nn.silu(gate) * up).astype(cfg.dtype), down_w, preferred_element_type=f32)
-
-        cap = gather_rows(n)
         weight_of = held_w.T  # [held, n]
-
-        def skip(e, out):
-            return out, jnp.zeros((), jnp.int32)
-
-        def full(e, out):
-            return out + ffn(u, e) * weight_of[e][:, None], counts[e]
-
-        def gathered(e, out):
-            (idx,) = jnp.nonzero(weight_of[e] != 0, size=cap, fill_value=n)
-            rows = jnp.take(u, idx, axis=0, mode="fill", fill_value=0)
-            wt = jnp.take(weight_of[e], idx, mode="fill", fill_value=0.0)
-            out = out.at[idx].add(ffn(rows, e) * wt[:, None], mode="drop")
-            return out, jnp.sum(idx < n, dtype=jnp.int32)
-
-        # Few tokens: an expert runs over all of them, or not at all.
-        branches = (skip, full) if cap >= n else (skip, gathered, full)
-
-        def one_expert(e, carry):
-            out, computed = carry
-            branch = (counts[e] > 0).astype(jnp.int32)
-            if cap < n:
-                branch = branch + (counts[e] > cap)
-            out, done = jax.lax.switch(branch, branches, e, out)
-            return out, computed + done
-
         with jax.named_scope("moe.experts"):
-            out, computed = jax.lax.fori_loop(
-                0, n_held, one_expert, (jnp.zeros((n, h), f32), jnp.zeros((), jnp.int32))
-            )
+            if few_tokens(n):
+                # An expert runs over all the tokens, or not at all.
+                out, computed = expert_ffn(u, weight_of, counts, w_gate, w_up, w_down)
+            else:
+                out, computed = _many_token_experts(u, weight_of, counts, w_gate, w_up, w_down)
         with jax.named_scope("moe.identity"):
             out = out + zero_w[:, None] * u.astype(f32)
 

@@ -106,22 +106,72 @@ def device_generation(environ: Optional[Mapping[str, str]] = None) -> str:
     return "cpu"
 
 
+def _row_for(generation: Optional[str], rows, cpu_row, fallback_row):
+    """``(row, exact)`` of a table keyed by device_kind prefix: the CPU
+    row off the chip, the fallback row for a TPU generation without one."""
+    kind = device_generation() if generation is None else generation
+    if kind == "cpu":
+        return cpu_row, True
+    for row in rows:
+        if kind.startswith(row.generation):
+            return row, True
+    return fallback_row, False
+
+
 def decode_row(generation: Optional[str] = None) -> tuple[DecodeRow, bool]:
     """The tuning row for ``generation`` (default: discovered) and
     whether it was an exact match (False = the conservative fallback —
     the engine's untuned-generation fallback signal)."""
-    kind = device_generation() if generation is None else generation
-    if kind == "cpu":
-        return CPU_ROW, True
-    for row in DECODE_ROWS:
-        if kind.startswith(row.generation):
-            return row, True
-    return FALLBACK_ROW, False
+    return _row_for(generation, DECODE_ROWS, CPU_ROW, FALLBACK_ROW)
 
 
 def has_row(generation: Optional[str] = None) -> bool:
     """Whether a reviewed tuning row exists for this generation."""
     return decode_row(generation)[1]
+
+
+@dataclass(frozen=True)
+class ExpertFfnRow:
+    """One generation's row for the grouped expert FFN (ops/expert_ffn.py):
+    the tile of the f axis that one grid step streams (a gate, an up and a
+    down block of ``h x tile_f`` elements each, double-buffered by the
+    pipeline) and the scoped VMEM the kernel may take."""
+
+    generation: str
+    tile_f: int
+    vmem_limit_bytes: Optional[int]  # None: the compiler's default
+    source: str
+
+
+# Reckoned at LongCat-Flash's widths (h 6144, f 2048, bf16): a block is
+# h x tile_f x 2 bytes, three blocks a step, each double-buffered, beside
+# the resident rows and float32 output (64 rows: 0.8 + 1.6 MB, 256 rows:
+# 3.1 + 6.3 MB, each double-buffered too).  tile_f 256 is 18.9 MB of
+# weight buffers, already over the 16 MiB default scoped limit, so the
+# limit is raised; a v5e core has 128 MiB of VMEM.  Timed alone on a v5e
+# (chip run, PR 33: one layer, 64 rows, 8 of 16 experts touched, 604 MB):
+# tile 128 0.845 ms, 256 0.893, 512 0.842, 1,024 0.848; the XLA lane 0.978.
+EXPERT_FFN_ROWS: tuple[ExpertFfnRow, ...] = (
+    ExpertFfnRow(
+        "TPU v5 lite", 512, 96 << 20,
+        "0.842 ms at 64 rows, 8 of 16 touched (717 GB/s); the XLA lane 0.978 (chip run, PR 33)",
+    ),
+    ExpertFfnRow("TPU v5e", 512, 96 << 20, "alias of the v5 lite row"),
+)
+
+# The Pallas interpreter has no VMEM: two tiles of a test's 256-wide f, so
+# the sum over tiles and the held-block index map both run.
+EXPERT_FFN_CPU_ROW = ExpertFfnRow("cpu", 128, None, "interpret-mode default")
+
+# A TPU generation without a row: the tile that fits the default scoped
+# limit of every generation so far, and no raised limit.
+EXPERT_FFN_FALLBACK_ROW = ExpertFfnRow("unknown-tpu", 128, None, "no row for this generation")
+
+
+def expert_ffn_row(generation: Optional[str] = None) -> tuple[ExpertFfnRow, bool]:
+    """The grouped expert FFN's row for ``generation`` (default:
+    discovered) and whether it was an exact match."""
+    return _row_for(generation, EXPERT_FFN_ROWS, EXPERT_FFN_CPU_ROW, EXPERT_FFN_FALLBACK_ROW)
 
 
 def pick_num_splits(

@@ -10,6 +10,7 @@ double-layers, 8 routed + 4 zero-computation experts, top-3, this replica
 holds experts 0, 2 and 5."""
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -91,9 +92,20 @@ def streams(served):
 # ------------------------------------------------- (a) the served path ----
 
 
-def test_the_model_is_the_reference(served):
+@pytest.fixture(params=["xla lane", "kernel through the interpreter"])
+def expert_lane(request, monkeypatch):
+    """Few tokens' experts on each lane of ops/expert_ffn.py: the XLA lane
+    (what a CPU backend takes by itself) and the kernel forced through the
+    Pallas interpreter."""
+    if request.param != "xla lane":
+        monkeypatch.setattr(moe, "expert_ffn", functools.partial(moe.expert_ffn, interpret=True))
+    return request.param
+
+
+def test_the_model_is_the_reference(served, expert_lane):
     """No cache, no engine: ``TransformerLM`` over one sequence (expanded
-    attention, every expert branch) against the reference's logits."""
+    attention, few tokens: every touched expert over all of them, on each
+    lane) against the reference's logits."""
     cfg, _, params = served
     ids = np.asarray([prompt_of(29)], np.int32)
     got = np.asarray(jax.jit(TransformerLM(cfg).apply)({"params": params}, jnp.asarray(ids)))[0]
@@ -216,11 +228,11 @@ def test_an_experts_leaves_do_not_depend_on_its_neighbours():
             assert jnp.array_equal(stacked[f"moe/experts_{name}"][held.index(5)], alone[name]), (held, name)
 
 
-def test_the_shares_of_all_ranks_add_up_to_the_uncut_layer():
+def test_the_shares_of_all_ranks_add_up_to_the_uncut_layer(expert_lane):
     """(c) Four expert-parallel ranks of two experts each: the held
     experts' parts of all ranks, with the zero-computation experts (which
     every chip computes alike) counted ONCE, are the uncut reference
-    layer over all eight experts."""
+    layer over all eight experts; on each lane of the few-token experts."""
     cfg, _ = FAMILY.build(MODEL, GEOMETRY)
     u, everyone = some_hidden(40, salt=5), tuple(range(8))
     leaves = jax.jit(lambda words: ref.layer_leaves(MODEL, words, 0, everyone))(weights.seed_words(SEED))
@@ -332,6 +344,28 @@ def test_the_routing_counts_come_back_with_the_tokens(streams):
     per_expert = np.asarray(state["prefill"]["expert_tokens"]) + np.asarray(state["decode"]["expert_tokens"])
     assert series(registry, 'tpu_engine_moe_expert_tokens_total{expert="5",layer="1"}') == per_expert[1, 2]
     assert series(registry, "tpu_engine_moe_expert_tokens_peak") == per_expert.max()
+    # The XLA lane on this backend: the kernel's counter exists and stays 0.
+    assert state["expert_kernel"] is False and series(registry, "tpu_engine_moe_kernel_layer_steps_total") == 0
+
+
+def test_the_kernels_counter_follows_the_lane_of_the_compiled_program(served, streams, monkeypatch):
+    """On a backend whose lane is the kernel (here: said so to the engine,
+    and the kernel forced through the interpreter) every decode layer-step
+    is counted as the kernel's, the profile says ``expert_kernel``, and
+    the served tokens are the XLA lane's."""
+    from k8s_device_plugin_tpu.models import engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "on_kernel_lane", lambda: True)
+    monkeypatch.setattr(moe, "expert_ffn", functools.partial(moe.expert_ffn, interpret=True))
+    registry = MetricsRegistry()
+    eng = make_engine(served, metrics=EngineMetrics(registry))
+    _, _, cases = streams
+    done = eng.run([(cases[n]["prompt"], NEW) for n in (5, 17)])
+    assert [list(r.tokens) for r in done] == [cases[n]["tokens"] for n in (5, 17)]
+    state = eng.moe_state()
+    assert state["expert_kernel"] is True and state["decode"]["active"] > 0 and state["decode"]["dropped"] == 0
+    assert series(registry, "tpu_engine_moe_kernel_layer_steps_total") == state["decode"]["active"]
+    assert series(registry, "tpu_engine_moe_decode_layer_steps_total") == state["decode"]["active"]
 
 
 def test_a_shared_prefix_shares_latent_pages(served, streams):
