@@ -281,7 +281,9 @@ class ServingEngine(
         # that BUILDS it refuses below or falls back (restore-resume:
         # engine_kvcache.py; split roles: engine_handoff.py).
         self.slot_state_bytes = slot_state_bytes(self.cache)
-        self.cache_bytes_per_token = cache_bytes_per_token(self.cache)
+        self.cache_bytes_per_token, self.cache_pad_bytes_per_token = cache_bytes_per_token(
+            self.cache, cfg.mla.row_width if cfg.mla is not None else None
+        )
         if self.slot_state_bytes and spec_gamma > 0:
             raise ValueError(
                 "spec_gamma > 0 is not supported on a model with per-slot "
@@ -476,6 +478,7 @@ class ServingEngine(
             metrics.tp_size.set(self.tp_size)
             metrics.slot_state_bytes.set(self.slot_state_bytes)
             metrics.cache_bytes_per_token.set(self.cache_bytes_per_token)
+            metrics.cache_pad_bytes_per_token.set(self.cache_pad_bytes_per_token)
         # Routing counts of a model with expert layers (models/moe.py),
         # summed on the host from what the decode programs pack behind
         # their tokens and the prefill chunks hand back (_moe_fold): rows
@@ -1098,8 +1101,12 @@ class ServingEngine(
             return None
         out = {
             "held_experts": list(self.cfg.moe.held), "cache_bytes_per_token": self.cache_bytes_per_token,
+            "cache_pad_bytes_per_token": self.cache_pad_bytes_per_token,
             "expert_kernel": self.moe_expert_kernel,
         }
+        if self.cfg.mla is not None:
+            # A latent row as the model defines it and as the pool stores it.
+            out["latent_row"] = {"width": self.cfg.mla.row_width, "stored": self.cfg.mla.stored_width}
         for phase, counts in self.moe_counts.items():
             out[phase] = {
                 **{name: int(counts[:, i].sum()) for i, name in enumerate(STATS)},

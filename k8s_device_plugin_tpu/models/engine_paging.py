@@ -46,15 +46,23 @@ def slot_leaves(layer: dict):
     ]
 
 
-def cache_bytes_per_token(cache: dict) -> int:
-    """Device bytes one cached position takes over all layers: a row of
-    every ``pool_*`` leaf (K and V with their scales, or one latent)."""
-    return sum(
-        leaf.size // (leaf.shape[0] * leaf.shape[1]) * leaf.dtype.itemsize
-        for layer in cache.values()
-        for name, leaf in layer["attn"].items()
-        if name.startswith("pool_")
-    )
+def cache_bytes_per_token(cache: dict, latent_row: Optional[int] = None) -> tuple[int, int]:
+    """Device bytes one cached position takes over all layers, as (row,
+    pad): the row the model defines in every ``pool_*`` leaf (K and V with
+    their scales, or one latent row of ``latent_row`` values) and the
+    lanes a ``pool_latent`` stores beyond it (models/mla.py stores a row
+    lane-aligned).  Padding is storage, not work: what a step reads and
+    writes of a position is ``row``."""
+    row = pad = 0
+    for layer in cache.values():
+        for name, leaf in layer["attn"].items():
+            if not name.startswith("pool_"):
+                continue
+            stored = leaf.size // (leaf.shape[0] * leaf.shape[1])
+            used = latent_row if name == "pool_latent" else stored
+            row += used * leaf.dtype.itemsize
+            pad += (stored - used) * leaf.dtype.itemsize
+    return row, pad
 
 
 def slot_state_bytes(cache: dict) -> int:

@@ -229,8 +229,15 @@ class EngineMetrics:
             "tpu_engine_cache_bytes_per_token",
             "Device bytes one cached position takes over all layers: a "
             "row of every page pool (K and V, int8 scales beside them, "
-            "or one latent row an attention).  Set once at engine "
-            "construction",
+            "or one latent row an attention), as the model defines the "
+            "row.  Set once at engine construction",
+        )
+        self.cache_pad_bytes_per_token = registry.gauge(
+            "tpu_engine_cache_pad_bytes_per_token",
+            "Device bytes of padding one cached position holds over all "
+            "layers beyond tpu_engine_cache_bytes_per_token: the zero "
+            "lanes that store a latent row lane-aligned (0 for K/V "
+            "pools).  Set once at engine construction",
         )
         # Routing counts of a model with expert layers (models/moe.py):
         # summed on the device over a decode block, read inside the

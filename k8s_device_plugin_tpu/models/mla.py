@@ -11,7 +11,14 @@ Per token (``h`` hidden, ``H`` heads, ranks ``r_q``/``r_kv``, head widths
 
 What a token leaves in the cache is ``[c^ | rope(k_r)]``: ``r_kv + d_r``
 wide (576 where a 64-head K/V row would be 16,384), never the per-head keys
-and values.  Two compute paths over one set of parameters:
+and values.  The row is STORED lane-aligned: zero lanes pad it to a
+multiple of 128 (``[c^ | rope(k_r) | 0]``, 640 wide), so a TPU keeps the
+pool row-major.  A 576-wide row is no multiple of 128 lanes: row-major
+tiles would pad it to 640 anyway, so the chip's compact layout would put
+the pages minor, and every decode program and graft would lay the whole
+pool out row-major for its scatter and gather and back.  The pad lanes
+are never read: the absorbed path contracts ``[..., :r_kv + d_r]`` and
+``[..., :r_kv]``.  Two compute paths over one set of parameters:
 
 - EXPANDED (``mla.expand``): per-head ``k_n`` and ``v`` through ``W_kvb``,
   then ordinary causal attention.  For a bulk prefill into an empty cache
@@ -25,12 +32,13 @@ and values.  Two compute paths over one set of parameters:
   positions a step would cost 60x the step's other FLOPs.
 
 Three cache forms, as ``CausalSelfAttention`` has them: none; dense
-``cached_latent [batch, max_seq, r_kv + d_r]`` with ``cache_index`` (the
-engine's prefill bridge); paged ``pool_latent [num_pages, page_size,
-r_kv + d_r]`` with ``page_table`` / ``seq_lens``.  The serving engine's
-compiled cache writers, page copies and snapshots walk ``pool_*`` /
-``cached_*`` leaves of any trailing shape, so a latent pool rides them
-as a K/V pair does (models/engine_paging.py).
+``cached_latent [batch, max_seq, W]`` with ``cache_index`` (the engine's
+prefill bridge); paged ``pool_latent [num_pages, page_size, W]`` with
+``page_table`` / ``seq_lens``; ``W`` is ``MlaConfig.stored_width``.  The
+serving engine's compiled cache writers, page copies and snapshots walk
+``pool_*`` / ``cached_*`` leaves of any trailing shape, so a latent pool
+rides them as a K/V pair does (models/engine_paging.py): both forms hold
+the same padded row, and every writer copies its zero lanes along.
 """
 
 from __future__ import annotations
@@ -49,6 +57,9 @@ import jax.numpy as jnp
 # chunk of 64 x 256 against 512 cached positions holds 0.5 GB of scores and
 # 0.6 GB of queries and outputs at a time, not 2 and 3.3.
 _SCORE_ELEMS = 1 << 27
+
+# A TPU vector register's lanes: the minor dimension of a tile.
+LANES = 128
 
 
 @dataclass(frozen=True)
@@ -70,6 +81,13 @@ class MlaConfig:
         """Width of a token's cache row: the latent and the rotary key."""
         return self.kv_rank + self.rope_dim
 
+    @property
+    def stored_width(self) -> int:
+        """Width of a cache row as stored: ``row_width`` rounded up to whole
+        lanes, the rest zeros.  A row that is already lane-aligned is
+        stored as it is."""
+        return -(-self.row_width // LANES) * LANES
+
     def __post_init__(self):
         if self.rope_dim % 2:
             raise ValueError(f"rope_dim {self.rope_dim} must be even (rotary pairs)")
@@ -79,19 +97,21 @@ def absorbed_attention(q_n, q_r, latent, positions, kv_b, sm_scale: float):
     """Attention of queries moved into the latent space against cache rows.
 
     q_n [batch, q_len, heads, d_n], q_r [batch, q_len, heads, d_r] (rotated);
-    latent [batch, L, r_kv + d_r] (``c^ | rope(k_r)``: a dense cache or a
-    gathered page view); kv_b [r_kv, heads, d_n + d_v]; a query at
+    latent [batch, L, >= r_kv + d_r] (``c^ | rope(k_r)``, then any pad
+    lanes, which no contraction reads: a dense cache or a gathered page
+    view); kv_b [r_kv, heads, d_n + d_v]; a query at
     ``positions[b, i]`` sees rows ``<= position``.  Returns the heads'
     outputs [batch, q_len, heads, d_v].  Everything that is heads x r_kv
     wide (``q~``, the scores, ``o~``) lives inside one block of rows."""
     from .transformer import NEG_LOGIT
 
     r_kv, d_n = kv_b.shape[0], q_n.shape[-1]
+    width = r_kv + q_r.shape[-1]
 
     def rows(q_n, q_r, lat, pos):
         q_lat = jnp.concatenate([jnp.einsum("bqhd,chd->bqhc", q_n, kv_b[..., :d_n]), q_r], axis=-1)
         key_pos = jnp.arange(lat.shape[1])[None, None, None, :]
-        s = jnp.einsum("bqhc,bkc->bhqk", q_lat, lat, preferred_element_type=jnp.float32) * sm_scale
+        s = jnp.einsum("bqhc,bkc->bhqk", q_lat, lat[..., :width], preferred_element_type=jnp.float32) * sm_scale
         s = jnp.where(key_pos <= pos[:, None, :, None], s, NEG_LOGIT)
         p = jax.nn.softmax(s, axis=-1).astype(lat.dtype)
         o_lat = jnp.einsum("bhqk,bkc->bqhc", p, lat[..., :r_kv])
@@ -148,6 +168,8 @@ class LatentAttention(nn.Module):
             q_n, q_r = q[..., :d_n], apply_rope(q[..., d_n:], cos, sin)
             k_r = apply_rope(kv[..., None, r_kv:], cos, sin)[:, :, 0]  # one key for all heads
             row = jnp.concatenate([latent, k_r], axis=-1)  # the token's cache row
+            if mc.stored_width > mc.row_width:  # stored lane-aligned, zero lanes last
+                row = jnp.pad(row, ((0, 0), (0, 0), (0, mc.stored_width - mc.row_width)))
         # [r_kv, H, d_n + d_v]: a plain parameter, read whole by the
         # expanded path and in its two halves by the absorbed one.
         kv_b = self.param(
@@ -169,7 +191,7 @@ class LatentAttention(nn.Module):
                 return out.transpose(0, 2, 1, 3)[..., :d_v]
 
         def absorbed(cache_rows):
-            """Attention against cache rows [batch, L, r_kv + d_r]."""
+            """Attention against stored cache rows [batch, L, stored_width]."""
             with jax.named_scope("mla.absorb"):
                 return absorbed_attention(q_n, q_r, cache_rows, positions, kv_b, sm_scale)
 
@@ -181,7 +203,7 @@ class LatentAttention(nn.Module):
                     "paged-attention kernel (ops/paged_attention.py) takes key and value pools"
                 )
             pool = self.variable(
-                "cache", "pool_latent", jnp.zeros, (pg.num_pages, pg.page_size, mc.row_width), row.dtype
+                "cache", "pool_latent", jnp.zeros, (pg.num_pages, pg.page_size, mc.stored_width), row.dtype
             )
             table = self.variable("cache", "page_table", jnp.zeros, (batch, pg.max_pages_per_seq), jnp.int32)
             lens = self.variable("cache", "seq_lens", jnp.zeros, (batch,), jnp.int32)
@@ -193,12 +215,12 @@ class LatentAttention(nn.Module):
             pool.value = pool.value.at[page, offs % pg.page_size].set(row)
             lens.value = cur + q_len
             with jax.named_scope("paged_gather"):
-                rows = pool.value[table.value].reshape(batch, pg.max_len, mc.row_width)
+                rows = pool.value[table.value].reshape(batch, pg.max_len, mc.stored_width)
             attn = absorbed(rows)
         elif self.decode:
             idx = self.variable("cache", "cache_index", lambda: jnp.zeros((), jnp.int32))
             cached = self.variable(
-                "cache", "cached_latent", jnp.zeros, (batch, cfg.max_seq, mc.row_width), row.dtype
+                "cache", "cached_latent", jnp.zeros, (batch, cfg.max_seq, mc.stored_width), row.dtype
             )
             cur = idx.value
             cached.value = jax.lax.dynamic_update_slice(cached.value, row, (0, cur, 0))

@@ -146,6 +146,27 @@ def test_graft_writes_private_pages_and_nothing_else(engines, kind, plen, n_shar
     np.testing.assert_array_equal(np.asarray(eng._chain), chain)
 
 
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_kv_pool_stores_the_row_the_model_defines(engines, kind):
+    """K/V pools keep their [pages, page_size, kv_heads, head_dim] shape
+    and hold no padding: the bytes a cached position takes are the K and
+    V rows (and int8 scales) of every layer, and the pad gauge reads 0
+    (only a latent row is stored lane-aligned, models/mla.py)."""
+    eng, registry = engines[kind]
+    cfg = eng.cfg
+    att = eng.cache["layer_0"]["attn"]
+    shape = (PAGED.num_pages, PS, cfg.kv_heads, cfg.head_dim)
+    assert att["pool_key"].shape == att["pool_value"].shape == shape
+    itemsize = {"bf16": 2, "int8": 1}.get(kind, 4)
+    scales = 2 * cfg.kv_heads * 4 if kind == "int8" else 0
+    assert eng.cache_bytes_per_token == cfg.num_layers * (2 * cfg.kv_heads * cfg.head_dim * itemsize + scales)
+    assert eng.cache_pad_bytes_per_token == 0
+    for series, want in (("tpu_engine_cache_bytes_per_token", eng.cache_bytes_per_token),
+                         ("tpu_engine_cache_pad_bytes_per_token", 0)):
+        [line] = [l for l in registry.render().splitlines() if l.startswith(series + " ")]
+        assert float(line.split()[-1]) == want
+
+
 def test_prompt_lengths_add_no_writer_and_one_dispatch_a_prompt(engines):
     """Two prompts of different lengths (and shared-page counts) in one
     bucket run the SAME compiled writer: the programs gauge stays put

@@ -3,7 +3,10 @@ through the Pallas interpreter against the XLA lane (the ``fori_loop`` of
 ``skip`` / ``full`` that serves off the TPU), inside ``models/moe.py``'s
 expert layer at a toy size in bfloat16; then the kernel compiled for a
 described v5e at LongCat-Flash's widths, which the interpreter cannot
-refuse (tiling, VMEM)."""
+refuse (tiling, VMEM), and the decode programs of a latent paged cache
+compiled for it, whose layouts only the chip's compiler chooses.  Every
+compile for the described chip lives in this file: one worker loads the
+TPU's library for all of them."""
 
 import dataclasses
 import functools
@@ -16,8 +19,10 @@ import numpy as np
 import pytest
 
 from chipbench import families
+from chipbench import weights
 from k8s_device_plugin_tpu.models import moe
-from k8s_device_plugin_tpu.models.transformer import GPTConfig, TransformerLM
+from k8s_device_plugin_tpu.models.engine_sampling import build_block_fn, build_step_fn
+from k8s_device_plugin_tpu.models.transformer import GPTConfig, TransformerLM, decode_cache_spec
 from k8s_device_plugin_tpu.ops import expert_ffn as kernel
 from k8s_device_plugin_tpu.ops import tuning
 
@@ -218,3 +223,44 @@ def test_the_kernel_compiles_for_a_v5e_at_longcat_flash_widths(one_chip, rows):
     ).compile().as_text()
     assert text.count("tpu_custom_call") >= 1
     assert not [ln for ln in text.splitlines() if " copy(" in ln and ("[16,6144,2048]" in ln or "[16,2048,6144]" in ln)]
+
+
+@pytest.mark.parametrize("program", ["single step", "decode block"])
+def test_the_latent_pool_is_never_laid_out_anew(one_chip, program):
+    """The tiny LongCat-Flash preset in bfloat16 on 256 pages of 16, its
+    decode program compiled for a v5e: the latent pool comes in row-major
+    and no copy of a pool-sized operand is in the program.  (A row stored
+    24 wide makes the chip's compact layout put the pages minor, and then
+    each program lays every pool out row-major for its scatter and gather
+    and back again.)"""
+    family = families.load("longcat_flash")
+    model_conf = {**MODEL, "torch_dtype": "bfloat16"}
+    cfg, paged = family.build(model_conf, {"page_size": 16, "num_pages": 256, "max_pages_per_seq": 8})
+    model = TransformerLM(dataclasses.replace(cfg, paged=paged), decode=True)
+    slots = 4
+    place = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)  # noqa: E731
+    params = jax.eval_shape(lambda w: family.params_tree(model_conf, w), weights.seed_words(1))
+    cache = decode_cache_spec(model, slots)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+    build = build_step_fn if program == "single step" else functools.partial(build_block_fn, T=4)
+    fn = build(model, filtered=False, want_lp=False, derive_tables=True)
+    text = fn.lower(
+        jax.tree.map(place, params), jax.tree.map(place, cache), i32(slots, 1), i32(slots, 1),
+        jax.ShapeDtypeStruct((slots,), jnp.float32, sharding=one_chip), i32(slots),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip), i32(slots, paged.max_pages_per_seq),
+    ).compile().as_text()
+    pool = (paged.num_pages, paged.page_size, cfg.mla.stored_width)
+    assert cache["layer_0"]["attn"]["pool_latent"].shape == pool
+    dims = "[" + ",".join(map(str, pool)) + "]{"
+    # The module's entry layout (its first line): minor to major 2, 1, 0.
+    entry = text.splitlines()[0]
+    assert entry.count(dims) == 2 * cfg.num_layers and entry.count(dims + "2,1,0") == 2 * cfg.num_layers
+    pool_sized = {int(np.prod(pool)), int(np.prod(pool[:2])) * cfg.mla.row_width}
+    copies = []
+    for line in text.splitlines():
+        if not any(f" {op}(" in line for op in ("copy", "copy-start", "copy-done")):
+            continue
+        shape = line.split("=", 1)[1].split("]", 1)[0].split("[", 1)[1]
+        if shape and int(np.prod([int(d) for d in shape.split(",")])) in pool_sized:
+            copies.append(line.strip()[:160])
+    assert not copies, copies

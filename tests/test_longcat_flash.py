@@ -63,6 +63,16 @@ def series(registry, line_start):
     return float(line.rsplit(" ", 1)[1])
 
 
+def assert_pad_lanes_zero(eng):
+    """Every latent pool is stored lane-aligned, and the lanes past the
+    model's row read zero on every page."""
+    row = eng.cfg.mla.row_width
+    for name in eng._layer_names:
+        pool = np.asarray(eng.cache[name]["attn"]["pool_latent"])
+        assert pool.shape[-1] % mla.LANES == 0 and pool.shape[-1] > row, pool.shape
+        assert not pool[..., row:].any(), name
+
+
 def worst_gap(cases, **kw):
     rows = ref.served_gaps(MODEL, SEED, cases, pad_to=48, control=False, **kw)
     return max(g for row in rows for g in row["gaps"])
@@ -176,13 +186,37 @@ def test_absorbed_attention_is_expanded_attention():
     expanded = mla.LatentAttention(cfg).apply({"params": params}, x[None], pos)
     np.testing.assert_allclose(np.asarray(expanded[0]), want, rtol=1e-4, atol=1e-5)
     cached = mla.LatentAttention(dataclasses.replace(cfg, max_seq=24), decode=True, append_mode="cached")
-    cache = {"cache_index": jnp.zeros((), jnp.int32), "cached_latent": jnp.zeros((1, 24, 24), jnp.float32)}
+    stored = cfg.mla.stored_width
+    cache = {"cache_index": jnp.zeros((), jnp.int32), "cached_latent": jnp.zeros((1, 24, stored), jnp.float32)}
     absorbed, mut = cached.apply({"params": params, "cache": cache}, x[None], pos, mutable=["cache"])
     np.testing.assert_allclose(np.asarray(absorbed[0]), want, rtol=1e-4, atol=1e-5)
-    # What the cache holds of a token: the scaled normed latent and the rotated key, 16 + 8 wide.
+    # What the cache holds of a token: the scaled normed latent and the
+    # rotated key, 16 + 8 wide, then zero lanes up to 128.
+    assert (cfg.mla.row_width, stored) == (24, 128)
     row = np.asarray(mut["cache"]["cached_latent"][0, :19])
     latent = ref._rmsnorm(ref._matmul(x, w["attn0/kv_a"])[:, :16], w["attn0/kv_norm"], 1e-5) * d["scale_kv"]
     np.testing.assert_allclose(row[:, :16], np.asarray(latent), rtol=1e-4, atol=1e-5)
+    assert not row[:, 24:].any()
+
+
+@pytest.mark.parametrize("kv_rank,rope_dim,stored", [(16, 8, 128), (512, 64, 640), (448, 64, 512), (120, 8, 128)])
+def test_a_latent_row_is_stored_lane_aligned(kv_rank, rope_dim, stored):
+    """The stored width is the row's rounded up to whole 128-lane tiles,
+    read off the row alone: a row already aligned is stored as it is."""
+    mc = mla.MlaConfig(kv_rank=kv_rank, rope_dim=rope_dim)
+    assert mc.row_width == kv_rank + rope_dim and mc.stored_width == stored
+
+
+def test_pad_lanes_reach_no_contraction():
+    """Absorbed attention over rows with pad lanes is attention over the
+    rows without them, whatever the lanes hold."""
+    keys = jax.random.split(jax.random.PRNGKey(1), 5)
+    q_n, q_r = jax.random.normal(keys[0], (2, 3, 4, 16)), jax.random.normal(keys[1], (2, 3, 4, 8))
+    lat, kv_b = jax.random.normal(keys[2], (2, 12, 24)), jax.random.normal(keys[3], (16, 4, 32))
+    pos = jnp.broadcast_to(jnp.arange(9, 12), (2, 3))
+    want = mla.absorbed_attention(q_n, q_r, lat, pos, kv_b, 0.2)
+    padded = jnp.concatenate([lat, jax.random.normal(keys[4], (2, 12, 104))], axis=-1)
+    np.testing.assert_array_equal(np.asarray(mla.absorbed_attention(q_n, q_r, padded, pos, kv_b, 0.2)), np.asarray(want))
 
 
 def test_rows_taken_in_turn_give_the_same_attention(monkeypatch):
@@ -315,10 +349,18 @@ def test_graft_and_clear_are_one_dispatch_each_on_a_latent_pool(streams):
     # Optimistic admission grew generation pages through the chain writer.
     assert eng.chain_write_dispatches > 0 and eng.chain_pages_written >= len(LENGTHS)
     att = eng.cache["layer_0"]["attn"]
-    assert sorted(att) == ["page_table", "pool_latent", "seq_lens"] and att["pool_latent"].shape == (96, 4, 24)
-    # One float32 row of 16 + 8 an attention, four attentions.
+    assert sorted(att) == ["page_table", "pool_latent", "seq_lens"] and att["pool_latent"].shape == (96, 4, 128)
+    # One float32 row of 16 + 8 an attention, four attentions; stored
+    # 128 lanes wide, the other 104 padding.
     assert eng.cache_bytes_per_token == 4 * 24 * 4
     assert series(registry, "tpu_engine_cache_bytes_per_token") == eng.cache_bytes_per_token
+    assert eng.cache_pad_bytes_per_token == 4 * (128 - 24) * 4
+    assert series(registry, "tpu_engine_cache_pad_bytes_per_token") == eng.cache_pad_bytes_per_token
+    state = eng.moe_state()
+    assert state["latent_row"] == {"width": 24, "stored": 128}
+    assert state["cache_pad_bytes_per_token"] == eng.cache_pad_bytes_per_token
+    # After decode blocks, grafts and slot teardowns the pad lanes still read zero.
+    assert_pad_lanes_zero(eng)
 
 
 def test_the_routing_counts_come_back_with_the_tokens(streams):
@@ -398,6 +440,30 @@ def test_preemption_resumes_on_a_latent_pool(served, streams):
     assert [r.tokens for r in subs] == [cases[n]["tokens"] for n in (9, 12)]
     assert eng.preemptions >= 1 and eng.kv_resumes_recompute + eng.kv_resumes_restored == eng.preemptions
     assert eng.moe_state()["decode"]["dropped"] == 0 and len(parked) > 0
+    # The victim came back from its retained pages and a tail snapshot:
+    # those rows went through the host and back with their pad lanes.
+    assert eng.kv_resumes_restored >= 1
+    assert_pad_lanes_zero(eng)
+
+
+def test_a_snapshot_round_trip_restores_latent_pages(served, streams, tmp_path):
+    """A warm replica's latent pages saved to a snapshot and loaded by a
+    fresh one: the fresh one restores them from its host tier in place of
+    a prefill, serves the same tokens, and its pad lanes read zero."""
+    from k8s_device_plugin_tpu.models.engine_snapshot import load_arena_snapshot, save_arena_snapshot
+
+    _, _, cases = streams
+    prompt = cases[17]["prompt"]
+    warm = make_engine(served, kv_retain=True, kv_host_cache_mb=8)
+    [first] = warm.run([(prompt, NEW)])
+    path = str(tmp_path / "kv_arena.snapshot")
+    assert save_arena_snapshot(warm, path)["entries"] >= 4
+    fresh = make_engine(served, kv_retain=True, kv_host_cache_mb=8)
+    assert load_arena_snapshot(fresh, path)["restored"] >= 4
+    [again] = fresh.run([(prompt, NEW)])
+    assert first.tokens == again.tokens == cases[17]["tokens"]
+    assert fresh.kv_restores >= 4
+    assert_pad_lanes_zero(fresh)
 
 
 def test_a_reused_slot_serves_what_a_fresh_one_serves(served, streams):
