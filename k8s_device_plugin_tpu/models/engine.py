@@ -45,7 +45,6 @@ Module layout (round-4 split; this module remains the import surface):
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import threading
 import time
@@ -79,8 +78,8 @@ from ..utils import failpoints
 from ..utils.anomaly import AnomalyMonitor
 from ..utils.flight import FlightRecorder
 from ..utils.platform import device_facts
-from ..utils.spans import ENGINE_TRACE, SpanRecorder
-from .engine_profiler import EngineProfiler
+from ..utils.spans import SpanRecorder
+from .engine_profiler import EngineProfiler, in_phase
 from .moe import STATS, few_tokens
 from .transformer import (
     GPTConfig,
@@ -571,6 +570,7 @@ class ServingEngine(
                 ),
                 seconds=metrics.loop_seconds if metrics else None,
                 counts=metrics.loop_counts if metrics else None,
+                host_cpu=metrics.loop_host_cpu if metrics else None,
             )
         )
         self._step_tokens = 0  # tokens emitted by the step in flight
@@ -768,47 +768,53 @@ class ServingEngine(
         previous step fed forward (tokens/positions/key) plus the cached
         uploads (temps/aids/filters/biases, which only change via dirty
         events)."""
-        dev = self._dev
-        if dev is None:
-            if self.metrics:
-                self.metrics.state_rebuilds.inc()
-            self._rng, sub = jax.random.split(self._rng)
-            # _rep: the rebuild re-applies the sharding contract (mesh
-            # engines replicate these per-slot vectors explicitly; the
-            # no-mesh path is identity).
-            dev = self._dev = {
-                "tokens": self._rep(
-                    jnp.asarray(self._slot_last, jnp.int32)[:, None]
-                ),
-                "positions": self._rep(
-                    jnp.asarray(self._slot_len, jnp.int32)[:, None]
-                ),
-                "temps": self._rep(jnp.asarray(self._slot_temp, jnp.float32)),
-                "aids": self._rep(jnp.asarray(self._slot_aid, jnp.int32)),
-                "key": self._rep(sub),
-            }
-            # Step-variant selector flags ride the state dict: they are a
-            # function of the occupied slots' sampler settings, which only
-            # ever change through an activation/teardown — events that
-            # dirty the whole state — so ONE slot scan per rebuild
-            # replaces three full-slot scans per step in the hot loop.
-            filtered = want_lp = biased = False
-            for s in range(self.max_slots):
-                req = self.slots[s]
-                if req is None:
-                    continue
-                if (
-                    self._slot_topk[s] < self.cfg.vocab_size
-                    or self._slot_topp[s] < 1.0
-                ):
-                    filtered = True
-                if req.logprobs:
-                    want_lp = True
-                if req.logit_bias:
-                    biased = True
-            dev["filtered"] = filtered
-            dev["want_lp"] = want_lp
-            dev["biased"] = biased
+        if self._dev is None:
+            return self._rebuild_device_state()
+        return self._dev
+
+    @in_phase("dispatch.state")
+    def _rebuild_device_state(self) -> dict:
+        """Build the per-slot step state from the host lists: four
+        uploads, a key split and the variant flags."""
+        if self.metrics:
+            self.metrics.state_rebuilds.inc()
+        self._rng, sub = jax.random.split(self._rng)
+        # _rep: the rebuild re-applies the sharding contract (mesh
+        # engines replicate these per-slot vectors explicitly; the
+        # no-mesh path is identity).
+        dev = self._dev = {
+            "tokens": self._rep(
+                jnp.asarray(self._slot_last, jnp.int32)[:, None]
+            ),
+            "positions": self._rep(
+                jnp.asarray(self._slot_len, jnp.int32)[:, None]
+            ),
+            "temps": self._rep(jnp.asarray(self._slot_temp, jnp.float32)),
+            "aids": self._rep(jnp.asarray(self._slot_aid, jnp.int32)),
+            "key": self._rep(sub),
+        }
+        # Step-variant selector flags ride the state dict: they are a
+        # function of the occupied slots' sampler settings, which only
+        # ever change through an activation/teardown — events that
+        # dirty the whole state — so ONE slot scan per rebuild
+        # replaces three full-slot scans per step in the hot loop.
+        filtered = want_lp = biased = False
+        for s in range(self.max_slots):
+            req = self.slots[s]
+            if req is None:
+                continue
+            if (
+                self._slot_topk[s] < self.cfg.vocab_size
+                or self._slot_topp[s] < 1.0
+            ):
+                filtered = True
+            if req.logprobs:
+                want_lp = True
+            if req.logit_bias:
+                biased = True
+        dev["filtered"] = filtered
+        dev["want_lp"] = want_lp
+        dev["biased"] = biased
         return dev
 
     def _feed_forward(self, dev: dict, tokens, positions, key) -> dict:
@@ -953,12 +959,14 @@ class ServingEngine(
                 if T == 1
                 else self.metrics.decode_dispatches_block
             ).inc()
-        out, ff_tok, ff_pos, ff_key, self.cache = fn(
+        args = (
             self.params, self.cache, dev["tokens"], dev["positions"],
             dev["temps"], dev["aids"], dev["key"],
             *self._chain_args(),
             *self._variant_arrays(dev, filtered, biased),
         )
+        with self.profiler.phase("dispatch.enqueue"):
+            out, ff_tok, ff_pos, ff_key, self.cache = fn(*args)
         return {
             "T": T,
             "out": out,
@@ -996,19 +1004,11 @@ class ServingEngine(
         owner before any visible read; a surviving slot's re-dispatch
         overwrites position L with identical rows), but the dispatch
         advanced every row's carried seq_lens past host truth — re-align
-        in one vector write per layer.  A FRESH array per layer: sharing
-        one would hand the next dispatch's donation the same buffer
-        twice, which XLA rejects (see the identical note in _spec_step).
-        The fed-forward state derives from outputs the host never
+        them.  The fed-forward state derives from outputs the host never
         consumed, so it is dropped too: the next dispatch rebuilds from
         the host lists."""
         self._dev = None
-        for name in self._layer_names:
-            att = self.cache[name]["attn"]
-            self.cache[name]["attn"] = {
-                **att,
-                "seq_lens": self._rep(jnp.array(self._slot_len, jnp.int32)),
-            }
+        self._realign_seq_lens()
         self.overlap_discards += 1
         if self.metrics:
             self.metrics.overlap_discards.inc()
@@ -1019,6 +1019,19 @@ class ServingEngine(
                 T=inflight["T"],
                 slots=len(inflight["active"]),
             )
+
+    @in_phase("finish.realign")
+    def _realign_seq_lens(self) -> None:
+        """Set every attention layer's device lengths to host truth, in
+        one vector write per layer.  A FRESH array per layer: sharing
+        one would hand the next dispatch's donation the same buffer
+        twice, which XLA rejects (see the identical note in _spec_step)."""
+        for name in self._layer_names:
+            att = self.cache[name]["attn"]
+            self.cache[name]["attn"] = {
+                **att,
+                "seq_lens": self._rep(jnp.array(self._slot_len, jnp.int32)),
+            }
 
     def _drop_stale_inflight(self, reason: str) -> None:
         """Discard the pending overlap dispatch (if any) after a dirty
@@ -1224,14 +1237,7 @@ class ServingEngine(
                 if self._inflight is not None:
                     self._drop_stale_inflight("slot_teardown")
                 else:
-                    for name in self._layer_names:
-                        att = self.cache[name]["attn"]
-                        self.cache[name]["attn"] = {
-                            **att,
-                            "seq_lens": self._rep(
-                                jnp.array(self._slot_len, jnp.int32)
-                            ),
-                        }
+                    self._realign_seq_lens()
             self._step_tokens += emitted_total
             if self.metrics:
                 self.metrics.steps.inc()
@@ -1243,11 +1249,6 @@ class ServingEngine(
         """Admit what fits, advance every active slot one token; returns
         every request that finished this step (including ones done at
         admission — EOS/max_new on the prefill token)."""
-        span = (
-            self.spans.span("engine.step", trace_id=ENGINE_TRACE)
-            if self.spans
-            else contextlib.nullcontext()
-        )
         self.profiler.begin_step()
         self._step_tokens = 0
         hits0, discards0 = self.overlap_hits, self.overlap_discards
@@ -1257,11 +1258,10 @@ class ServingEngine(
         if wd is not None:
             wd.step_started()
         try:
-            with span:
-                if self.metrics:
-                    with self.metrics.step_seconds.time():
-                        return self._step_inner()
-                return self._step_inner()
+            if self.metrics:
+                with self.metrics.step_seconds.time():
+                    return self._step_inner()
+            return self._step_inner()
         finally:
             with self._lock:
                 active = sum(1 for s in self.slots if s is not None)

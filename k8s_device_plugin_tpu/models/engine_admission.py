@@ -906,6 +906,7 @@ class AdmissionMixin:
             self._slot_bias_vals[slot] = [0.0] * self.MAX_BIAS
         self._slot_aid[slot] = req.adapter if req.adapter is not None else -1
 
+    @in_phase("prefill.first_token")
     def _sample_first_token(self, req: Request, last_logits) -> int:
         """Sample one request's ADMISSION token from its last-position
         logits — the same math the jitted step applies (bias what gets

@@ -27,6 +27,15 @@ no event encloses a step.  (A gap labeller that names a device gap
 after the host event covering MOST of it would otherwise give every
 gap to the enclosing event.)
 
+At the same instants the owner thread's CPU clock (``time.thread_time``)
+is read, and its seconds go FLAT to the innermost open phase, as the
+annotations do: ``cpu_s`` beside ``total_s`` in ``GET /debug/profile``.
+A phase whose CPU seconds fall well short of its wall seconds waits (for
+the chip, or for the GIL other threads hold); one that burns its wall
+seconds runs its own Python.  One counter takes the CPU of the host's
+own work: every step phase but the waits for the chip (``readback``,
+``prefill.first_token``) and ``spec_verify``.
+
 Every ``summary_every`` steps a compact aggregate goes into the flight
 recorder (utils/flight.py) as an ``engine.step`` event — the black box
 carries the performance timeline alongside the lifecycle events — and
@@ -74,8 +83,17 @@ SUB_PHASES = (
     "prefill.graft",           # engine_paging._graft: prompt K/V -> pages
     "dispatch.frontier",       # engine_paging._ensure_frontier
     "finish.clear_slot",       # engine_paging._clear_slot
+    # engine_admission._sample_first_token: waits for the prefill program;
+    # in schedule for a handoff admission.
+    "prefill.first_token",
+    "dispatch.state",          # engine._rebuild_device_state
+    "dispatch.enqueue",        # the jit_step / jit_block call itself
+    "finish.realign",          # engine._realign_seq_lens
 )
 ALL_PHASES = PHASES + (IDLE,) + SUB_PHASES
+# Phases whose CPU seconds the host-CPU counter leaves out: the two that
+# wait for the chip, the speculative round, and the wait for work.
+NOT_HOST_CPU = frozenset({"readback", "prefill.first_token", "spec_verify", IDLE})
 
 
 class _Phase:
@@ -126,6 +144,8 @@ class EngineProfiler:
     step's wall seconds.  ``seconds`` and ``counts`` map a phase name to
     the ``/metrics`` counter that takes its seconds / its closes
     (engine_types.EngineMetrics.loop_seconds, .loop_counts).
+    ``host_cpu`` takes the CPU seconds of the host's own work
+    (``NOT_HOST_CPU``); ``thread_clock`` is the owner thread's CPU clock.
     ``snapshot()`` is the JSON body of ``GET /debug/profile``; phases
     are opened and closed on the engine owner thread only, readers run
     on HTTP handler threads — hence the lock.
@@ -139,6 +159,8 @@ class EngineProfiler:
         observe_step=None,
         seconds=None,
         counts=None,
+        host_cpu=None,
+        thread_clock=time.thread_time,
     ):
         if window < 1:
             raise ValueError(f"window must be >= 1, got {window}")
@@ -147,11 +169,14 @@ class EngineProfiler:
         self.observe_step = observe_step
         self._seconds = dict(seconds or {})
         self._counts = dict(counts or {})
+        self._host_cpu = host_cpu
+        self._thread_clock = thread_clock
         self._lock = threading.Lock()
         self._window: deque[dict] = deque(maxlen=window)
         self.steps = 0
         self.tokens = 0
         self._phase_totals = {p: 0.0 for p in ALL_PHASES}
+        self._cpu_totals = {p: 0.0 for p in ALL_PHASES}
         self._phase_cms = {p: _Phase(self, p) for p in ALL_PHASES}
         # Owner-thread-only: the open phases, innermost last, as
         # (name, opened_at); the innermost one's live annotation; and the
@@ -163,6 +188,8 @@ class EngineProfiler:
         # Where the step's last top-level phase closed, and its name.
         self._step_mark = 0.0
         self._step_last: Optional[str] = None
+        # The thread clock at the last instant its seconds were given out.
+        self._cpu_mark = 0.0
         self._mem_fn = "unprobed"  # "unprobed" -> callable | None
 
     # --------------------------------------------------------------- phases
@@ -177,19 +204,28 @@ class EngineProfiler:
             # one starts when this child closes.
             self._annotation.__exit__(None, None, None)
         now = time.perf_counter()
-        if self._step_phases is not None and not self._stack:
+        cpu = self._thread_clock()
+        if self._stack:
+            self._add_cpu(self._stack[-1][0], cpu)
+        elif self._step_phases is not None:
             # Inside a step the top-level phases are contiguous: each
             # runs from where the previous one closed (the first from the
             # step's start), so they sum to the step's wall time and the
             # few microseconds of step() between them are not lost.
+            # The CPU of those microseconds goes the same way.
             now = self._step_mark
+            self._add_cpu(name, cpu)
+        self._cpu_mark = cpu
         self._stack.append((name, now))
         # The event starts at construction (jaxlib TraceMe), not __enter__.
         self._annotation = TraceAnnotation("engine." + name)
 
     def _close(self) -> None:
         self._annotation.__exit__(None, None, None)
-        name, t0 = self._stack.pop()
+        cpu = self._thread_clock()
+        name, t0 = self._stack[-1]
+        self._add_cpu(name, cpu)
+        self._stack.pop()
         now = time.perf_counter()
         if self._stack:
             self._annotation = TraceAnnotation("engine." + self._stack[-1][0])
@@ -200,6 +236,24 @@ class EngineProfiler:
         sink = self._counts.get(name)
         if sink is not None:
             sink.inc()
+
+    def _add_cpu(self, name: str, cpu: float) -> None:
+        """The owner thread's CPU since the last mark, to ``name``: the
+        innermost open phase (or, at a step's edges, the top-level phase
+        the wall time goes to)."""
+        dt = cpu - self._cpu_mark
+        self._cpu_mark = cpu
+        with self._lock:
+            self._cpu_totals[name] += dt
+        # The top-level phase ``name`` runs under counts too: a finish
+        # inside a speculative round is that round's.
+        root = self._stack[0][0] if self._stack else name
+        if (
+            self._host_cpu is not None
+            and name not in NOT_HOST_CPU
+            and root not in NOT_HOST_CPU
+        ):
+            self._host_cpu.inc(dt)
 
     def _add(self, name: str, dt: float) -> None:
         """``dt`` more seconds of ``name`` into all three records."""
@@ -244,6 +298,7 @@ class EngineProfiler:
         also land in its per-step breakdown."""
         self._step_phases = {}
         self._step_t0 = self._step_mark = time.perf_counter()
+        self._cpu_mark = self._thread_clock()
         self._step_last = None
 
     def finish_step(
@@ -270,6 +325,7 @@ class EngineProfiler:
         now = time.perf_counter()
         if self._step_last is not None:
             # step()'s own wrap-up counts in the phase that ran last.
+            self._add_cpu(self._step_last, self._thread_clock())
             self._add(self._step_last, now - self._step_mark)
         wall = now - self._step_t0
         record = {
@@ -347,6 +403,7 @@ class EngineProfiler:
             steps = self.steps
             tokens = self.tokens
             totals = dict(self._phase_totals)
+            cpu = dict(self._cpu_totals)
         n = len(window)
         phases = {}
         for phase in ALL_PHASES:
@@ -354,6 +411,9 @@ class EngineProfiler:
             in_window = [r for r in window if phase in r["phases"]]
             phases[phase] = {
                 "total_s": round(totals[phase], 6),
+                # Flat: the owner thread's CPU while this phase was the
+                # innermost open one (its children's is theirs).
+                "cpu_s": round(cpu[phase], 6),
                 "window_mean_ms": round(
                     (sum(samples) / n * 1e3) if n else 0.0, 4
                 ),

@@ -168,7 +168,39 @@ class EngineMetrics:
                 "Seconds tearing slots down when a request ends or is "
                 "evicted (counted in the phase that ended it)",
             ),
+            "prefill.first_token": registry.counter(
+                "tpu_engine_loop_first_token_seconds_total",
+                "Seconds sampling a request's admission token, waiting "
+                "for its prefill program (counted in prefill, or in "
+                "schedule for a handoff admission)",
+            ),
+            "dispatch.state": registry.counter(
+                "tpu_engine_loop_state_seconds_total",
+                "Seconds rebuilding the per-slot decode step state from "
+                "the host lists (counted in dispatch too)",
+            ),
+            "dispatch.enqueue": registry.counter(
+                "tpu_engine_loop_enqueue_seconds_total",
+                "Seconds in the call that enqueues a decode program, its "
+                "operands already built (counted in dispatch too)",
+            ),
+            "finish.realign": registry.counter(
+                "tpu_engine_loop_realign_seconds_total",
+                "Seconds re-uploading every attention layer's device "
+                "lengths after a mid-consume finish or an overlap "
+                "discard (counted in the phase that needed it)",
+            ),
         }
+        # The owner thread's CPU seconds in the host's own work: every
+        # step phase but readback, prefill.first_token and spec_verify,
+        # each phase's CPU counted once, where it is the innermost.
+        self.loop_host_cpu = registry.counter(
+            "tpu_engine_loop_host_cpu_seconds_total",
+            "Owner-thread CPU seconds in the step phases that do not "
+            "wait for the chip (all but readback, first-token sampling "
+            "and spec_verify); against those phases' wall seconds it "
+            "says how much of them the loop computed",
+        )
         self.loop_counts = {
             "prefill.chunk": registry.counter(
                 "tpu_engine_prefill_chunks_total",
@@ -177,6 +209,11 @@ class EngineMetrics:
             "finish.clear_slot": registry.counter(
                 "tpu_engine_cleared_slots_total",
                 "Slots torn down (finish, cancel, eviction)",
+            ),
+            "finish.realign": registry.counter(
+                "tpu_engine_seq_len_realigns_total",
+                "Re-alignments of the attention layers' device lengths "
+                "to the host's",
             ),
         }
         self.cache_write_dispatches = registry.counter(

@@ -80,7 +80,9 @@ rest of the models/ stack which benchmarks on synthetic ids):
     GET /debug/profile -> 200 JSON per-step profiler snapshot
          (models/engine_profiler.py): per-phase breakdown
          (schedule/prefill/dispatch/readback/sample/host_gap/spec_verify
-         p50/p99 over the rolling window), batch occupancy, KV-page
+         p50/p99 over the rolling window; each phase's lifetime
+         ``total_s`` and ``cpu_s``, the loop thread's CPU while it was
+         the innermost open phase), batch occupancy, KV-page
          utilization, overlap hit/discard window counts, device-memory
          track.  Always on.
     GET /debug/snapshot -> 200 application/octet-stream: the live KV

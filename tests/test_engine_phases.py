@@ -31,6 +31,7 @@ from k8s_device_plugin_tpu.models.engine_types import EngineMetrics
 from k8s_device_plugin_tpu.models.http_server import EngineServer
 from k8s_device_plugin_tpu.models.transformer import GPTConfig, TransformerLM
 from k8s_device_plugin_tpu.utils.metrics import MetricsRegistry
+from k8s_device_plugin_tpu.utils.spans import ENGINE_TRACE, SpanRecorder
 
 LOOP = PHASES + (IDLE,)
 PROMPT_LENS = (4, 8, 16)
@@ -72,7 +73,10 @@ def _all_names(profile):
 def captured():
     """One server, warmed; two clients in closed loops with a pause (so the
     loop also goes idle) while ``/debug/trace`` captures 1 s; scrapes of
-    ``/metrics`` on the same clock around the traffic."""
+    ``/metrics`` on the same clock around the traffic.  Every request
+    finishes inside a decode block of 4 (12 tokens), so each finish
+    re-aligns the device lengths (``finish.realign``), directly or by
+    discarding the overlapped block."""
     cfg = dataclasses.replace(GPTConfig.tiny(), max_seq=64)
     params = TransformerLM(cfg).init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
@@ -81,6 +85,7 @@ def captured():
     engine = ServingEngine(
         cfg, params, PagedConfig(page_size=4, num_pages=64, max_pages_per_seq=16),
         max_slots=3, metrics=EngineMetrics(registry), decode_block=4,
+        spans=SpanRecorder(),
     )
     server = EngineServer(
         engine, host="127.0.0.1", port=0, registry=registry, enable_trace=True,
@@ -115,6 +120,7 @@ def captured():
             "frames_profile": bench_trace.load(bench_trace.find_xplane(frames["trace_dir"])),
             "before": before, "after": after, "elapsed": t1 - t0,
             "debug_profile": json.loads(_get(port, "/debug/profile")),
+            "spans": engine.spans.snapshot(),
         }
         shutil.rmtree(reply["trace_dir"], ignore_errors=True)
         shutil.rmtree(frames["trace_dir"], ignore_errors=True)
@@ -172,6 +178,14 @@ def test_sub_phase_seconds_lie_inside_their_parents(captured):
     assert 0 < grew("dispatch.frontier") <= grew("dispatch")
     # A slot is cleared inside whichever phase ended its request.
     assert 0 < grew("finish.clear_slot") <= grew("sample") + grew("host_gap") + grew("schedule")
+    # The first token's sync nests in prefill (no handoff admission here).
+    assert 0 < grew("prefill.first_token") <= grew("prefill")
+    assert 0 < grew("prefill.chunk") + grew("prefill.graft") + grew("prefill.first_token") <= grew("prefill")
+    for child in ("dispatch.state", "dispatch.enqueue"):
+        assert grew(child) > 0, child
+    assert grew("dispatch.state") + grew("dispatch.enqueue") + grew("dispatch.frontier") <= grew("dispatch")
+    # A realign runs inside the phase that found the finish or discard.
+    assert 0 < grew("finish.realign") <= grew("dispatch") + grew("sample") + grew("host_gap")
 
 
 def test_work_is_counted_where_it_is_timed(captured):
@@ -186,6 +200,12 @@ def test_work_is_counted_where_it_is_timed(captured):
     singles = grew("tpu_engine_decode_dispatches_step_total")
     assert blocks > 0 and singles > 0  # decode_block=4 of 12 tokens, then the tail
     assert blocks + singles >= grew("tpu_engine_steps_total")
+    assert grew("tpu_engine_state_rebuilds_total") > 0
+    # One realign a close: every discarded overlap realigns, and so does
+    # a finish with nothing in flight.
+    realigns = grew("tpu_engine_seq_len_realigns_total")
+    assert realigns >= max(grew("tpu_engine_overlap_discards_total"), 1)
+    assert realigns <= finished + grew("tpu_engine_overlap_discards_total")
 
 
 def test_debug_profile_keeps_what_the_benchmark_reads(captured):
@@ -196,6 +216,11 @@ def test_debug_profile_keeps_what_the_benchmark_reads(captured):
     for phase in (IDLE,) + SUB_PHASES:
         assert snap["phases"][phase]["total_s"] > 0
     assert "trace_overhead" not in snap
+    for phase in ALL_PHASES:
+        assert isinstance(snap["phases"][phase]["cpu_s"], float)
+    assert snap["phases"]["dispatch.enqueue"]["cpu_s"] > 0
+    # The host's own work runs on the loop thread: CPU in the counter.
+    assert captured["after"]["tpu_engine_loop_host_cpu_seconds_total"] > 0
     # The same closes feed /debug/profile and /metrics.
     assert snap["phases"]["prefill.graft"]["total_s"] == pytest.approx(
         captured["after"][_counter("prefill.graft")], rel=0.2)
@@ -287,3 +312,107 @@ def test_annotations_cost_a_flag_test_without_a_capture():
             pass
     per_phase_us = (time.perf_counter() - t0) / 2000 * 1e6
     assert per_phase_us < 100  # a few microseconds; a 14 ms step holds about ten
+
+
+def test_a_served_window_leaves_request_spans_and_no_step_spans(captured):
+    """The ring is for requests: step() records no span of its own (the
+    profiler keeps every step), so a busy loop evicts nothing."""
+    spans = captured["spans"]
+    assert not [s for s in spans if s["trace_id"] == ENGINE_TRACE and s["name"] == "engine.step"]
+    names = {s["name"] for s in spans}
+    assert {"request", "queue", "prefill", "decode"} <= names
+
+
+class _Clock:
+    """A thread clock the test moves by hand."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        return self.t
+
+
+def test_cpu_goes_flat_to_the_innermost_phase_and_skips_the_chips_waits():
+    clock, host_cpu = _Clock(), _Sink()
+    prof = EngineProfiler(host_cpu=host_cpu, thread_clock=clock)
+    prof.begin_step()
+    clock.t += 1.0  # before the first phase: that phase's
+    with prof.phase("prefill"):
+        clock.t += 2.0
+        with prof.phase("prefill.first_token"):
+            clock.t += 4.0
+        clock.t += 8.0
+        with prof.phase("prefill.graft"):
+            clock.t += 16.0
+    clock.t += 32.0  # between top-level phases: the next one's
+    with prof.phase("readback"):
+        clock.t += 64.0
+    with prof.phase("dispatch"):
+        clock.t += 128.0
+        with prof.phase("dispatch.enqueue"):
+            clock.t += 256.0
+    clock.t += 512.0  # step()'s wrap-up: the top-level phase that ran last
+    prof.finish_step(active_slots=1, max_slots=1, queued=0, kv_page_utilization=0.0, tokens=1)
+    clock.t += 1024.0  # between steps: nobody's
+    with prof.phase(IDLE):
+        clock.t += 2048.0
+    cpu = {p: v["cpu_s"] for p, v in prof.snapshot()["phases"].items()}
+    assert cpu["prefill"] == 1 + 2 + 8
+    assert cpu["prefill.first_token"] == 4 and cpu["prefill.graft"] == 16
+    assert cpu["readback"] == 32 + 64
+    assert cpu["dispatch"] == 128 + 512 and cpu["dispatch.enqueue"] == 256
+    assert cpu[IDLE] == 2048
+    assert sum(cpu.values()) == 4095 - 1024
+    # The counter leaves out the waits for the chip (and idle).
+    assert host_cpu.total == 1 + 2 + 8 + 16 + 128 + 256 + 512
+
+
+def test_the_host_cpu_counter_leaves_a_speculative_round_out_whole():
+    clock, host_cpu = _Clock(), _Sink()
+    prof = EngineProfiler(host_cpu=host_cpu, thread_clock=clock)
+    prof.begin_step()
+    with prof.phase("spec_verify"):
+        clock.t += 1.0
+        with prof.phase("finish.clear_slot"):
+            clock.t += 2.0
+    prof.finish_step(active_slots=1, max_slots=1, queued=0, kv_page_utilization=0.0, tokens=1)
+    assert prof.snapshot()["phases"]["finish.clear_slot"]["cpu_s"] == 2
+    assert host_cpu.total == 0
+
+
+def test_cpu_and_wall_partition_the_same_instants():
+    """With the wall clock standing in for the thread clock, each phase's
+    flat CPU is its self time (its seconds less its children's), and the
+    step's CPU sums to its wall."""
+    prof = EngineProfiler(thread_clock=time.perf_counter)
+    prof.begin_step()
+    time.sleep(0.002)
+    with prof.phase("schedule"):
+        with prof.phase("schedule.start_prefill"):
+            time.sleep(0.003)
+        time.sleep(0.001)
+    time.sleep(0.002)
+    with prof.phase("dispatch"):
+        with prof.phase("dispatch.state"):
+            time.sleep(0.001)
+        with prof.phase("dispatch.enqueue"):
+            time.sleep(0.002)
+        with prof.phase("dispatch.frontier"):
+            with prof.phase("finish.clear_slot"):
+                time.sleep(0.001)
+    time.sleep(0.002)
+    wall = prof.finish_step(active_slots=1, max_slots=1, queued=0, kv_page_utilization=0.0, tokens=1)
+    phases = prof.snapshot()["phases"]
+    wall_s = {p: v["total_s"] for p, v in phases.items()}
+    cpu_s = {p: v["cpu_s"] for p, v in phases.items()}
+    children = {
+        "schedule": ["schedule.start_prefill"],
+        "dispatch": ["dispatch.state", "dispatch.enqueue", "dispatch.frontier"],
+        "dispatch.frontier": ["finish.clear_slot"],
+    }
+    for phase in ALL_PHASES:
+        own = wall_s[phase] - sum(wall_s[c] for c in children.get(phase, []))
+        assert cpu_s[phase] == pytest.approx(own, abs=1e-4), phase
+    assert sum(cpu_s.values()) == pytest.approx(wall, abs=1e-4)
+    assert wall_s["schedule"] + wall_s["dispatch"] == pytest.approx(wall, abs=2e-4)

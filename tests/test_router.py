@@ -1114,6 +1114,16 @@ def test_trace_context_propagates_and_roots_replica_tree():
         assert ctx.trace_id == "propagate-1"
         assert ctx.hop == 1 and ctx.attempt == 0
         # The parent span id resolves to a recorded router.attempt span.
+        # (The router records its request root in the handler's finally,
+        # after the reply is written — the client can see the reply
+        # first, so wait for it as for the replica's side below.)
+        assert wait_until(
+            lambda: any(
+                s["name"] == "router.request"
+                for s in router.spans.dump(trace_id="propagate-1")["spans"]
+            ),
+            timeout=5,
+        )
         router_spans = router.spans.dump(trace_id="propagate-1")["spans"]
         by_name = {}
         for s in router_spans:
@@ -1287,6 +1297,15 @@ def test_killed_stream_assembles_one_timeline_zero_gaps():
             lambda: all(
                 r.spans.dump(trace_id="killed-1")["spans"]
                 for r in replicas
+            ),
+            timeout=5,
+        )
+        # So does the router's handler its request root, in its finally
+        # after the stream's last write: wait for it too.
+        assert wait_until(
+            lambda: any(
+                s["name"] == "router.request"
+                for s in router.spans.dump(trace_id="killed-1")["spans"]
             ),
             timeout=5,
         )
