@@ -79,6 +79,7 @@ from ..utils.anomaly import AnomalyMonitor
 from ..utils.flight import FlightRecorder
 from ..utils.platform import device_facts
 from ..utils.spans import SpanRecorder
+from . import mla
 from .engine_profiler import EngineProfiler, in_phase
 from .moe import STATS, few_tokens
 from .transformer import (
@@ -496,6 +497,10 @@ class ServingEngine(
             if metrics:
                 # The series exist from the start: a scrape reads 0, not nothing.
                 self._moe_fold("decode", np.zeros(self._moe_shape, np.int64))
+        # Whether a decode step's latent attentions read the pool through
+        # the latent kernel (ops/latent_attention.py) is a fact of the
+        # compiled program too: the backend's lane, one query a row.
+        self.mla_latent_kernel = cfg.mla is not None and mla.latent_kernel_lane(1)
         # Forensics layer (always on — a production incident cannot ask
         # for instrumentation retroactively, and all three pieces are
         # stdlib-cheap): a bounded flight-recorder black box of typed
@@ -1106,6 +1111,17 @@ class ServingEngine(
             if self.moe_expert_kernel:
                 m.moe_kernel_layer_steps.inc(col["active"])
 
+    def _latent_fold(self, steps: int) -> None:
+        """Count one consumed decode dispatch of ``steps`` steps (a block
+        counts its steps) against every latent attention layer."""
+        m = self.metrics
+        if not m or self.cfg.mla is None:
+            return
+        layer_steps = steps * len(self._layer_names)
+        m.mla_decode_layer_steps.inc(layer_steps)
+        if self.mla_latent_kernel:
+            m.mla_kernel_layer_steps.inc(layer_steps)
+
     def moe_state(self) -> Optional[dict]:
         """The ``moe`` block of ``GET /debug/profile``: the same sums as
         the tpu_engine_moe_* counters, by phase; None for a model without
@@ -1120,6 +1136,7 @@ class ServingEngine(
         if self.cfg.mla is not None:
             # A latent row as the model defines it and as the pool stores it.
             out["latent_row"] = {"width": self.cfg.mla.row_width, "stored": self.cfg.mla.stored_width}
+            out["latent_kernel"] = self.mla_latent_kernel
         for phase, counts in self.moe_counts.items():
             out[phase] = {
                 **{name: int(counts[:, i].sum()) for i, name in enumerate(STATS)},
@@ -1193,6 +1210,7 @@ class ServingEngine(
             "host_gap" if self._inflight is not None else "sample"
         ):
             self._moe_fold("decode", rec.get("moe_stats"))
+            self._latent_fold(rec["T"])
             now = time.monotonic()
             emitted_total = 0
             for s, req in zip(rec["active"], rec["reqs"]):
@@ -1396,6 +1414,7 @@ class ServingEngine(
             "host_gap" if self._inflight is not None else "sample"
         ):
             self._moe_fold("decode", rec.get("moe_stats"))
+            self._latent_fold(rec["T"])
             now = time.monotonic()
             consumed = 0
             for s, req in zip(rec["active"], rec["reqs"]):

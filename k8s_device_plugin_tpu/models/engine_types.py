@@ -331,6 +331,18 @@ class EngineMetrics:
             "a touched expert's weights read once, in place): all of "
             "them on a TPU backend, none elsewhere",
         )
+        self.mla_decode_layer_steps = registry.counter(
+            "tpu_engine_mla_decode_layer_steps_total",
+            "(decode step, latent attention layer) pairs of the consumed "
+            "decode dispatches; a block counts its steps",
+        )
+        self.mla_kernel_layer_steps = registry.counter(
+            "tpu_engine_mla_kernel_layer_steps_total",
+            "Those of tpu_engine_mla_decode_layer_steps_total whose "
+            "attention read the latent pool in place through one kernel "
+            "(ops/latent_attention.py): all of them on a TPU backend, "
+            "none elsewhere",
+        )
         self.decode_dispatches_block = registry.counter(
             "tpu_engine_decode_dispatches_block_total",
             "Decode dispatches that ran a multi-step block program",

@@ -17,8 +17,9 @@ pool row-major.  A 576-wide row is no multiple of 128 lanes: row-major
 tiles would pad it to 640 anyway, so the chip's compact layout would put
 the pages minor, and every decode program and graft would lay the whole
 pool out row-major for its scatter and gather and back.  The pad lanes
-are never read: the absorbed path contracts ``[..., :r_kv + d_r]`` and
-``[..., :r_kv]``.  Two compute paths over one set of parameters:
+reach no contraction: the gathered path contracts ``[..., :r_kv + d_r]``
+and ``[..., :r_kv]``, the latent kernel zeroes them in VMEM.  Two compute
+paths over one set of parameters:
 
 - EXPANDED (``mla.expand``): per-head ``k_n`` and ``v`` through ``W_kvb``,
   then ordinary causal attention.  For a bulk prefill into an empty cache
@@ -30,6 +31,20 @@ are never read: the absorbed path contracts ``[..., :r_kv + d_r]`` and
   value for all heads and is never expanded.  For decode and for every
   append that attends against a cache: expanding 64 slots x 900 cached
   positions a step would cost 60x the step's other FLOPs.
+
+The absorbed path over a PAGED pool has two lanes between the same two
+projections (``q~`` and ``o = o~ W_uv``, XLA einsums in ``mla.absorb``):
+
+- the latent kernel (ops/latent_attention.py, ``latent_decode_attention``):
+  a decode of one query a row on a TPU backend reads each live row of the
+  pool once, in place through the page table;
+- the gather (``paged_gather``, then ``absorbed_attention``): every other
+  case, an append of several queries a row against the pool and every
+  backend but a TPU (the CPU parity environment).  It writes the
+  ``[batch, max_len, W]`` view of every addressable position.
+
+The lane is read off what the program can observe (``latent_kernel_lane``:
+the backend and the append's length), never set.
 
 Three cache forms, as ``CausalSelfAttention`` has them: none; dense
 ``cached_latent [batch, max_seq, W]`` with ``cache_index`` (the engine's
@@ -50,6 +65,9 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from ..ops.expert_ffn import on_kernel_lane
+from ..ops.latent_attention import latent_decode_attention
 
 # Scores of one absorbed append are [rows, heads, q_len, cache] float32, and
 # the latent-space queries and outputs [rows, q_len, heads, r_kv]: rows
@@ -126,6 +144,29 @@ def absorbed_attention(q_n, q_r, latent, positions, kv_b, sm_scale: float):
     )
 
 
+def latent_kernel_lane(q_len: int) -> bool:
+    """Whether a paged append of ``q_len`` queries a row reads the pool
+    through the latent kernel: one query a row (a decode step) on a TPU
+    backend.  A fact of the program, known before it is compiled."""
+    return q_len == 1 and on_kernel_lane()
+
+
+def absorbed_decode(q_n, q_r, pool, table, positions, kv_b, sm_scale: float):
+    """``absorbed_attention`` of one query a row read in place from a paged
+    pool: q_n [batch, 1, heads, d_n], q_r [batch, 1, heads, d_r] (rotated);
+    pool [num_pages, page_size, W]; table [batch, max_pages]; the query at
+    ``positions[b, 0]`` sees rows ``<= position``.  Returns [batch, 1,
+    heads, d_v].  The projections stay einsums; the kernel computes ``o~``
+    from the rows where they live."""
+    r_kv, d_n = kv_b.shape[0], q_n.shape[-1]
+    q_lat = jnp.concatenate([jnp.einsum("bhd,chd->bhc", q_n[:, 0], kv_b[..., :d_n]), q_r[:, 0]], axis=-1)
+    key_width = q_lat.shape[-1]
+    q_lat = jnp.pad(q_lat, ((0, 0), (0, 0), (0, pool.shape[-1] - key_width)))
+    lengths = jnp.clip(positions[:, 0] + 1, 1, table.shape[1] * pool.shape[1])
+    o_lat = latent_decode_attention(q_lat, pool, table, lengths, key_width=key_width, kv_rank=r_kv, sm_scale=sm_scale)
+    return jnp.einsum("bhc,chd->bhd", o_lat, kv_b[..., d_n:])[:, None]
+
+
 class LatentAttention(nn.Module):
     """Latent attention of one block; ``config`` is the model's
     ``GPTConfig`` with ``config.mla`` an ``MlaConfig``.  Same call and
@@ -199,8 +240,10 @@ class LatentAttention(nn.Module):
             pg = cfg.paged
             if pg.use_kernel:
                 raise ValueError(
-                    "use_kernel is not supported with latent attention (cfg.mla): the "
-                    "paged-attention kernel (ops/paged_attention.py) takes key and value pools"
+                    "use_kernel is not supported with latent attention (cfg.mla): the K/V "
+                    "paged-attention kernel (ops/paged_attention.py) takes key and value "
+                    "pools; latent attention's decode has a kernel lane of its own "
+                    "(ops/latent_attention.py), taken on a TPU backend"
                 )
             pool = self.variable(
                 "cache", "pool_latent", jnp.zeros, (pg.num_pages, pg.page_size, mc.stored_width), row.dtype
@@ -214,9 +257,13 @@ class LatentAttention(nn.Module):
             page = table.value[jnp.arange(batch)[:, None], offs // pg.page_size]
             pool.value = pool.value.at[page, offs % pg.page_size].set(row)
             lens.value = cur + q_len
-            with jax.named_scope("paged_gather"):
-                rows = pool.value[table.value].reshape(batch, pg.max_len, mc.stored_width)
-            attn = absorbed(rows)
+            if latent_kernel_lane(q_len):
+                with jax.named_scope("mla.absorb"):
+                    attn = absorbed_decode(q_n, q_r, pool.value, table.value, positions, kv_b, sm_scale)
+            else:
+                with jax.named_scope("paged_gather"):
+                    rows = pool.value[table.value].reshape(batch, pg.max_len, mc.stored_width)
+                attn = absorbed(rows)
         elif self.decode:
             idx = self.variable("cache", "cache_index", lambda: jnp.zeros((), jnp.int32))
             cached = self.variable(

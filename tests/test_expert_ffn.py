@@ -4,9 +4,11 @@ through the Pallas interpreter against the XLA lane (the ``fori_loop`` of
 expert layer at a toy size in bfloat16; then the kernel compiled for a
 described v5e at LongCat-Flash's widths, which the interpreter cannot
 refuse (tiling, VMEM), and the decode programs of a latent paged cache
-compiled for it, whose layouts only the chip's compiler chooses.  Every
-compile for the described chip lives in this file: one worker loads the
-TPU's library for all of them."""
+compiled for it, whose layouts only the chip's compiler chooses; the
+latent decode kernel (ops/latent_attention.py) too, alone at the same
+widths and inside those decode programs.  Every compile for the described
+chip lives in this file: one worker loads the TPU's library for all of
+them."""
 
 import dataclasses
 import functools
@@ -20,10 +22,11 @@ import pytest
 
 from chipbench import families
 from chipbench import weights
-from k8s_device_plugin_tpu.models import moe
+from k8s_device_plugin_tpu.models import mla, moe
 from k8s_device_plugin_tpu.models.engine_sampling import build_block_fn, build_step_fn
 from k8s_device_plugin_tpu.models.transformer import GPTConfig, TransformerLM, decode_cache_spec
 from k8s_device_plugin_tpu.ops import expert_ffn as kernel
+from k8s_device_plugin_tpu.ops import latent_attention
 from k8s_device_plugin_tpu.ops import tuning
 
 with open(os.path.join(os.path.dirname(__file__), "chipbench", "data", "tiny-longcat-flash.json")) as f:
@@ -225,6 +228,25 @@ def test_the_kernel_compiles_for_a_v5e_at_longcat_flash_widths(one_chip, rows):
     assert not [ln for ln in text.splitlines() if " copy(" in ln and ("[16,6144,2048]" in ln or "[16,2048,6144]" in ln)]
 
 
+def test_the_latent_kernel_compiles_for_a_v5e_at_longcat_flash_widths(one_chip):
+    """64 slots of 64 heads over 6,144 pages of 16 rows 640 wide, a table
+    of 96 pages, in bfloat16: Mosaic takes it, and the pool reaches the
+    kernel as it is, with no copy in front of it."""
+    shape = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+
+    def call(q_lat, pool, table, lengths):
+        return latent_attention.latent_decode_attention(
+            q_lat, pool, table, lengths, key_width=576, kv_rank=512, sm_scale=192 ** -0.5, interpret=False
+        )
+
+    text = jax.jit(call).lower(
+        shape((64, 64, 640), jnp.bfloat16), shape((6144, 16, 640), jnp.bfloat16),
+        shape((64, 96), jnp.int32), shape((64,), jnp.int32),
+    ).compile().as_text()
+    assert sum("custom-call" in ln and "latent_decode_attention" in ln for ln in text.splitlines()) == 1
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and "[6144,16,640]" in ln]
+
+
 @pytest.mark.parametrize("program", ["single step", "decode block"])
 def test_the_latent_pool_is_never_laid_out_anew(one_chip, program):
     """The tiny LongCat-Flash preset in bfloat16 on 256 pages of 16, its
@@ -233,9 +255,31 @@ def test_the_latent_pool_is_never_laid_out_anew(one_chip, program):
     24 wide makes the chip's compact layout put the pages minor, and then
     each program lays every pool out row-major for its scatter and gather
     and back again.)"""
+    compile_latent_decode(one_chip, program)
+
+
+@pytest.mark.parametrize("program", ["single step", "decode block"])
+def test_the_decode_programs_on_the_latent_kernel_gather_no_view(one_chip, program, monkeypatch):
+    """The same programs on the latent kernel's lane (what a TPU backend
+    takes): one kernel call an attention, no gathered view of the pages
+    and still no copy of a pool.  On 4,096 pages: a pool of 256 pages (1
+    MB) is small enough that the compiler prefetches it into VMEM for the
+    kernel, which the chip's 126 MB pools never are."""
+    monkeypatch.setattr(mla, "on_kernel_lane", lambda: True)
+    monkeypatch.setattr(mla, "latent_decode_attention", functools.partial(mla.latent_decode_attention, interpret=False))
+    text, cfg, paged, slots = compile_latent_decode(one_chip, program, num_pages=4096)
+    calls = [ln for ln in text.splitlines() if "custom-call" in ln and "latent_decode_attention" in ln]
+    assert len(calls) == cfg.num_layers
+    view = f"[{slots},{paged.max_pages_per_seq},{paged.page_size},{cfg.mla.stored_width}]"
+    assert not [ln for ln in text.splitlines() if view in ln]
+
+
+def compile_latent_decode(one_chip, program, num_pages=256):
+    """Compile the tiny preset's decode program for a v5e and check that
+    the pools come in row-major and no program copies one."""
     family = families.load("longcat_flash")
     model_conf = {**MODEL, "torch_dtype": "bfloat16"}
-    cfg, paged = family.build(model_conf, {"page_size": 16, "num_pages": 256, "max_pages_per_seq": 8})
+    cfg, paged = family.build(model_conf, {"page_size": 16, "num_pages": num_pages, "max_pages_per_seq": 8})
     model = TransformerLM(dataclasses.replace(cfg, paged=paged), decode=True)
     slots = 4
     place = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)  # noqa: E731
@@ -264,3 +308,4 @@ def test_the_latent_pool_is_never_laid_out_anew(one_chip, program):
         if shape and int(np.prod([int(d) for d in shape.split(",")])) in pool_sized:
             copies.append(line.strip()[:160])
     assert not copies, copies
+    return text, cfg, paged, slots

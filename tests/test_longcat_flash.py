@@ -25,6 +25,7 @@ from chipbench.reference import longcat_flash as ref
 from k8s_device_plugin_tpu.models import mla, moe
 from k8s_device_plugin_tpu.models.engine import EngineMetrics, ServingEngine
 from k8s_device_plugin_tpu.models.transformer import TransformerLM, decode_cache_spec
+from k8s_device_plugin_tpu.ops import latent_attention
 from k8s_device_plugin_tpu.utils.metrics import MetricsRegistry
 
 SEED = 13
@@ -99,6 +100,21 @@ def streams(served):
     return eng, registry, {n: {"prompt": prompts[n], "tokens": list(r.tokens)} for n, r in zip(LENGTHS, done)}
 
 
+@pytest.fixture(scope="module")
+def kernel_streams(served):
+    """The same jobs served by an engine on the latent kernel's lane (said
+    to be on a TPU; the kernel through the Pallas interpreter, blocks of
+    two pages so a slot's walk crosses blocks)."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(mla, "on_kernel_lane", lambda: True)
+        mp.setattr(latent_attention, "PAGES_PER_BLOCK", 2)
+        registry = MetricsRegistry()
+        eng = make_engine(served, metrics=EngineMetrics(registry))
+        prompts = {n: prompt_of(n) for n in LENGTHS}
+        done = eng.run([(prompts[n], NEW) for n in LENGTHS])
+    return eng, registry, {n: {"prompt": prompts[n], "tokens": list(r.tokens)} for n, r in zip(LENGTHS, done)}
+
+
 # ------------------------------------------------- (a) the served path ----
 
 
@@ -136,6 +152,15 @@ def test_served_tokens_are_the_references_first(streams, plen):
     assert worst_gap([cases[plen]]) < 1e-3
 
 
+@pytest.mark.parametrize("plen", LENGTHS)
+def test_served_tokens_on_the_latent_kernel_are_the_references_first(kernel_streams, plen):
+    """As above, with every decode step's latent attention read in place
+    from the pool by the latent kernel."""
+    _, _, cases = kernel_streams
+    assert len(cases[plen]["tokens"]) == NEW
+    assert worst_gap([cases[plen]]) < 1e-3
+
+
 @pytest.mark.parametrize("part", ["experts", "attention_1"])
 def test_the_comparison_sees_every_part(streams, part):
     """With the expert layer, or a pair's second attention, dropped from
@@ -148,6 +173,18 @@ def test_paged_decode_logits_are_the_full_forwards(served):
     """Teacher-forced through the paged latent cache a token at a time,
     after a two-chunk cached prefill: the logits of every position agree
     with the cache-less forward."""
+    assert_paged_decode_is_the_full_forward(served)
+
+
+def test_paged_decode_logits_on_the_latent_kernel_are_the_full_forwards(served, monkeypatch):
+    """As above on the latent kernel's lane: the two chunks of eight still
+    gather, every step of one token reads the pool through the kernel."""
+    monkeypatch.setattr(mla, "on_kernel_lane", lambda: True)
+    monkeypatch.setattr(latent_attention, "PAGES_PER_BLOCK", 2)
+    assert_paged_decode_is_the_full_forward(served)
+
+
+def assert_paged_decode_is_the_full_forward(served):
     cfg, paged, params = served
     ids = jnp.asarray([prompt_of(22), prompt_of(22, salt=1)], jnp.int32)
     full = TransformerLM(cfg).apply({"params": params}, ids)
@@ -408,6 +445,23 @@ def test_the_kernels_counter_follows_the_lane_of_the_compiled_program(served, st
     assert state["expert_kernel"] is True and state["decode"]["active"] > 0 and state["decode"]["dropped"] == 0
     assert series(registry, "tpu_engine_moe_kernel_layer_steps_total") == state["decode"]["active"]
     assert series(registry, "tpu_engine_moe_decode_layer_steps_total") == state["decode"]["active"]
+
+
+def test_the_latent_kernels_counter_follows_the_lane_of_the_compiled_program(streams, kernel_streams):
+    """On the kernel's lane every (decode step, latent attention) pair is
+    counted as the kernel's, the profile says ``latent_kernel``, and the
+    served tokens are the gather lane's; on the gather lane (this backend)
+    the kernel's counter exists and stays 0."""
+    eng, registry, cases = kernel_streams
+    assert [c["tokens"] for c in cases.values()] == [c["tokens"] for c in streams[2].values()]
+    steps = series(registry, "tpu_engine_mla_decode_layer_steps_total")
+    assert steps > 0 and steps % len(eng._layer_names) == 0
+    assert series(registry, "tpu_engine_mla_kernel_layer_steps_total") == steps
+    assert eng.moe_state()["latent_kernel"] is True
+    gather_eng, gather_registry, _ = streams
+    assert gather_eng.moe_state()["latent_kernel"] is False
+    assert series(gather_registry, "tpu_engine_mla_decode_layer_steps_total") > 0
+    assert series(gather_registry, "tpu_engine_mla_kernel_layer_steps_total") == 0
 
 
 def test_a_shared_prefix_shares_latent_pages(served, streams):
