@@ -79,7 +79,7 @@ from ..utils.anomaly import AnomalyMonitor
 from ..utils.flight import FlightRecorder
 from ..utils.platform import device_facts
 from ..utils.spans import SpanRecorder
-from . import mla
+from . import kda, mla
 from .engine_profiler import EngineProfiler, in_phase
 from .moe import STATS, few_tokens
 from .transformer import (
@@ -274,8 +274,9 @@ class ServingEngine(
         model = TransformerLM(self.cfg, decode=True)
         spec = decode_cache_spec(model, max_slots)
         self.cache = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), spec)
-        # Per-slot recurrent state beside the pages (a mixer's
-        # ``slot_*`` leaves, models/ssm.py; 0 for a model without one).
+        # Per-slot recurrent state beside the pages (a mixer's or a
+        # linear attention's ``slot_*`` leaves, models/ssm.py and
+        # models/kda.py; 0 for a model without one).
         # The compiled cache writers carry it through graft and teardown
         # (engine_paging.py); every path that skips or reorders the work
         # that BUILDS it refuses below or falls back (restore-resume:
@@ -287,13 +288,13 @@ class ServingEngine(
         if self.slot_state_bytes and spec_gamma > 0:
             raise ValueError(
                 "spec_gamma > 0 is not supported on a model with per-slot "
-                "recurrent state (cfg.mixer): a rejected draft would have "
-                "to roll the state back, and the round programs do not"
+                "recurrent state (cfg.mixer, cfg.kda): a rejected draft would "
+                "have to roll the state back, and the round programs do not"
             )
         if self.slot_state_bytes and self.tp_size > 1:
             raise ValueError(
                 f"tp={self.tp_size} is not supported on a model with "
-                "per-slot recurrent state (cfg.mixer): the sharding "
+                "per-slot recurrent state (cfg.mixer, cfg.kda): the sharding "
                 "contract (parallel/serving.py) has no rule for slot_* leaves"
             )
         if mesh is not None:
@@ -302,7 +303,16 @@ class ServingEngine(
             self.cache = jax.device_put(
                 self.cache, cache_sharding(self.cache, mesh, tp_axis)
             )
-        self._layer_names = [f"layer_{i}" for i in range(cfg.num_layers)]
+        # The layers that hold pages (an ``attn`` subtree): every walker of
+        # pools, tables and lengths goes by these.  A linear-attention
+        # layer (models/kda.py) holds per-slot state and no pages.
+        self._layer_names = [f"layer_{i}" for i in range(cfg.num_layers) if not cfg.linear_layer(i)]
+        if not self._layer_names:
+            raise ValueError(
+                "the paged engine needs one layer with attention: cfg.kda with no "
+                "attention_layers holds no pages for the scheduler to allocate"
+            )
+        self._linear_layers = cfg.num_layers - len(self._layer_names)
 
         # Single-token decode steps are built lazily per (filtered,
         # want_lp) — like _block_fn — so the common greedy/temperature
@@ -486,7 +496,7 @@ class ServingEngine(
         # expert.  None for a model without them.
         self._moe_shape = None
         if cfg.moe is not None:
-            self._moe_shape = (cfg.num_layers // 2, cfg.moe.stats_width)
+            self._moe_shape = (cfg.expert_layers, cfg.moe.stats_width)
             # Whether a decode step's experts run the grouped-FFN kernel
             # is a fact of the compiled program: the backend's lane and
             # the step's row count (one row a slot).
@@ -501,6 +511,9 @@ class ServingEngine(
         # the latent kernel (ops/latent_attention.py) is a fact of the
         # compiled program too: the backend's lane, one query a row.
         self.mla_latent_kernel = cfg.mla is not None and mla.latent_kernel_lane(1)
+        # And whether a decode step's linear attentions run the recurrence
+        # kernel (ops/kda_decode.py): the same facts.
+        self.kda_kernel = bool(self._linear_layers) and kda.kernel_lane(1)
         # Forensics layer (always on — a production incident cannot ask
         # for instrumentation retroactively, and all three pieces are
         # stdlib-cheap): a bounded flight-recorder black box of typed
@@ -1111,16 +1124,34 @@ class ServingEngine(
             if self.moe_expert_kernel:
                 m.moe_kernel_layer_steps.inc(col["active"])
 
-    def _latent_fold(self, steps: int) -> None:
+    def _layer_steps_fold(self, steps: int) -> None:
         """Count one consumed decode dispatch of ``steps`` steps (a block
-        counts its steps) against every latent attention layer."""
+        counts its steps) against every latent attention layer, and every
+        linear-attention layer."""
         m = self.metrics
-        if not m or self.cfg.mla is None:
+        if not m:
             return
-        layer_steps = steps * len(self._layer_names)
-        m.mla_decode_layer_steps.inc(layer_steps)
-        if self.mla_latent_kernel:
-            m.mla_kernel_layer_steps.inc(layer_steps)
+        if self.cfg.mla is not None:
+            layer_steps = steps * len(self._layer_names)
+            m.mla_decode_layer_steps.inc(layer_steps)
+            if self.mla_latent_kernel:
+                m.mla_kernel_layer_steps.inc(layer_steps)
+        if self._linear_layers:
+            layer_steps = steps * self._linear_layers
+            m.kda_decode_layer_steps.inc(layer_steps)
+            if self.kda_kernel:
+                m.kda_kernel_layer_steps.inc(layer_steps)
+
+    def kda_state(self) -> Optional[dict]:
+        """The ``kda`` block of ``GET /debug/profile``: the linear-attention
+        layers, whether their decode steps run the recurrence kernel, and
+        the slot state's bytes; None for a model without them."""
+        if not self._linear_layers:
+            return None
+        return {
+            "layers": self._linear_layers, "kernel": self.kda_kernel,
+            "slot_state_bytes": self.slot_state_bytes, "cache_bytes_per_token": self.cache_bytes_per_token,
+        }
 
     def moe_state(self) -> Optional[dict]:
         """The ``moe`` block of ``GET /debug/profile``: the same sums as
@@ -1210,7 +1241,7 @@ class ServingEngine(
             "host_gap" if self._inflight is not None else "sample"
         ):
             self._moe_fold("decode", rec.get("moe_stats"))
-            self._latent_fold(rec["T"])
+            self._layer_steps_fold(rec["T"])
             now = time.monotonic()
             emitted_total = 0
             for s, req in zip(rec["active"], rec["reqs"]):
@@ -1414,7 +1445,7 @@ class ServingEngine(
             "host_gap" if self._inflight is not None else "sample"
         ):
             self._moe_fold("decode", rec.get("moe_stats"))
-            self._latent_fold(rec["T"])
+            self._layer_steps_fold(rec["T"])
             now = time.monotonic()
             consumed = 0
             for s, req in zip(rec["active"], rec["reqs"]):

@@ -152,10 +152,10 @@ class HandoffMixin:
             raise ValueError(f"role must be one of {ROLES}, got {role!r}")
         if role != "unified" and self.slot_state_bytes:
             # A handoff ships pages; the decode side would skip the
-            # chunks that build the recurrent state (models/ssm.py).
+            # chunks that build the recurrent state (models/ssm.py, kda.py).
             raise ValueError(
                 f"role={role!r} is not supported on a model with per-slot "
-                "recurrent state (cfg.mixer): a prefill handoff carries "
+                "recurrent state (cfg.mixer, cfg.kda): a prefill handoff carries "
                 "K/V pages, not the state the skipped chunks would build"
             )
         if role != "unified" and self.cfg.mla is not None:

@@ -475,7 +475,7 @@ class KVCacheMixin:
             return False
         if self.slot_state_bytes:
             # The snapshot holds K/V rows and scalars, not the slot's
-            # recurrent state (models/ssm.py): skipping prefill would
+            # recurrent state (models/ssm.py, kda.py): skipping prefill would
             # resume from zeros.  Recompute-resume rebuilds the state
             # (its retained pages still shrink the graft); counted.
             self._kv_drop_snapshot(req.rid)

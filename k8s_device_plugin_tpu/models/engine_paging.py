@@ -30,7 +30,10 @@ from .engine_profiler import in_phase
 # Two kinds of cached unit: ``pool_*`` leaves under ``attn`` are pages
 # keyed by a table (what a sequence holds grows with its length);
 # ``slot_*`` leaves (``[slots, ...]``, any subtree but ``attn``: a
-# mixer's recurrent state, models/ssm.py) are one fixed-size row a slot.
+# mixer's recurrent state, models/ssm.py, or a linear attention's,
+# models/kda.py) are one fixed-size row a slot.  A layer may hold only
+# the second kind: a linear-attention layer has no ``attn`` subtree, no
+# pages, no table and no lengths, and every walker here passes it by.
 # A graft copies the prefilled row into the slot, a slot-row write
 # without a graft leaves the slot with no state: zeros.
 
@@ -55,7 +58,7 @@ def cache_bytes_per_token(cache: dict, latent_row: Optional[int] = None) -> tupl
     writes of a position is ``row``."""
     row = pad = 0
     for layer in cache.values():
-        for name, leaf in layer["attn"].items():
+        for name, leaf in layer.get("attn", {}).items():
             if not name.startswith("pool_"):
                 continue
             stored = leaf.size // (leaf.shape[0] * leaf.shape[1])
@@ -83,11 +86,13 @@ def _write_slot_row(cache, chain, slot, length, row, derive_tables: bool, dense=
     prefill cache's same leaf, or zeros where no prefill is given."""
     out = {}
     for name, layer in cache.items():
-        att = layer["attn"]
-        new_att = {**att, "seq_lens": att["seq_lens"].at[slot].set(length)}
-        if not derive_tables:
-            new_att["page_table"] = att["page_table"].at[slot].set(row)
-        new_layer = {**layer, "attn": new_att}
+        new_layer = dict(layer)
+        if "attn" in layer:
+            att = layer["attn"]
+            new_att = {**att, "seq_lens": att["seq_lens"].at[slot].set(length)}
+            if not derive_tables:
+                new_att["page_table"] = att["page_table"].at[slot].set(row)
+            new_layer["attn"] = new_att
         for sub, leaf in slot_leaves(layer):
             state = 0
             if dense is not None:
@@ -148,6 +153,9 @@ def build_graft_writer(derive_tables: bool):
 
         grafted = {}
         for name, layer in cache.items():
+            if "attn" not in layer:
+                grafted[name] = layer  # state alone: _write_slot_row copies it
+                continue
             att, src = layer["attn"], dense[name]["attn"]
             pools = {
                 pool: write_pages(att[pool], src["cached_" + pool[len("pool_"):]])

@@ -91,7 +91,7 @@ def _derived_tables(cache, chain, pos, page_size):
         0,
     )
     return {
-        name: {**layer, "attn": {**layer["attn"], "page_table": table}}
+        name: {**layer, "attn": {**layer["attn"], "page_table": table}} if "attn" in layer else layer
         for name, layer in cache.items()
     }
 
@@ -279,7 +279,7 @@ def build_block_fn(model, T: int, filtered: bool, want_lp: bool,
 
         stats0 = []
         if moe is not None:
-            stats0 = [jnp.zeros((model.config.num_layers // 2, moe.stats_width), jnp.int32)]
+            stats0 = [jnp.zeros((model.config.expert_layers, moe.stats_width), jnp.int32)]
         (cache, last_tok, last_pos, *stats), ys = jax.lax.scan(
             body, (cache, tokens, positions, *stats0), jax.random.split(sub, T)
         )

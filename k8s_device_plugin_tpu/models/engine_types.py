@@ -257,8 +257,8 @@ class EngineMetrics:
         )
         self.slot_state_bytes = registry.gauge(
             "tpu_engine_slot_state_bytes",
-            "Device bytes of all per-slot cache leaves (a mixer's "
-            "recurrent state and convolution tail, every layer, every "
+            "Device bytes of all per-slot cache leaves (a mixer's or a "
+            "linear attention's recurrent state and convolution tail, every layer, every "
             "slot; 0 for a model without one).  Set once at engine "
             "construction",
         )
@@ -342,6 +342,18 @@ class EngineMetrics:
             "attention read the latent pool in place through one kernel "
             "(ops/latent_attention.py): all of them on a TPU backend, "
             "none elsewhere",
+        )
+        self.kda_decode_layer_steps = registry.counter(
+            "tpu_engine_kda_decode_layer_steps_total",
+            "(decode step, linear-attention layer) pairs of the consumed "
+            "decode dispatches; a block counts its steps",
+        )
+        self.kda_kernel_layer_steps = registry.counter(
+            "tpu_engine_kda_kernel_layer_steps_total",
+            "Those of tpu_engine_kda_decode_layer_steps_total whose "
+            "recurrence read and wrote the slot state once, in place, "
+            "through one kernel (ops/kda_decode.py): all of them on a TPU "
+            "backend, none elsewhere",
         )
         self.decode_dispatches_block = registry.counter(
             "tpu_engine_decode_dispatches_block_total",

@@ -1620,7 +1620,8 @@ class EngineServer:
                     # it stays as open as /metrics.
                     # Routing counts by phase; absent for a model
                     # without expert layers.
-                    moe = server.engine.moe_state()
+                    # The linear-attention layers likewise.
+                    moe, kda = server.engine.moe_state(), server.engine.kda_state()
                     self._reply(
                         200,
                         {
@@ -1628,6 +1629,7 @@ class EngineServer:
                             "cache_writes": server.engine.cache_writes_state(),
                             "prefill_cache": server.engine.prefill_cache_state(),
                             **({} if moe is None else {"moe": moe}),
+                            **({} if kda is None else {"kda": kda}),
                         },
                     )
                 elif path == "/debug/disagg":

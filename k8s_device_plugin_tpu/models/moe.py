@@ -10,6 +10,13 @@ chosen, scaling ``s``)::
     w_i = s * p_i for i chosen                          not renormalised
     M(u) = sum_{i < E chosen} w_i down_i(silu(gate_i u) * up_i u) + sum_{i >= E chosen} w_i u
 
+The same layer serves a plain pre-norm block's feed-forward (Solar Open
+2's: ``MoeConfig.score`` "sigmoid", ``s = sigmoid(float32(u) . float32(W_r))``
+each on its own, the chosen weights renormalised to sum to 1 with
+``norm_topk``, no identity experts, and a shared SwiGLU expert of
+``shared_size`` computed for every token, ``moe.shared``).  A config that
+sets none of these fields traces the operations it traced before.
+
 **The chip's share.**  The layer is told which routed experts it HOLDS
 (``MoeConfig.held``: with 512 experts over 32 chips, expert-parallel rank 0
 holds 0..15).  It routes over all ``E + Z``, computes the chosen held
@@ -86,9 +93,9 @@ STATS = ("held", "identity", "absent", "dropped", "touched", "active")
 @dataclass(frozen=True)
 class MoeConfig:
     """The expert layer of a shortcut-connected double-layer (the topology
-    is ``models/transformer.py`` ``ShortcutBlock``'s).  ``n_routed`` and
-    ``n_zero`` are the PUBLISHED counts: the router is ``n_routed + n_zero``
-    wide whatever ``held`` says."""
+    is ``models/transformer.py`` ``ShortcutBlock``'s) or of a plain
+    pre-norm block.  ``n_routed`` and ``n_zero`` are the PUBLISHED counts:
+    the router is ``n_routed + n_zero`` wide whatever ``held`` says."""
 
     n_routed: int = 512
     n_zero: int = 256
@@ -96,6 +103,13 @@ class MoeConfig:
     scaling: float = 6.0
     expert_size: int = 2048
     held: tuple[int, ...] = tuple(range(16))
+    # A shared SwiGLU expert of this width, computed for every token where
+    # the token is (0 = none).
+    shared_size: int = 0
+    # The router's scores: "softmax" over all experts, or "sigmoid" each.
+    score: str = "softmax"
+    # Chosen weights renormalised to sum to 1 (before ``scaling``).
+    norm_topk: bool = False
 
     def __post_init__(self):
         if not self.held or len(set(self.held)) != len(self.held):
@@ -104,6 +118,8 @@ class MoeConfig:
             raise ValueError(f"held experts {self.held} are not among the {self.n_routed} routed")
         if not 1 <= self.top_k <= self.n_routed + self.n_zero:
             raise ValueError(f"top_k {self.top_k} of {self.n_routed + self.n_zero} experts")
+        if self.score not in ("softmax", "sigmoid"):
+            raise ValueError(f"score must be softmax|sigmoid, got {self.score!r}")
 
     @property
     def width(self) -> int:
@@ -162,6 +178,21 @@ def _many_token_experts(u, weight_of, counts, w_gate, w_up, w_down):
     )
 
 
+def _shared_expert(mod, u, dtype, f):
+    """The shared SwiGLU expert over every token [n, h]: operands in
+    ``dtype``, float32 accumulation, the activation cast to ``dtype``
+    before the down projection (ops/expert_ffn.py's precision); float32
+    out."""
+    h, f32 = u.shape[-1], jnp.float32
+    init = nn.initializers.normal(h ** -0.5)
+    gate = mod.param("shared_gate", init, (h, f)).astype(dtype)
+    up = mod.param("shared_up", init, (h, f)).astype(dtype)
+    down = mod.param("shared_down", nn.initializers.normal(f ** -0.5), (f, h)).astype(dtype)
+    x = u.astype(dtype)
+    act = jax.nn.silu(jnp.dot(x, gate, preferred_element_type=f32)) * jnp.dot(x, up, preferred_element_type=f32)
+    return jnp.dot(act.astype(dtype), down, preferred_element_type=f32)
+
+
 class ExpertLayer(nn.Module):
     """``config`` is the model's ``GPTConfig`` (``config.moe`` a
     ``MoeConfig``).  Parameters: ``router`` [h, E + Z], ``select_bias``
@@ -192,9 +223,12 @@ class ExpertLayer(nn.Module):
 
         with jax.named_scope("moe.route"):
             logits = jnp.dot(u.astype(f32), router.astype(f32), precision=hi)
-            p = jax.nn.softmax(logits, axis=-1)
+            p = jax.nn.sigmoid(logits) if mc.score == "sigmoid" else jax.nn.softmax(logits, axis=-1)
             _, ids = jax.lax.top_k(p + bias.astype(f32), mc.top_k)  # [n, k]
-            w = jnp.take_along_axis(p, ids, axis=-1) * mc.scaling
+            w = jnp.take_along_axis(p, ids, axis=-1)
+            if mc.norm_topk:
+                w = w / jnp.sum(w, axis=-1, keepdims=True)
+            w = w * mc.scaling
             w = jnp.where(valid[:, None], w, 0.0)
             local_of = np.full((mc.width,), -1, np.int32)
             local_of[list(mc.held)] = np.arange(n_held)
@@ -212,8 +246,12 @@ class ExpertLayer(nn.Module):
                 out, computed = expert_ffn(u, weight_of, counts, w_gate, w_up, w_down)
             else:
                 out, computed = _many_token_experts(u, weight_of, counts, w_gate, w_up, w_down)
-        with jax.named_scope("moe.identity"):
-            out = out + zero_w[:, None] * u.astype(f32)
+        if mc.n_zero:
+            with jax.named_scope("moe.identity"):
+                out = out + zero_w[:, None] * u.astype(f32)
+        if mc.shared_size:
+            with jax.named_scope("moe.shared"):
+                out = out + _shared_expert(self, u, cfg.dtype, mc.shared_size)
 
         held_n, zero_n = jnp.sum(counts), jnp.sum(is_zero, dtype=jnp.int32)
         stats = jnp.concatenate([

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 from ..ops.flash_attention import flash_attention, mha_reference
 from ..ops.quant import Int8DenseGeneral, dequantize_kv, quantize_kv_pair
+from .kda import KdaConfig, KdaMixer
 from .mla import LatentAttention, MlaConfig
 from .moe import ExpertLayer, MoeConfig
 from .ssm import Mamba2Mixer, MambaConfig
@@ -206,31 +207,65 @@ class GPTConfig:
     # ``num_kv_heads`` and ``head_dim`` are then unused.  Comes with
     # ``moe``: one block has both.
     mla: Optional[MlaConfig] = None
-    # Shortcut-connected expert layers (models/moe.py, ``ShortcutBlock``):
-    # the decoder is pairs of blocks, ``num_layers`` counts the blocks (one
-    # attention and one cache-tree layer each, so an even number), and the
-    # expert layer of a pair reads the first block's post-attention norm and
-    # joins the residual at the END of the second: it runs beside a whole
-    # attention and feed-forward.
+    # Expert layers (models/moe.py).  With ``mla``: shortcut-connected
+    # (``ShortcutBlock``): the decoder is pairs of blocks, ``num_layers``
+    # counts the blocks (one attention and one cache-tree layer each, so an
+    # even number), and the expert layer of a pair reads the first block's
+    # post-attention norm and joins the residual at the END of the second:
+    # it runs beside a whole attention and feed-forward.  Without: the
+    # feed-forward of EVERY pre-norm block is an expert layer.
     moe: Optional[MoeConfig] = None
+    # Linear attention (models/kda.py) in place of attention in every
+    # layer that ``attention_layers`` does not name: a layer of that kind
+    # holds per-slot state (``slot_kda*`` leaves) and no pages.
+    kda: Optional[KdaConfig] = None
+    # The layers with softmax attention, where ``kda`` is set.
+    attention_layers: tuple[int, ...] = ()
+    # Rotary embeddings on queries and keys; False = attention without
+    # positions (NoPE).
+    use_rope: bool = True
+    # A sigmoid output gate on attention: o * sigmoid(x W_gate), elementwise
+    # over the heads' outputs, before the output projection.
+    attn_gate: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.hidden_size // self.num_heads)
         if self.logits_to_keep not in (None, 1):
             raise ValueError(f"logits_to_keep must be None or 1, got {self.logits_to_keep}")
-        if (self.mla is None) != (self.moe is None):
+        if self.mla is not None and self.moe is None:
             raise ValueError(
-                "cfg.mla and cfg.moe come together: ShortcutBlock is the one block "
-                "with latent attention and the one with an expert layer"
+                "cfg.mla comes with cfg.moe: ShortcutBlock is the one block "
+                "with latent attention"
             )
-        if self.moe is not None and self.num_layers % 2:
+        if self.mla is not None and self.num_layers % 2:
             raise ValueError(
                 f"num_layers {self.num_layers} must be even with cfg.moe: it counts "
                 "the blocks of shortcut-connected pairs"
             )
         if self.moe is not None and self.mixer is not None:
-            raise ValueError("cfg.moe (ShortcutBlock) has no mixer beside its attention")
+            raise ValueError("cfg.moe has no mixer beside its attention")
+        if self.kda is not None:
+            if self.mla is not None or self.mixer is not None:
+                raise ValueError("cfg.kda (linear-attention layers) is not supported beside cfg.mla or cfg.mixer")
+            if any(not 0 <= i < self.num_layers for i in self.attention_layers):
+                raise ValueError(
+                    f"attention_layers {self.attention_layers} are not among the {self.num_layers} layers"
+                )
+        elif self.attention_layers:
+            raise ValueError("attention_layers names the softmax layers beside cfg.kda, which is not set")
+
+    def linear_layer(self, i: int) -> bool:
+        """Whether layer ``i`` is a linear-attention layer (``kda``)."""
+        return self.kda is not None and i not in self.attention_layers
+
+    @property
+    def expert_layers(self) -> int:
+        """Expert layers of the decoder: one a shortcut-connected pair, or
+        one a block."""
+        if self.moe is None:
+            return 0
+        return self.num_layers // 2 if self.mla is not None else self.num_layers
 
     @property
     def kv_heads(self) -> int:
@@ -430,9 +465,12 @@ class CausalSelfAttention(nn.Module):
                 ("value", cfg.kv_heads),
             )
         }  # [batch, seq, (kv_)heads, head_dim]
-        cos, sin = rope_angles(positions, cfg.head_dim, cfg.rope_theta)
-        q = apply_rope(proj["query"], cos, sin)
-        k = apply_rope(_scaled(proj["key"], cfg.multipliers.key), cos, sin)
+        if cfg.use_rope:
+            cos, sin = rope_angles(positions, cfg.head_dim, cfg.rope_theta)
+            q = apply_rope(proj["query"], cos, sin)
+            k = apply_rope(_scaled(proj["key"], cfg.multipliers.key), cos, sin)
+        else:
+            q, k = proj["query"], _scaled(proj["key"], cfg.multipliers.key)
         v = proj["value"]
 
         if self.decode and cfg.paged is not None:
@@ -651,6 +689,12 @@ class CausalSelfAttention(nn.Module):
                 attn = tiled_causal_attention(qh, kh, vh, cfg.attention_window)
             attn = attn.transpose(0, 2, 1, 3)
 
+        if cfg.attn_gate:
+            with jax.named_scope("attn.gate"):
+                gate = _site_call(
+                    dense_site(cfg, (cfg.num_heads, cfg.head_dim), name="gate"), hidden, cfg, adapter_ids
+                )
+                attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(attn.dtype)
         return _site_call(
             dense_site(cfg, cfg.hidden_size, axis=(-2, -1), name="out"),
             attn,
@@ -683,27 +727,35 @@ class SwiGluMlp(nn.Module):
 
 
 class DecoderBlock(nn.Module):
+    """A pre-norm block: attention (or, with ``linear``, the linear
+    attention of models/kda.py), then the feed-forward (an expert layer
+    where ``cfg.moe`` is set, models/moe.py)."""
+
     config: GPTConfig
     decode: bool = False
     mlp_factory: Optional[Any] = None  # swap-in point for MoE (parallel/moe.py)
     attention_fn: Optional[Any] = None
     append_mode: str = "auto"
+    linear: bool = False
 
     @nn.compact
-    def __call__(self, hidden, positions, adapter_ids=None, last_positions=None):
+    def __call__(self, hidden, positions, adapter_ids=None, last_positions=None, token_mask=None):
         cfg, mul = self.config, self.config.multipliers
         normed = RMSNorm(dtype=cfg.dtype, eps=cfg.rms_norm_eps, name="attn_norm")(hidden)
-        attn = CausalSelfAttention(
-            cfg,
-            decode=self.decode,
-            attention_fn=self.attention_fn,
-            append_mode=self.append_mode,
-            name="attn",
-        )(
-            _scaled(normed, mul.attention_in),
-            positions,
-            adapter_ids,
-        )
+        if self.linear:
+            attn = KdaMixer(cfg, decode=self.decode, name="kda")(normed, positions, last_positions)
+        else:
+            attn = CausalSelfAttention(
+                cfg,
+                decode=self.decode,
+                attention_fn=self.attention_fn,
+                append_mode=self.append_mode,
+                name="attn",
+            )(
+                _scaled(normed, mul.attention_in),
+                positions,
+                adapter_ids,
+            )
         hidden = hidden + _scaled(attn, mul.attention_out)
         if cfg.mixer is not None:
             # The same pre-norm feeds the mixer, side by side with attention.
@@ -711,6 +763,11 @@ class DecoderBlock(nn.Module):
                 _scaled(normed, mul.ssm_in), positions, last_positions
             )
             hidden = hidden + _scaled(mixed, mul.ssm_out)
+        if cfg.moe is not None:
+            if self.mlp_factory is not None:
+                raise ValueError("cfg.moe takes no mlp_factory")
+            norm_h = RMSNorm(dtype=cfg.dtype, eps=cfg.rms_norm_eps, name="mlp_norm")(hidden)
+            return hidden + ExpertLayer(cfg, name="moe")(norm_h, token_mask)
         if cfg.lora_serve and self.mlp_factory is not None:
             # A swapped-in MLP (MoE) has the plain one-argument call and
             # would silently skip its adapters.
@@ -804,7 +861,7 @@ class TransformerLM(nn.Module):
         )
         pending = None  # a pair's expert output, on its way to the pair's end
         for i in range(cfg.num_layers):
-            if cfg.moe is not None:
+            if cfg.mla is not None:
                 if self.mlp_factory is not None or self.attention_fn is not None or cfg.remat:
                     raise ValueError("cfg.moe (ShortcutBlock) takes no mlp_factory, attention_fn or remat")
                 hidden, pending = ShortcutBlock(
@@ -818,8 +875,9 @@ class TransformerLM(nn.Module):
                 mlp_factory=self.mlp_factory,
                 attention_fn=self.attention_fn,
                 append_mode=self.append_mode,
+                linear=cfg.linear_layer(i),
                 name=f"layer_{i}",
-            )(hidden, positions, adapter_ids, last_positions)
+            )(hidden, positions, adapter_ids, last_positions, token_mask)
         hidden = RMSNorm(dtype=cfg.dtype, eps=cfg.rms_norm_eps, name="final_norm")(hidden)
         if output == "hidden":
             # For the fused LM-head + cross-entropy path (ops/fused_xent.py):

@@ -26,7 +26,7 @@ from k8s_device_plugin_tpu.models import mla, moe
 from k8s_device_plugin_tpu.models.engine_sampling import build_block_fn, build_step_fn
 from k8s_device_plugin_tpu.models.transformer import GPTConfig, TransformerLM, decode_cache_spec
 from k8s_device_plugin_tpu.ops import expert_ffn as kernel
-from k8s_device_plugin_tpu.ops import latent_attention
+from k8s_device_plugin_tpu.ops import kda_decode, latent_attention
 from k8s_device_plugin_tpu.ops import tuning
 
 with open(os.path.join(os.path.dirname(__file__), "chipbench", "data", "tiny-longcat-flash.json")) as f:
@@ -245,6 +245,25 @@ def test_the_latent_kernel_compiles_for_a_v5e_at_longcat_flash_widths(one_chip):
     ).compile().as_text()
     assert sum("custom-call" in ln and "latent_decode_attention" in ln for ln in text.splitlines()) == 1
     assert not [ln for ln in text.splitlines() if " copy(" in ln and "[6144,16,640]" in ln]
+
+
+def test_the_recurrence_kernel_compiles_for_a_v5e_at_solar_open2_widths(one_chip):
+    """64 slots of 64 heads of 128 x 128 float32 state (268 MB a layer): Mosaic
+    takes it, the state is written in place over the donated operand, and
+    nothing state-sized is copied or held beside it."""
+    shape = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+
+    def call(state, q, k, v, g, beta):
+        return kda_decode.kda_decode(state, q, k, v, g, beta, interpret=False)
+
+    vec = shape((64, 64, 128), jnp.float32)
+    compiled = jax.jit(call, donate_argnums=0).lower(
+        shape((64, 64, 128, 128), jnp.float32), vec, vec, vec, vec, shape((64, 64), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert sum("custom-call" in ln and "kda_decode" in ln for ln in text.splitlines()) == 1
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and "[64,64,128,128]" in ln]
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 64 * 64 * 128 * 128 * 4 and memory.temp_size_in_bytes < 64 * 2**20
 
 
 @pytest.mark.parametrize("program", ["single step", "decode block"])
